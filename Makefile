@@ -2,7 +2,7 @@
 
 PY ?= python
 
-.PHONY: help test test-all speclint speclint-json speclint-sarif speclint-changed speclint-all forkdiff bench bench-smoke bench-diff bench-trend chaos mesh-smoke mem-smoke pool-smoke proofs-smoke soak-smoke trace-smoke pipeline-selfcheck trace metrics profile serve serve-data server-smoke serving-smoke
+.PHONY: help test test-all speclint speclint-json speclint-sarif speclint-changed speclint-all forkdiff bench chip-smoke bench-smoke bench-diff bench-trend chaos mesh-smoke mem-smoke pool-smoke proofs-smoke soak-smoke trace-smoke pipeline-selfcheck trace metrics profile serve serve-data server-smoke serving-smoke
 
 PROFILE_DIR ?= profile_artifacts
 
@@ -35,8 +35,11 @@ speclint-all:  ## include allowlisted findings in the listing
 forkdiff:  ## regenerate docs/FORKDIFF.md from the fork-diff machinery
 	$(PY) -m tools.speclint --write-forkdiff
 
-bench:  ## full benchmark battery (bench.py; TPU-aware, CPU fallback)
+bench:  ## full benchmark battery (bench.py; needs a TPU — exits 3 without one, sizes never depend on the backend)
 	$(PY) bench.py
+
+chip-smoke:  ## the main path end to end on ONE TPU chip at the 2^20-validator deneb deployment (chip_smoke.py; on a four-chip host `$(PY) chip_smoke.py --chips 4` runs the mesh path instead); fails without a TPU
+	$(PY) chip_smoke.py
 
 bench-smoke:  ## tier-1-adjacent: one warm 2^14 deneb block (columnar engine engaged) + a 2^18 columnar-primary epoch engagement check + the 2^18 phase0 committee-mask engagement check + the scenario smoke + the serving smoke + the pool smoke + the mesh smoke + the soak smoke + the memory-observatory smoke + the proof-plane smoke + the trace-plane smoke
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_ops_vector.py tests/test_epoch_vector.py tests/test_committee_masks.py tests/test_scenarios.py tests/test_serving.py tests/test_pool.py tests/test_mesh_runtime.py tests/test_soak.py tests/test_memory_observatory.py tests/test_proofs.py tests/test_trace_plane.py -q -m 'bench_smoke or chaos_smoke or serving_smoke or pool_smoke or mesh_smoke or soak_smoke or mem_smoke or proofs_smoke or trace_smoke'
@@ -80,7 +83,7 @@ metrics:  ## dump the telemetry metrics registry after a pipeline run
 	JAX_PLATFORMS=cpu $(PY) -m ethereum_consensus_tpu.pipeline --selfcheck --metrics-out metrics.json
 	@cat metrics.json
 
-profile:  ## one-command capture artifact: selfcheck with Chrome trace + metrics snapshot + device ledger in $(PROFILE_DIR)/ (the TPU_CAPTURE_PLAN command; on a chip, run without JAX_PLATFORMS=cpu)
+profile:  ## one-command capture artifact: selfcheck with Chrome trace + metrics snapshot + device ledger in $(PROFILE_DIR)/ (CPU backend; the chip run is `make chip-smoke`)
 	mkdir -p $(PROFILE_DIR)
 	JAX_PLATFORMS=cpu $(PY) -m ethereum_consensus_tpu.pipeline --selfcheck --trace-out $(PROFILE_DIR)/trace.json --metrics-out $(PROFILE_DIR)/metrics.json --device-out $(PROFILE_DIR)/device.json
 	@echo "capture artifact in $(PROFILE_DIR)/: trace.json (Perfetto), metrics.json, device.json"

@@ -44,7 +44,6 @@ KNOWN_KEYS = {
     "ECT_MESH_PROOF_MIN_CHUNKS": "proof-group chunk count below which gathers stay host",
     "ECT_PAIRING_MIN_SETS": "pairing-batch size routed to device; off pins the host engine",
     "ECT_TRACEMALLOC": "=1/on adds tracemalloc deltas to the memory observatory",
-    "EC_JAX_CACHE_DIR": "jax persistent compilation cache directory",
     "EC_PAIRING_MULT": "pairing product kernel: u64 (CIOS lanes) | mxu (int8 matmul)",
     "EC_BLS_BACKEND": "BLS backend pin: auto | native | python",
     "EC_NATIVE_SHA_NI": "native SHA extension toggle (build-probe cache key input)",

@@ -755,7 +755,8 @@ def _batch_all_valid(sets: list[SignatureSet], dst: bytes) -> bool:
             agg = device_g1.aggregate_pubkey_sets_device(
                 [[pk.raw_uncompressed() for pk in s.public_keys] for s in sets]
             )
-        except Exception:  # noqa: BLE001 — device trouble must not change verdicts
+        except Exception as exc:  # noqa: BLE001 — device trouble must not change verdicts
+            _device_decline("batch_aggregate", exc)
             agg = None
         if agg is not None:
             if any(is_inf for _, is_inf in agg):

@@ -202,23 +202,12 @@ def jitted_kernels() -> dict:
 def kernel_cache_census() -> "tuple[int, int]":
     """(bytes, entries) for the memory observatory's
     ``epoch_vector.jit_kernels`` owner (telemetry/memory.py): one entry
-    per wrapped kernel plus its executable-cache population where the
-    jax version exposes it (``_cache_size``). Bytes stay 0 — XLA does
-    not expose executable sizes, and an honest unknown beats a guess."""
+    per wrapped kernel plus its executable-cache population beyond the
+    first (``_cache_size``). Bytes stay 0 — XLA does not expose
+    executable sizes, and an honest unknown beats a guess."""
     entries = 0
     for kernel in _JITTED_KERNELS.values():
-        entries += 1
-        probe = getattr(
-            getattr(kernel, "__wrapped__", kernel), "_cache_size", None
-        )
-        if probe is not None:
-            try:
-                entries += max(0, int(probe()) - 1)
-            except (TypeError, ValueError, RuntimeError):
-                # jax version drift: _cache_size is a private probe and
-                # may change arity/return shape; the census stays honest
-                # at one entry per kernel
-                pass
+        entries += 1 + max(0, kernel.__wrapped__._cache_size() - 1)
     return 0, entries
 
 

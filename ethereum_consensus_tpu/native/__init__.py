@@ -13,8 +13,10 @@ hash_tree_root below the device threshold runs native.
 from __future__ import annotations
 
 import ctypes
+import functools
 import hashlib
 import os
+import platform
 import subprocess
 import tempfile
 
@@ -23,6 +25,7 @@ from .. import _env
 __all__ = [
     "load",
     "available",
+    "build_failures",
     "hash_level_native",
     "merkle_root_native",
     "install",
@@ -39,44 +42,105 @@ def _build_dir() -> str:
     return path
 
 
-def _source_tag() -> str:
-    with open(_SOURCE, "rb") as f:
-        digest = hashlib.sha256(f.read())
-    digest.update(_env.raw("EC_NATIVE_SHA_NI").encode())
+@functools.lru_cache(maxsize=1)
+def _host_tag() -> bytes:
+    """What ``-march=native`` depends on besides the source: this host's
+    CPU feature flags and the compiler. Part of every artifact's name, so
+    a ``_build/`` directory that travels with the tree to a different
+    machine is rebuilt from source there instead of being loaded with
+    instructions that host may lack."""
+    flags = ""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
+                    break
+    except OSError:
+        pass
+    try:
+        compiler = subprocess.run(
+            ["g++", "-dumpfullversion", "-dumpmachine"],
+            check=True, capture_output=True, text=True, timeout=30,
+        ).stdout
+    except (OSError, subprocess.SubprocessError):
+        compiler = ""
+    return "\0".join(
+        (platform.machine(), flags or platform.processor(), compiler)
+    ).encode()
+
+
+def artifact_tag(sources, extra: str = "") -> str:
+    """Name tag of a built library: source bytes + build inputs + host."""
+    digest = hashlib.sha256()
+    for path in sources:
+        with open(path, "rb") as f:
+            digest.update(f.read())
+    digest.update(extra.encode())
+    digest.update(_host_tag())
     return digest.hexdigest()[:16]
 
 
+# library stem -> why its last build failed (compiler stderr tail);
+# available() stays a bool, this says what a False means
+_BUILD_FAILURES: dict = {}
+
+
+def build_failures() -> dict:
+    return dict(_BUILD_FAILURES)
+
+
+def build_shared(stem: str, tag: str, source: str, flags, timeout: int):
+    """Path of ``_build/<stem>-<tag>.so``, compiling ``source`` with g++
+    first when it is not there yet; None when the build fails."""
+    lib_path = os.path.join(_build_dir(), f"{stem}-{tag}.so")
+    if os.path.exists(lib_path):
+        return lib_path
+    tmp = None
+    try:
+        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_build_dir())
+        os.close(fd)
+        subprocess.run(
+            ["g++", "-O3", "-march=native", "-shared", "-fPIC", *flags,
+             source, "-o", tmp],
+            check=True,
+            capture_output=True,
+            timeout=timeout,
+        )
+        os.replace(tmp, lib_path)  # atomic under concurrent builders
+        tmp = None
+    except (OSError, subprocess.SubprocessError) as exc:
+        stderr = getattr(exc, "stderr", None) or b""
+        _BUILD_FAILURES[stem] = (
+            f"{type(exc).__name__}: {exc}"[:300]
+            + stderr.decode("utf-8", "replace")[-500:]
+        )
+        return None
+    finally:
+        if tmp and os.path.exists(tmp):
+            os.unlink(tmp)
+    return lib_path
+
+
 def load():
-    """Compile (once per source hash) + load the shared library, or None."""
+    """Compile (once per source hash and host) + load the shared library,
+    or None."""
     global _LIB, _TRIED
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    lib_path = os.path.join(_build_dir(), f"sha256_merkle-{_source_tag()}.so")
-    if not os.path.exists(lib_path):
-        tmp = None
-        try:
-            fd, tmp = tempfile.mkstemp(suffix=".so", dir=_build_dir())
-            os.close(fd)
-            flags = ["-O3", "-march=native", "-shared", "-fPIC"]
-            # SHA-NI is opt-in: virtualized hosts may trap the sha
-            # instructions (measured ~20x slower than scalar under
-            # emulation in this image)
-            if _env.raw("EC_NATIVE_SHA_NI"):
-                flags.append("-DEC_USE_SHA_NI")
-            subprocess.run(
-                ["g++", *flags, _SOURCE, "-o", tmp],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-            os.replace(tmp, lib_path)  # atomic under concurrent builders
-            tmp = None
-        except (OSError, subprocess.SubprocessError):
-            return None
-        finally:
-            if tmp and os.path.exists(tmp):
-                os.unlink(tmp)
+    # SHA-NI is opt-in: virtualized hosts may trap the sha instructions
+    # (measured ~20x slower than scalar under emulation in this image)
+    sha_ni = _env.raw("EC_NATIVE_SHA_NI")
+    lib_path = build_shared(
+        "sha256_merkle",
+        artifact_tag([_SOURCE], sha_ni),
+        _SOURCE,
+        ["-DEC_USE_SHA_NI"] if sha_ni else [],
+        timeout=120,
+    )
+    if lib_path is None:
+        return None
     try:
         lib = ctypes.CDLL(lib_path)
     except OSError:

@@ -14,10 +14,9 @@ slots otherwise pick up garbage upper halves on x86-64.
 from __future__ import annotations
 
 import ctypes
-import hashlib
 import os
-import subprocess
-import tempfile
+
+from . import artifact_tag, build_shared
 
 __all__ = [
     "load",
@@ -74,20 +73,6 @@ def decode_error_message(rc: int) -> str:
     return _DECODE_ERRORS.get(rc, f"native error {rc}")
 
 
-def _build_dir() -> str:
-    path = os.path.join(os.path.dirname(__file__), "_build")
-    os.makedirs(path, exist_ok=True)
-    return path
-
-
-def _source_tag() -> str:
-    digest = hashlib.sha256()
-    for path in (_SOURCE, _HEADER):
-        with open(path, "rb") as f:
-            digest.update(f.read())
-    return digest.hexdigest()[:16]
-
-
 def _declare(lib) -> None:
     c = _c
     sz = c.c_size_t
@@ -138,35 +123,25 @@ def _declare(lib) -> None:
 
 
 def load():
-    """Compile (once per source hash) + load the shared library, or None."""
+    """Compile (once per source hash and host) + load the shared library,
+    or None."""
     global _LIB, _TRIED
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    lib_path = os.path.join(_build_dir(), f"bls12_381-{_source_tag()}.so")
-    if not os.path.exists(lib_path):
-        tmp = None
-        try:
-            fd, tmp = tempfile.mkstemp(suffix=".so", dir=_build_dir())
-            os.close(fd)
-            subprocess.run(
-                # -std=c++17 makes operator new honor over-aligned types
-                # (the AVX-512 x8 structs); older toolchains default to
-                # gnu++14 where a heap MillerPairX8 is only 16-byte
-                # aligned and the first vmovdqa64 GP-faults
-                ["g++", "-O3", "-march=native", "-std=c++17", "-shared",
-                 "-fPIC", _SOURCE, "-o", tmp],
-                check=True,
-                capture_output=True,
-                timeout=300,
-            )
-            os.replace(tmp, lib_path)  # atomic under concurrent builders
-            tmp = None
-        except (OSError, subprocess.SubprocessError):
-            return None
-        finally:
-            if tmp and os.path.exists(tmp):
-                os.unlink(tmp)
+    lib_path = build_shared(
+        "bls12_381",
+        artifact_tag([_SOURCE, _HEADER]),
+        _SOURCE,
+        # -std=c++17 makes operator new honor over-aligned types (the
+        # AVX-512 x8 structs); older toolchains default to gnu++14 where
+        # a heap MillerPairX8 is only 16-byte aligned and the first
+        # vmovdqa64 GP-faults
+        ["-std=c++17"],
+        timeout=300,
+    )
+    if lib_path is None:
+        return None
     try:
         lib = ctypes.CDLL(lib_path)
     except OSError:

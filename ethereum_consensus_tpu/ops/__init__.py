@@ -79,7 +79,8 @@ def install(
     Spec semantics are unchanged — every device twin is bit-identical to
     its host function (cross-checked in tests); the thresholds only decide
     where the work runs. Exact u64 arithmetic needs jax x64 mode, enabled
-    here."""
+    here. The process-wide shuffle memo is dropped (as ``uninstall`` does),
+    so shuffles from here on take the installed route."""
     import jax
 
     jax.config.update("jax_enable_x64", True)
@@ -88,6 +89,7 @@ def install(
     _device_flags.SHUFFLE_MIN_N = shuffle_min_n
     _device_flags.BLS_AGG_MIN_N = bls_agg_min_n
     _device_flags.PAIRING_MIN_SETS = pairing_min_sets
+    _forget_shuffles()
 
 
 def uninstall() -> None:
@@ -96,6 +98,14 @@ def uninstall() -> None:
     _device_flags.SHUFFLE_MIN_N = None
     _device_flags.BLS_AGG_MIN_N = None
     _device_flags.PAIRING_MIN_SETS = None
+    _forget_shuffles()
+
+
+def _forget_shuffles() -> None:
+    """The committee shuffles are memoized process-wide by seed; a change
+    of routing drops them, so the shuffles computed from here on take the
+    route now installed (the memo would otherwise keep serving the other
+    route's — bit-identical — results and the new route would never run)."""
     from ..models.phase0 import helpers as _phase0_helpers
 
     _phase0_helpers._SHUFFLE_CACHE.clear()

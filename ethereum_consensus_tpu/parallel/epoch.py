@@ -33,12 +33,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..telemetry import device as _obs
 from ..telemetry import memory as _mem
-from ._compat import shard_map
-from .mesh import SHARD_AXIS
+from .mesh import SHARD_AXIS, psum_u64
 
 __all__ = ["MeshEpochSweeps", "pad_to_mesh"]
 
@@ -72,7 +72,7 @@ def _inactivity_sharded(mesh, bias: int, recovery: int, leaking: bool):
     spec = P(SHARD_AXIS)
     return _obs.observe_jit(
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(spec,) * 3,
@@ -110,13 +110,13 @@ def _fused_sharded(
             scores, increment, brpi, active_increments, denominator,
             bias, recovery_rate, weights, weight_denominator, leaking,
             head_flag_index, target_flag_index,
-            psum=lambda v: jax.lax.psum(v, SHARD_AXIS),
+            psum=psum_u64,
         )
 
     spec = P(SHARD_AXIS)
     return _obs.observe_jit(
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(spec,) * 7 + (P(),) * 4,
@@ -166,9 +166,7 @@ def _rewards_sharded(
             )
             if flag_index == target_flag_index:
                 target_unslashed = unslashed
-            flag_sum = jax.lax.psum(
-                jnp.sum(jnp.where(unslashed, eff, zero)), SHARD_AXIS
-            )
+            flag_sum = psum_u64(jnp.sum(jnp.where(unslashed, eff, zero)))
             sums.append(flag_sum)
             # get_total_balance floors at one increment
             unslashed_increments = (
@@ -210,13 +208,13 @@ def _rewards_sharded(
                 (raised < balances).astype(jnp.uint64)
             )
             balances = jnp.where(raised >= penalties, raised - penalties, zero)
-        wrapped_total = jax.lax.psum(wrapped, SHARD_AXIS)
+        wrapped_total = psum_u64(wrapped)
         return balances, wrapped_total, jnp.stack(sums)
 
     spec = P(SHARD_AXIS)
     return _obs.observe_jit(
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(spec,) * 7 + (P(),) * 4,
@@ -255,6 +253,17 @@ class MeshEpochSweeps:
             mem.record_copy("parallel.pad_to_mesh", int(out.nbytes))
         return out
 
+    def _shard(self, site: str, *columns):
+        """Pad the host columns and place each ROW-SHARDED over the mesh:
+        every device receives its own rows straight from the host (a
+        plain ``jnp.asarray`` would land the whole column on device 0
+        and leave the spreading to the jitted call)."""
+        return _obs.h2d_put(
+            site,
+            columns,
+            NamedSharding(self.mesh, P(SHARD_AXIS)),
+        )
+
     def inactivity_scores(self, scores, eligible, participating, bias: int,
                           recovery_rate: int, leaking: bool):
         """Sharded ``process_inactivity_updates`` sweep; returns the new
@@ -271,7 +280,7 @@ class MeshEpochSweeps:
         kernel = _inactivity_sharded(
             self.mesh, int(bias), int(recovery_rate), bool(leaking)
         )
-        args = _obs.h2d(
+        args = self._shard(
             "parallel.epoch.inactivity",
             self._pad(scores),
             self._pad(eligible, False),
@@ -306,7 +315,7 @@ class MeshEpochSweeps:
             int(head_flag_index),
             int(target_flag_index),
         )
-        sharded = _obs.h2d(
+        sharded = self._shard(
             "parallel.epoch.fused",
             self._pad(balances),
             self._pad(eff),
@@ -352,7 +361,7 @@ class MeshEpochSweeps:
             int(head_flag_index),
             int(target_flag_index),
         )
-        sharded = _obs.h2d(
+        sharded = self._shard(
             "parallel.epoch.rewards",
             self._pad(balances),
             self._pad(eff),

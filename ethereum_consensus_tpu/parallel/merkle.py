@@ -19,13 +19,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..ops.merkle import reduce_levels, zero_hash_words
 from ..ssz.merkle import BYTES_PER_CHUNK, merkleize_chunks, next_pow_of_two, zero_hash
 from ..telemetry import device as _obs
-from ._compat import shard_map
 from .mesh import SHARD_AXIS
 
 __all__ = ["sharded_merkle_root_words", "sharded_merkleize_chunks"]
@@ -62,13 +61,18 @@ def sharded_merkle_root_words(
 
     # check_vma=False: see parallel/step.py — the SHA-256 fori_loop carry
     # mixes unvarying literals with varying lanes.
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, axis_name), P(None, None)),
         out_specs=P(None),
         check_vma=False,
     )(nodes, zero_words)
+
+
+sharded_merkle_root_words = _obs.observe_jit(
+    sharded_merkle_root_words, "parallel.merkle.sharded_merkle_root_words"
+)
 
 
 def sharded_merkleize_chunks(
@@ -106,8 +110,16 @@ def sharded_merkleize_chunks(
     words = np.ascontiguousarray(
         np.frombuffer(data, dtype=">u4").astype(np.uint32).reshape(padded, 8).T
     )
-    words_d, zero_d = _obs.h2d(
-        "parallel.merkle.sharded_merkleize", words, zero_hash_words()
+    # each device receives its own leaf range straight from the host
+    (words_d,) = _obs.h2d_put(
+        "parallel.merkle.sharded_merkleize",
+        (words,),
+        NamedSharding(mesh, P(None, axis_name)),
+    )
+    (zero_d,) = _obs.h2d_put(
+        "parallel.merkle.sharded_merkleize",
+        (zero_hash_words(),),
+        NamedSharding(mesh, P()),
     )
     root = sharded_merkle_root_words(
         words_d,

@@ -34,7 +34,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops import fq12, pairing as dp
 from ..telemetry import device as _obs
-from ._compat import shard_map
 from .mesh import SHARD_AXIS, default_device_mesh
 
 __all__ = ["batch_verify_sharded", "miller_partials_sharded"]
@@ -62,7 +61,7 @@ def _sharded_parts(mesh):
     # unvarying constants (same situation as parallel/step.py's SHA loop)
     return _obs.observe_jit(
         jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(P(SHARD_AXIS),) * 7,

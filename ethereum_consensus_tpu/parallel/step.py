@@ -31,8 +31,7 @@ from ..ops.merkle import reduce_levels
 from ..ops.sha256 import sha256_64b
 from ..ssz.merkle import next_pow_of_two
 from ..telemetry import device as _obs
-from ._compat import shard_map
-from .mesh import SHARD_AXIS
+from .mesh import SHARD_AXIS, psum_u64
 
 __all__ = [
     "make_chain_step",
@@ -129,7 +128,7 @@ def make_chain_step(
         )
 
         # 2. total active balance across the whole mesh
-        total = jax.lax.psum(
+        total = psum_u64(
             jnp.sum(jnp.where(active, new_eff, jnp.uint64(0))), axis_name
         )
 
@@ -149,7 +148,7 @@ def make_chain_step(
     # system rejects; replication of the psum/top-tree outputs is guaranteed
     # by construction here.
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -314,7 +313,7 @@ def _epoch_sweep_step(
             )
 
         # --- totals (psum over the mesh — the ICI collectives) ---
-        total_active = jax.lax.psum(
+        total_active = psum_u64(
             jnp.sum(jnp.where(active_cur, eff, jnp.uint64(0))), axis_name
         )
         total_active = jnp.maximum(total_active, increment)
@@ -334,7 +333,7 @@ def _epoch_sweep_step(
                 & active_prev
             )
             unslashed_increments = (
-                jax.lax.psum(
+                psum_u64(
                     jnp.sum(jnp.where(participating, eff, jnp.uint64(0))),
                     axis_name,
                 )
@@ -373,7 +372,7 @@ def _epoch_sweep_step(
 
     spec = P(axis_name)
     jitted = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(spec,) * 8,

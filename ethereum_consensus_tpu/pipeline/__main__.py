@@ -31,8 +31,7 @@ Telemetry exports (docs/OBSERVABILITY.md):
   (``telemetry/device.py``) for the selfcheck's duration and dump its
   ledgers (compile ledger + recompile sentinel, per-site host<->device
   transfer bytes, the device-vs-host routing journal) as JSON. The
-  three `-out` flags together are ``make profile``'s capture artifact
-  (docs/TPU_CAPTURE_PLAN.md).
+  three `-out` flags together are ``make profile``'s capture artifact.
 * ``--serve PORT``       — run the live introspection server
   (``telemetry/server.py``: /metrics Prometheus exposition, /healthz,
   /blocks lineage, /events SSE) for the selfcheck's duration; 0 picks

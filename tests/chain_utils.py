@@ -37,9 +37,26 @@ def secret_key(index: int) -> bls.SecretKey:
     return bls.SecretKey(index + 1)
 
 
+_INDEX_OF_KEY: dict = {}  # public_key_bytes(i) -> i, for _indices_of_keys
+
+
 @functools.lru_cache(maxsize=None)
 def public_key_bytes(index: int) -> bytes:
-    return secret_key(index).public_key().to_bytes()
+    key = secret_key(index).public_key().to_bytes()
+    _INDEX_OF_KEY[key] = index
+    return key
+
+
+def aggregate_sign(indices, message: bytes) -> bls.Signature:
+    """The aggregate of ``secret_key(i).sign(message)`` over ``indices``,
+    computed as ONE signature under the summed secret: every signer signs
+    the same message, so Σ sk_i·H(m) = (Σ sk_i)·H(m) — the same G2 point,
+    hence the same bytes, at one scalar multiplication instead of one per
+    signer (a 2^20-registry chain signs ~300k committee votes)."""
+    from ethereum_consensus_tpu.crypto.fields import R
+
+    total = sum(secret_key(i)._scalar for i in indices) % R
+    return bls.SecretKey(total).sign(message)
 
 
 def withdrawal_credentials(index: int) -> bytes:
@@ -322,10 +339,9 @@ def make_attestation(state, slot: int, index: int, context, participation=1.0,
     bits = [i < n_participants for i in range(len(committee))]
     domain = h.get_domain(state, DomainType.BEACON_ATTESTER, epoch, context)
     root = compute_signing_root(ns.AttestationData, data, domain)
-    sigs = [
-        secret_key(committee[i]).sign(root) for i in range(len(committee)) if bits[i]
-    ]
-    signature = bls.aggregate(sigs).to_bytes()
+    signature = aggregate_sign(
+        [committee[i] for i in range(len(committee)) if bits[i]], root
+    ).to_bytes()
     return ns.Attestation(
         aggregation_bits=bits, data=data, signature=signature
     )
@@ -409,6 +425,25 @@ def fresh_genesis_fork(fork_name: str, validator_count: int = 64,
     return state.copy(), context
 
 
+def _indices_of_keys(state, public_keys) -> list[int]:
+    """Validator index of each key. Every signing key of these chains is
+    ``public_key_bytes(i)`` for its own index ``i``, so the keys already
+    derived in this process answer in O(1) — checked against the registry
+    — and a full registry scan (seconds per call at 2^20) is only the
+    fallback."""
+    wanted = [bytes(pk) for pk in public_keys]
+    validators = state.validators
+    known = [_INDEX_OF_KEY.get(pk) for pk in wanted]
+    if all(
+        i is not None and i < len(validators)
+        and bytes(validators[i].public_key) == pk
+        for i, pk in zip(known, wanted)
+    ):
+        return known
+    index_by_key = {bytes(v.public_key): i for i, v in enumerate(validators)}
+    return [index_by_key[pk] for pk in wanted]
+
+
 def make_sync_aggregate(state, context, participation=1.0):
     """Full (or partial) sync-committee signature over the previous slot's
     block root; ``state`` must be at the block's slot."""
@@ -427,20 +462,19 @@ def make_sync_aggregate(state, context, participation=1.0):
     )
     signing_root = compute_signing_root(Root, root, domain)
 
-    index_by_key = {bytes(v.public_key): i for i, v in enumerate(state.validators)}
-    committee_indices = [
-        index_by_key[bytes(pk)] for pk in state.current_sync_committee.public_keys
-    ]
+    committee_indices = _indices_of_keys(
+        state, state.current_sync_committee.public_keys
+    )
     n_participants = max(1, int(len(committee_indices) * participation))
     bits = [i < n_participants for i in range(len(committee_indices))]
-    sigs = [
-        secret_key(committee_indices[i]).sign(signing_root)
-        for i in range(len(committee_indices))
-        if bits[i]
+    signers = [
+        committee_indices[i] for i in range(len(committee_indices)) if bits[i]
     ]
     return ns.SyncAggregate(
         sync_committee_bits=bits,
-        sync_committee_signature=bls.aggregate(sigs).to_bytes(),
+        sync_committee_signature=aggregate_sign(
+            signers, signing_root
+        ).to_bytes(),
     )
 
 
@@ -476,11 +510,15 @@ def make_execution_payload_fork(fork_name: str, state, context, block_number=1,
 
 
 def produce_block_fork(fork_name: str, state, slot: int, context,
-                       attestations=(), payload_fields=None, **body_extras):
+                       attestations=(), payload_fields=None, apply=False,
+                       **body_extras):
     """Generic produce_block for altair+ forks: advances the state, builds a
     body with attestations + a full sync aggregate (+ a chained execution
     payload on bellatrix+ and any fork-specific ``body_extras``), fills the
-    post-state root on a scratch copy, and signs."""
+    post-state root on a scratch copy, and signs. With ``apply`` the block
+    is applied to ``state`` itself instead of a copy — the chain builders'
+    shape, which would otherwise copy the state and then apply the same
+    block a second time."""
     from ethereum_consensus_tpu.models.phase0.containers import BeaconBlockHeader
 
     mod = _fork_module(fork_name)
@@ -506,11 +544,11 @@ def produce_block_fork(fork_name: str, state, slot: int, context,
         parent_root=BeaconBlockHeader.hash_tree_root(state.latest_block_header),
         body=body,
     )
-    scratch = state.copy()
+    domain = h.get_domain(state, DomainType.BEACON_PROPOSER, None, context)
+    scratch = state if apply else state.copy()
     mod.block_processing.process_block(scratch, block, context)
     block.state_root = type(scratch).hash_tree_root(scratch)
 
-    domain = h.get_domain(state, DomainType.BEACON_PROPOSER, None, context)
     root = compute_signing_root(ns.BeaconBlock, block, domain)
     signature = secret_key(proposer_index).sign(root).to_bytes()
     return ns.SignedBeaconBlock(message=block, signature=signature)
@@ -658,7 +696,7 @@ def make_attestation_electra(state, slot: int, context, participation=1.0,
     )
     domain = h.get_domain(state, DomainType.BEACON_ATTESTER, epoch, context)
     root = compute_signing_root(ns.AttestationData, data, domain)
-    signature = bls.aggregate([secret_key(v).sign(root) for v in sorted(signers)])
+    signature = aggregate_sign(signers, root)
     return ns.Attestation(
         aggregation_bits=bits,
         data=data,
@@ -899,14 +937,73 @@ def produce_full_upgrade_chain(validator_count: int = 64,
     return state.copy(), context, blocks
 
 
+def build_mainnet_chain(fork_name: str, validator_count: int,
+                        n_blocks: int, atts: int, registry=None):
+    """(pre_state, context, signed_blocks), built from nothing: ``n_blocks``
+    consecutive valid blocks at mainnet committee structure on a
+    ``validator_count`` registry, each carrying up to ``atts`` aggregate
+    attestations plus a full sync aggregate / execution payload on
+    altair+/bellatrix+. Deterministic. ``mainnet_chain_bundle`` is this
+    behind the disk cache; ``chip_smoke.py`` calls it directly (a 2^20
+    state costs more to serialize for the cache than to build).
+    ``registry(validator_count, fork_name)`` supplies the starting state
+    (default: ``build_fast_registry_state``, uncached)."""
+    mod = _fork_module(fork_name)
+    state, ctx = (registry or build_fast_registry_state)(
+        validator_count, fork_name
+    )
+    start = int(state.slot) + 2
+    # realize every key that will sign anywhere in the chain BEFORE
+    # any root is computed: committee shuffling and proposer sampling
+    # read seeds and effective balances, never pubkey bytes, and the
+    # chain stays within epochs whose seeds come from pre-genesis
+    # randao mixes — so index selection on a throwaway blockless
+    # advance matches the real replay
+    needed = set()
+    probe = state.copy()
+    for slot in range(start, start + n_blocks):
+        mod.slot_processing.process_slots(probe, slot, ctx)
+        needed.add(h.get_beacon_proposer_index(probe, ctx))
+    for slot in range(max(0, start - 2), start + n_blocks):
+        per_slot = h.get_committee_count_per_slot(
+            probe, slot // ctx.SLOTS_PER_EPOCH, ctx
+        )
+        for index in range(min(atts, per_slot)):
+            needed.update(h.get_beacon_committee(probe, slot, index, ctx))
+    del probe
+    realize_validator_keys(state, needed)
+    scratch = state.copy()
+
+    def attest(slot: int) -> list:
+        per_slot = h.get_committee_count_per_slot(
+            scratch, slot // ctx.SLOTS_PER_EPOCH, ctx
+        )
+        return [
+            make_attestation(scratch, slot, index, ctx)
+            for index in range(min(atts, per_slot))
+        ]
+
+    # the first block attests too — to the empty slot before it — so
+    # EVERY block of the chain carries its ``atts`` aggregates
+    mod.slot_processing.process_slots(scratch, start - 1, ctx)
+    pending = attest(start - 1)
+    blocks = []
+    for slot in range(start, start + n_blocks):
+        blocks.append(
+            produce_block_fork(
+                fork_name, scratch, slot, ctx, attestations=pending,
+                apply=True,
+            )
+        )
+        pending = attest(slot)
+    return state, ctx, blocks
+
+
 def mainnet_chain_bundle(fork_name: str, validator_count: int,
                          n_blocks: int, atts: int, cache_tag: str = ""):
-    """(pre_state, context, signed_blocks): ``n_blocks`` consecutive
-    valid blocks at mainnet committee structure on a ``validator_count``
-    registry, each carrying up to ``atts`` aggregate attestations plus a
-    full sync aggregate / execution payload on altair+/bellatrix+ —
-    the replay stream the pipeline bench drives. Disk-cached (the
-    signing cost at 2^20 is minutes; the bench pays one deserialize).
+    """``build_mainnet_chain`` behind the disk cache — the replay stream
+    the pipeline bench drives (a cold build at 2^20 is minutes; a warm
+    call pays one deserialize).
 
     ``cache_tag`` MUST name any scenario/mutator parameterization a
     caller derives a non-honest stream from AND THEN re-caches: it is
@@ -915,50 +1012,13 @@ def mainnet_chain_bundle(fork_name: str, validator_count: int,
     returned blocks needs no tag — the cached bytes are never mutated
     (mutators copy, scenarios/mutators.py)."""
     context = Context.for_mainnet()
-    mod = _fork_module(fork_name)
-    ns = mod.build(context.preset)
+    ns = _fork_module(fork_name).build(context.preset)
 
     def build():
-        state, ctx = fast_registry_state(validator_count, fork_name)
-        start = int(state.slot) + 2
-        # realize every key that will sign anywhere in the chain BEFORE
-        # any root is computed: committee shuffling and proposer sampling
-        # read seeds and effective balances, never pubkey bytes, and the
-        # chain stays within epochs whose seeds come from pre-genesis
-        # randao mixes — so index selection on a throwaway blockless
-        # advance matches the real replay
-        needed = set()
-        probe = state.copy()
-        for slot in range(start, start + n_blocks):
-            mod.slot_processing.process_slots(probe, slot, ctx)
-            needed.add(h.get_beacon_proposer_index(probe, ctx))
-        for slot in range(max(0, start - 2), start + n_blocks):
-            per_slot = h.get_committee_count_per_slot(
-                probe, slot // ctx.SLOTS_PER_EPOCH, ctx
-            )
-            for index in range(min(atts, per_slot)):
-                needed.update(h.get_beacon_committee(probe, slot, index, ctx))
-        del probe
-        realize_validator_keys(state, needed)
-        scratch = state.copy()
-        blocks = []
-        pending: list = []
-        for slot in range(start, start + n_blocks):
-            block = produce_block_fork(
-                fork_name, scratch, slot, ctx, attestations=pending
-            )
-            stm = mod.state_transition
-            stm.state_transition_block_in_slot(
-                scratch, block, stm.Validation.ENABLED, ctx
-            )
-            per_slot = h.get_committee_count_per_slot(
-                scratch, slot // ctx.SLOTS_PER_EPOCH, ctx
-            )
-            pending = [
-                make_attestation(scratch, slot, index, ctx)
-                for index in range(min(atts, per_slot))
-            ]
-            blocks.append(block)
+        state, _, blocks = build_mainnet_chain(
+            fork_name, validator_count, n_blocks, atts,
+            registry=fast_registry_state,
+        )
         return state, blocks
 
     def serialize(value):

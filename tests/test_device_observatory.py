@@ -297,8 +297,8 @@ def test_epoch_vector_decline_reasons_counted_and_one_shot(monkeypatch):
 
 def test_pipelined_replay_trace_has_device_lane_and_verify_route():
     """A pipelined replay with recording on, crossing an epoch boundary
-    with the device sweeps installed (host JAX backend here — same
-    machinery, real chip on the TPU_CAPTURE_PLAN run), yields a Chrome
+    with the device sweeps installed (host JAX backend here — the same
+    machinery chip_smoke.py drives on the chip), yields a Chrome
     trace whose `device` lane carries compile AND transfer events; the
     flight lineage of every flushed block names the pairing route that
     verified its window."""
