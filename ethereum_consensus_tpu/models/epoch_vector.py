@@ -155,30 +155,41 @@ def jitted_kernels() -> dict:
     with _JITTED_KERNELS_LOCK:
         if _JITTED_KERNELS:
             return _JITTED_KERNELS
-        import functools
-
         import jax
         import jax.numpy as jnp
 
         jax.config.update("jax_enable_x64", True)
+
+        def bound(name: str, kernel):
+            """``kernel`` with ``jax.numpy`` for its ``xp``, under a name
+            of its own: jit calls the program ``jit_<name>``, which is
+            what a device trace prints (a ``functools.partial`` has no
+            name and prints ``jit__unknown``)."""
+
+            def run(*args):
+                return kernel(jnp, *args)
+
+            run.__name__ = run.__qualname__ = name
+            return run
+
         built = {
             "inactivity_scores": _device_obs.observe_jit(
                 jax.jit(
-                    functools.partial(inactivity_scores_kernel, jnp),
+                    bound("inactivity_scores", inactivity_scores_kernel),
                     static_argnums=(3, 4, 5),  # bias, recovery, leaking
                 ),
                 "epoch_vector.inactivity_scores_kernel",
             ),
             "flag_deltas": _device_obs.observe_jit(
                 jax.jit(
-                    functools.partial(flag_deltas_kernel, jnp),
+                    bound("flag_deltas", flag_deltas_kernel),
                     # weight, increments, denominator, leaking, head flag
                     static_argnums=(3, 4, 5, 6, 7, 8),
                 ),
                 "epoch_vector.flag_deltas_kernel",
             ),
             "apply_delta_pairs": _device_obs.observe_jit(
-                jax.jit(functools.partial(apply_delta_pairs_kernel, jnp)),
+                jax.jit(bound("apply_delta_pairs", apply_delta_pairs_kernel)),
                 "epoch_vector.apply_delta_pairs_kernel",
             ),
             # the FUSED device epoch kernel (ISSUE 14): inactivity +
@@ -187,7 +198,7 @@ def jitted_kernels() -> dict:
             # constants, so a steady-state replay compiles exactly once
             "fused_epoch": _device_obs.observe_jit(
                 jax.jit(
-                    functools.partial(fused_epoch_kernel, jnp),
+                    bound("fused_epoch", fused_epoch_kernel),
                     # bias, recovery, weights, weight_denominator,
                     # leaking, head/target flag indices
                     static_argnums=(11, 12, 13, 14, 15, 16, 17),
@@ -1109,7 +1120,11 @@ def _fused_route(ec, leaking: bool) -> bool:
                 int(TIMELY_HEAD_FLAG_INDEX),
                 _TIMELY_TARGET_FLAG_INDEX,
             )
-            if int(wrapped):
+            # the first point that blocks on the kernel (and on whatever
+            # of the upload its dispatch did not wait for)
+            with trace.span("epoch_vector.fused.wait"):
+                wrapped = int(wrapped)
+            if wrapped:
                 _fused_fallback(ec, "wrap_guard", validators=ec.n)
                 return False
             new_scores = _device_obs.d2h("epoch_vector.fused", scores)
@@ -1579,7 +1594,9 @@ def process_epoch_columnar(state, context, fork: str) -> bool:
     except Exception:  # noqa: BLE001 — constants unavailable/mismatched
         fallback("constants")
         return False
-    ec = _sync(state, context, fork)
+    # the build of the working columns, before the pass opens
+    with trace.span("epoch_vector.sync", validators=n):
+        ec = _sync(state, context, fork)
     if ec is None:
         return False
     cfg = ec.cfg

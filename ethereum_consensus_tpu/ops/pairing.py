@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..telemetry import device as _obs
+from ..utils import trace
 from . import fq2, fq12, fql
 from .fql import LV
 
@@ -700,18 +701,23 @@ def batch_verify_device(
     pk_scalars = _pad_pow2(list(scalars), 1)
     sig_scalars = list(scalars) + [0] * (len(pk_padded) - n)
 
-    pk_jac = _g1_jac_from_affine_raws(pk_padded)
+    # raw bytes to R'-Montgomery columns on the device: three uploads and
+    # the eager field conversions, op by op, before any batched kernel
+    with trace.span("pairing.convert", sets=n, lanes=len(pk_padded)):
+        pk_jac = _g1_jac_from_affine_raws(pk_padded)
+        xq, yq = g2_affine_from_raw(h_padded)
+        sx, sy = g2_affine_from_raw(sig_padded)
+        one2 = jnp.broadcast_to(
+            jnp.asarray(
+                np.stack([fql.to_mont_cols(1), np.zeros(24, np.uint64)])
+            ),
+            sy.arr.shape,
+        )
+        sig_jac = _env(jnp.stack([sx.arr, sy.arr, one2], axis=-3))
+
     pk_blinded = g1_mul_batched(pk_jac, pk_scalars, bits=128)
     xp, yp = _g1_jacobian_to_affine(pk_blinded.arr)
 
-    xq, yq = g2_affine_from_raw(h_padded)
-
-    sx, sy = g2_affine_from_raw(sig_padded)
-    one2 = jnp.broadcast_to(
-        jnp.asarray(np.stack([fql.to_mont_cols(1), np.zeros(24, np.uint64)])),
-        sy.arr.shape,
-    )
-    sig_jac = _env(jnp.stack([sx.arr, sy.arr, one2], axis=-3))
     sig_sum = g2_sum_points(g2_mul_batched(sig_jac, sig_scalars, bits=128))
     s_raw, s_inf = _g2_point_to_raw(sig_sum)
 

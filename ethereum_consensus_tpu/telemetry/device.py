@@ -21,11 +21,12 @@ module closes that: one process-wide ``DeviceObservatory`` recording
   drifted signature;
 * a **transfer ledger** — host→device and device→host transfer counts
   and bytes aggregated per call site (``device.transfer.{h2d,d2h}_
-  {count,bytes}`` registry counters + per-site totals), with
-  per-transfer spans on a dedicated ``device`` virtual lane in the
-  Chrome-trace export (telemetry/spans.py ``named_lane``) so Perfetto
-  renders the device traffic alongside the pipeline/verifier thread
-  tracks;
+  {count,bytes}`` registry counters + per-site totals). The time of a
+  copy is not the ledger's: the seams open a facade span
+  ``<site>.h2d`` / ``<site>.d2h`` (``bytes=``) around it on the thread
+  that pays it, whatever the observatory's state, so it reaches every
+  sink of ``utils/trace.span`` (the recorder, the profiler's trace, the
+  ``span.<name>.*`` totals) nested under the caller's span;
 * a **routing journal** — every device-vs-host decision (the
   ``_device_flags`` threshold gates, the BLS pairing route, the
   ``epoch_vector`` engage/decline) with its choice, reason, and
@@ -36,7 +37,8 @@ module closes that: one process-wide ``DeviceObservatory`` recording
 Cost discipline (the spans/commit-hook contract): ``OBSERVATORY.active``
 is a plain bool read — instrumented call sites check it FIRST and pay
 nothing else while the observatory is off (guarded by the overhead test
-in tests/test_device_observatory.py). Everything here is stdlib-only;
+in tests/test_device_observatory.py); the transfer seams besides pay one
+disabled facade span a copy. Everything here is stdlib-only;
 jax is never imported by this module (the instrumented seams already
 have it).
 
@@ -214,9 +216,10 @@ class DeviceObservatory:
 
     # -- transfer ledger -----------------------------------------------------
     def record_transfer(self, site: str, direction: str, count: int,
-                        nbytes: int, t0: float, t1: float) -> None:
+                        nbytes: int) -> None:
         """One host<->device transfer at ``site`` (``direction`` is
-        ``h2d`` or ``d2h``)."""
+        ``h2d`` or ``d2h``): counts and bytes; the seam's facade span
+        has the time."""
         with self._lock:
             agg = self._transfers.get(site)
             if agg is None:
@@ -228,15 +231,6 @@ class DeviceObservatory:
             agg[f"{direction}_bytes"] += nbytes
         _metrics.counter(f"device.transfer.{direction}_count").inc(count)
         _metrics.counter(f"device.transfer.{direction}_bytes").inc(nbytes)
-        rec = _spans.RECORDER
-        if rec.enabled:
-            rec.add_complete(
-                f"device.{direction}",
-                t0,
-                t1,
-                {"site": site, "bytes": nbytes, "count": count},
-                lane=rec.named_lane(_DEVICE_LANE),
-            )
 
     # -- routing journal -----------------------------------------------------
     def record_route(self, kind: str, choice: str, reason: str,
@@ -430,6 +424,15 @@ def _np():
     return numpy
 
 
+@functools.lru_cache(maxsize=1)
+def _span():
+    """The facade's ``span``, resolved once: ``utils/trace.py`` imports
+    this package, so the import cannot stand at the top."""
+    from ..utils.trace import span
+
+    return span
+
+
 def _nbytes(a) -> int:
     n = getattr(a, "nbytes", None)
     if n is not None:
@@ -440,23 +443,25 @@ def _nbytes(a) -> int:
         return 0
 
 
-def h2d(site: str, *arrays):
-    """``jnp.asarray`` every argument (the repo's host→device seam),
-    recording count/bytes/seconds against ``site`` while observing.
-    Returns a single array for a single argument, a tuple otherwise.
-    On the CPU backend the "transfer" may be a zero-copy view — the
-    ledger measures the dispatch seam, which on a real accelerator IS
-    the PCIe/ICI transfer."""
-    jnp = _jnp()
+def _ledger(site: str, direction: str, count: int, nbytes: int) -> None:
     obs = OBSERVATORY
-    if not obs.active:
-        out = tuple(jnp.asarray(a) for a in arrays)
-        return out[0] if len(out) == 1 else out
+    if obs.active:
+        obs.record_transfer(site, direction, count, nbytes)
+
+
+def h2d(site: str, *arrays):
+    """``jnp.asarray`` every argument (the repo's host→device seam)
+    inside a facade span ``<site>.h2d``, recording count/bytes against
+    ``site`` while observing. Returns a single array for a single
+    argument, a tuple otherwise. On the CPU backend the "transfer" may
+    be a zero-copy view, and on an accelerator the copy may outlive the
+    call — the span times the dispatch seam; whoever reads the result
+    first pays the rest."""
+    jnp = _jnp()
     nbytes = sum(_nbytes(a) for a in arrays)
-    t0 = time.perf_counter()
-    out = tuple(jnp.asarray(a) for a in arrays)
-    t1 = time.perf_counter()
-    obs.record_transfer(site, "h2d", len(out), nbytes, t0, t1)
+    with _span()(site + ".h2d", bytes=nbytes):
+        out = tuple(jnp.asarray(a) for a in arrays)
+    _ledger(site, "h2d", len(out), nbytes)
     return out[0] if len(out) == 1 else out
 
 
@@ -464,33 +469,27 @@ def h2d_put(site: str, arrays, sharding=None):
     """``jax.device_put`` with an explicit sharding — the sharded-mesh
     twin of ``h2d``, and the ONLY sanctioned way to place host buffers
     onto a mesh (speclint's transfer-seam rule points every raw
-    ``device_put`` here). Takes an iterable so one ledger entry covers
-    the whole staged argument tuple; returns the placed tuple."""
+    ``device_put`` here). Takes an iterable so one span and one ledger
+    entry cover the whole staged argument tuple; returns the placed
+    tuple."""
     import jax
 
     arrays = tuple(arrays)
-    obs = OBSERVATORY
-    if not obs.active:
-        return tuple(jax.device_put(a, sharding) for a in arrays)
     nbytes = sum(_nbytes(a) for a in arrays)
-    t0 = time.perf_counter()
-    out = tuple(jax.device_put(a, sharding) for a in arrays)
-    t1 = time.perf_counter()
-    obs.record_transfer(site, "h2d", len(out), nbytes, t0, t1)
+    with _span()(site + ".h2d", bytes=nbytes):
+        out = tuple(jax.device_put(a, sharding) for a in arrays)
+    _ledger(site, "h2d", len(out), nbytes)
     return out
 
 
 def d2h(site: str, array):
-    """``np.asarray`` the device value (the device→host seam),
-    recording against ``site`` while observing."""
+    """``np.asarray`` the device value (the device→host seam) inside a
+    facade span ``<site>.d2h``, recording against ``site`` while
+    observing. The copy waits for whatever computes ``array``."""
     np = _np()
-    obs = OBSERVATORY
-    if not obs.active:
-        return np.asarray(array)
-    t0 = time.perf_counter()
-    out = np.asarray(array)
-    t1 = time.perf_counter()
-    obs.record_transfer(site, "d2h", 1, _nbytes(out), t0, t1)
+    with _span()(site + ".d2h", bytes=_nbytes(array)):
+        out = np.asarray(array)
+    _ledger(site, "d2h", 1, _nbytes(out))
     return out
 
 

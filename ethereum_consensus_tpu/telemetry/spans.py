@@ -54,6 +54,21 @@ full as before, but no longer silently: ``dropped`` counts evictions
 and mirrors to the ``spans.dropped`` counter. Completed traces noted
 via ``note_trace`` feed a bounded worst-N slow-trace ring — the
 ``/trace`` endpoint's index (docs/OBSERVABILITY.md).
+
+**The profiler sink.** ``profiler_annotation()`` is the facade's second
+timing sink: while a ``jax.profiler`` session is live it returns
+``jax.profiler.TraceAnnotation`` and the facade opens ``"ect:" + name``
+on the calling thread, so program spans land in the xplane on the
+device events' clock. The session is the switch; this module never
+imports jax (it looks in ``sys.modules``), so a host-only process pays
+one dict lookup a span.
+
+**Per-name totals.** While either timing sink is on, ``end`` adds the
+span to three integer counters of the metrics registry:
+``span.<name>.n``, ``span.<name>.ns`` and ``span.<name>.self_ns``
+(``ns`` minus what child spans on the same thread covered: the
+per-thread stack that parents spans also carries each open span's
+children total). They do not move while no sink is on.
 """
 
 from __future__ import annotations
@@ -61,16 +76,21 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
+
+from . import metrics as _metrics
 
 __all__ = [
     "SpanRecord",
     "SpanRecorder",
     "TraceContext",
     "RECORDER",
+    "PROFILER_PREFIX",
+    "profiler_annotation",
     "DEFAULT_CAPACITY",
     "SLOW_TRACE_RING",
     "is_recording",
@@ -84,6 +104,21 @@ DEFAULT_CAPACITY = 1 << 16
 
 # worst-N slow-trace ring size (completed traces, by duration)
 SLOW_TRACE_RING = 32
+
+# what a reduction of the xplane filters program spans on: the host plane
+# also holds jax's own TraceMes and the benchmark's ``bench:`` spans
+PROFILER_PREFIX = "ect:"
+
+
+def profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is live,
+    else None. Never imports jax: a session can only be live where
+    ``jax.profiler`` is already in ``sys.modules``."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation
+    return annotation if annotation.is_enabled() else None
 
 
 class TraceContext:
@@ -127,6 +162,8 @@ class SpanRecord:
         "fields",
         "error",
         "flow_src",
+        "child_ns",
+        "ring",
     )
 
     def __init__(self, span_id: int, parent_id: int, name: str, lane: int,
@@ -145,6 +182,12 @@ class SpanRecord:
         # (src_span_id, src_lane, src_ts) when this span was begun under
         # a context adopted from another lane — the flow-arrow source
         self.flow_src = None
+        # the nanoseconds this span's ended children (same thread)
+        # covered: what ``span.<name>.self_ns`` subtracts
+        self.child_ns = 0
+        # whether the ring keeps it (recording on at begin), or only the
+        # totals do (a profiler session alone)
+        self.ring = True
 
     @property
     def duration_s(self) -> float:
@@ -183,6 +226,7 @@ class SpanRecorder:
         self._t0 = 0.0                # perf_counter origin of the recording
         self._wall0 = 0.0             # wall-clock at start (metadata only)
         self._slow: list = []         # worst-N completed traces, ascending
+        self._totals: dict = {}       # span name -> its three counters
         self.dropped = 0              # ring evictions (spans + events)
         self.enabled = False
 
@@ -231,13 +275,18 @@ class SpanRecorder:
         return stack
 
     def begin(self, name: str, fields: dict) -> SpanRecord:
+        """Open a span on this thread's stack. The facade calls this
+        while either timing sink is on; the ring keeps the record only
+        if recording was on here, the per-name totals count it either
+        way."""
         stack = self._stack()
         lane = self._lane()
         flow_src = None
-        if stack:
+        parent = stack[-1] if stack else None
+        if parent is not None:
             # in-thread nesting wins: parent is the enclosing span
-            parent_id = stack[-1].span_id
-            trace_id = stack[-1].trace_id
+            parent_id = parent.span_id
+            trace_id = parent.trace_id
         else:
             ctx = getattr(self._tls, "adopted", None)
             if ctx is not None:
@@ -259,6 +308,7 @@ class SpanRecorder:
             trace_id=trace_id,
         )
         rec.flow_src = flow_src
+        rec.ring = self.enabled
         stack.append(rec)
         return rec
 
@@ -275,7 +325,28 @@ class SpanRecorder:
                 stack.remove(rec)
             except ValueError:
                 pass
-        self._append_span(rec)
+        ns = max(0, round((rec.t1 - rec.t0) * 1e9))
+        if stack:  # what is now on top encloses this span
+            stack[-1].child_ns += ns
+        count, total, own = self._span_totals(rec.name)
+        count.inc()
+        total.inc(ns)
+        own.inc(max(0, ns - rec.child_ns))
+        if rec.ring:
+            self._append_span(rec)
+
+    def _span_totals(self, name: str) -> tuple:
+        """The ``span.<name>.{n,ns,self_ns}`` counters, looked up once a
+        name."""
+        totals = self._totals.get(name)
+        if totals is None:
+            totals = tuple(
+                _metrics.counter(f"span.{name}.{what}")
+                for what in ("n", "ns", "self_ns")
+            )
+            with self._lock:
+                self._totals[name] = totals
+        return totals
 
     def _append_span(self, rec: SpanRecord) -> None:
         dropped = False
@@ -285,8 +356,6 @@ class SpanRecorder:
                 dropped = True
             self._spans.append(rec)
         if dropped:
-            from . import metrics as _metrics
-
             _metrics.counter("spans.dropped").inc()
 
     def event(self, name: str, fields: dict) -> None:
@@ -301,8 +370,6 @@ class SpanRecorder:
                 dropped = True
             self._events.append(rec)
         if dropped:
-            from . import metrics as _metrics
-
             _metrics.counter("spans.dropped").inc()
 
     # -- causal trace plane --------------------------------------------------

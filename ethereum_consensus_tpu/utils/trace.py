@@ -2,7 +2,7 @@
 
 Reference parity: the reference pulls in the `tracing` crate as a facade in
 its API client (beacon-api-client/Cargo.toml:21, examples/sse.rs:4-20); the
-core library emits nothing. Here the same facade fans out to two sinks:
+core library emits nothing. Here the same facade fans out to three sinks:
 
 * the **logging sink** (stdlib ``logging``, silent unless the application
   installs a handler — ``basic_setup`` for the examples/CLIs), exactly the
@@ -10,12 +10,21 @@ core library emits nothing. Here the same facade fans out to two sinks:
   works unchanged;
 * the **span recorder** (``telemetry/spans.py``), an in-process ring
   buffer with Chrome-trace export, active only between
-  ``telemetry.spans.start_recording()``/``stop_recording()``.
+  ``telemetry.spans.start_recording()``/``stop_recording()``;
+* the **profiler sink**: while a ``jax.profiler`` session is live, every
+  span also opens ``jax.profiler.TraceAnnotation("ect:" + name,
+  **fields)`` on the calling thread, so it lands in the xplane's
+  ``/host:CPU`` plane on the device events' clock, nested as it was
+  opened. The session is the switch: nothing to turn on here
+  (``telemetry.spans.profiler_annotation``, which never imports jax).
 
-When neither sink is active (the default), ``span`` takes a fast path
+While the recorder or the profiler sink is on, the end of a span also
+adds to the ``span.<name>.{n,ns,self_ns}`` counters (telemetry/spans.py).
+
+When no sink is active (the default), ``span`` takes a fast path
 that does no formatting, no recording, and no timestamp bookkeeping
 beyond one ``perf_counter`` read kept for the error log — the disabled
-cost is guarded by tests/test_telemetry.py's overhead test.
+cost is guarded by tests/test_telemetry.py's overhead tests.
 
 Usage::
 
@@ -53,6 +62,8 @@ logger.addHandler(logging.NullHandler())
 
 _RECORDER = _spans.RECORDER
 _MEMORY = _memory.OBSERVATORY
+_PROFILER_PREFIX = _spans.PROFILER_PREFIX
+_profiler_annotation = _spans.profiler_annotation
 
 
 def _fmt_fields(fields: dict) -> str:
@@ -63,10 +74,13 @@ def _fmt_fields(fields: dict) -> str:
 def span(name: str, **fields):
     """A timed span, delivered to every active sink: the logging sink
     (DEBUG on enter, INFO with elapsed ms on exit, ERROR with the
-    exception if the body raises) and, while recording, the telemetry
-    span recorder (thread lane, parent span, wall window, fields)."""
+    exception if the body raises), while recording the telemetry span
+    recorder (thread lane, parent span, wall window, fields), and while
+    a ``jax.profiler`` session is live the profiler's trace."""
+    annotate = _profiler_annotation()
+    timed = annotate is not None or _RECORDER.enabled
     if not (
-        _RECORDER.enabled
+        timed
         or _MEMORY.active
         or logger.isEnabledFor(logging.INFO)
     ):
@@ -83,26 +97,30 @@ def span(name: str, **fields):
             )
             raise
         return
-    rec = _RECORDER.begin(name, fields) if _RECORDER.enabled else None
+    # the recorder's per-thread stack serves both timing sinks: it parents
+    # the ring's records and carries the children's time for self_ns
+    rec = _RECORDER.begin(name, fields) if timed else None
     # the memory observatory brackets the transition/epoch phase spans
     # into its RSS ledger (telemetry/memory.py PHASE_PREFIXES); every
     # other span costs it one prefix check
     mem = _MEMORY.phase_begin(name) if _MEMORY.active else None
     if logger.isEnabledFor(logging.DEBUG):
         logger.debug("enter %s %s", name, _fmt_fields(fields))
+    annotation = None
+    if annotate is not None:
+        annotation = annotate(_PROFILER_PREFIX + name, **fields)
+        annotation.__enter__()
     start = time.perf_counter()
+    error = None
     try:
         yield
     except Exception as exc:
+        error = repr(exc)
         logger.error(
             "abort %s %s error=%r elapsed_ms=%.2f",
             name, _fmt_fields(fields), exc,
             (time.perf_counter() - start) * 1e3,
         )
-        if rec is not None:
-            _RECORDER.end(rec, error=repr(exc))
-        if mem is not None:
-            _MEMORY.phase_end(name, mem)
         raise
     else:
         if logger.isEnabledFor(logging.INFO):
@@ -110,8 +128,11 @@ def span(name: str, **fields):
                 "exit %s %s elapsed_ms=%.2f",
                 name, _fmt_fields(fields), (time.perf_counter() - start) * 1e3,
             )
+    finally:
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         if rec is not None:
-            _RECORDER.end(rec)
+            _RECORDER.end(rec, error=error)
         if mem is not None:
             _MEMORY.phase_end(name, mem)
 

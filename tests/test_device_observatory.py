@@ -1,6 +1,7 @@
 """Device execution observatory (telemetry/device.py): compile ledger +
 recompile sentinel, host<->device transfer ledger, device-vs-host
-routing journal, the Chrome-trace device lane, the /device endpoint,
+routing journal, the Chrome-trace device lane, the transfer seams' facade
+spans, the /device endpoint,
 BlockLineage.verify_route, and the off-path overhead guard."""
 
 import json
@@ -174,25 +175,51 @@ def test_transfer_ledger_counts_and_bytes_per_site():
     assert summary["totals"]["h2d_bytes"] >= 800
 
 
-def test_transfers_render_on_the_device_lane():
+def test_transfers_are_facade_spans_under_the_callers_span():
+    """A copy through the seams is a facade span ``<site>.h2d`` /
+    ``<site>.d2h`` with ``bytes=``, on the thread that paid it and
+    nested under the caller's span: not a pre-timed interval on the
+    virtual ``device`` lane."""
     pytest.importorskip("jax")
+    from ethereum_consensus_tpu.utils import trace
+
     arr = np.arange(64, dtype=np.uint64)
     spans.start_recording()
-    with device_obs.observing():
-        device_obs.d2h("lane.site", device_obs.h2d("lane.site", arr))
-    doc = spans.RECORDER.chrome_trace()
-    spans.stop_recording()
-    assert "device" in _lane_names(doc)
-    device_lane = next(
-        e["tid"] for e in doc["traceEvents"]
-        if e.get("ph") == "M" and e.get("name") == "thread_name"
-        and e["args"]["name"] == "device"
-    )
-    h2d_spans = [e for e in doc["traceEvents"]
-                 if e.get("ph") == "X" and e["name"] == "device.h2d"
-                 and e["tid"] == device_lane]
-    assert h2d_spans and h2d_spans[0]["args"]["site"] == "lane.site"
-    assert h2d_spans[0]["args"]["bytes"] == arr.nbytes
+    try:
+        with device_obs.observing():
+            with trace.span("lane.caller"):
+                device_obs.d2h("lane.site", device_obs.h2d("lane.site", arr))
+        records = spans.RECORDER.records()
+        doc = spans.RECORDER.chrome_trace()
+    finally:
+        spans.stop_recording()
+    by_name = {r.name: r for r in records}
+    caller = by_name["lane.caller"]
+    for name in ("lane.site.h2d", "lane.site.d2h"):
+        rec = by_name[name]
+        assert rec.parent_id == caller.span_id and rec.lane == caller.lane
+        assert rec.fields["bytes"] == arr.nbytes
+        assert caller.t0 <= rec.t0 and rec.t1 <= caller.t1
+    assert "device" not in _lane_names(doc)
+    assert not [e for e in doc["traceEvents"]
+                if e.get("name") in ("device.h2d", "device.d2h")]
+
+
+def test_transfer_spans_need_no_observatory():
+    """The seams' spans follow the facade's sinks, not the observatory:
+    with the ledger off the copy is still timed (and the byte tallies
+    stay where they were)."""
+    pytest.importorskip("jax")
+    assert not device_obs.is_observing()
+    h2d_bytes0 = _metric("device.transfer.h2d_bytes")
+    n0 = _metric("span.quiet.site.h2d.n")
+    with spans.recording() as recorder:
+        (placed,) = device_obs.h2d_put("quiet.site", [np.arange(8)])
+        device_obs.d2h("quiet.site", placed)
+        names = sorted(r.name for r in recorder.records())
+    assert names == ["quiet.site.d2h", "quiet.site.h2d"]
+    assert _metric("span.quiet.site.h2d.n") == n0 + 1
+    assert _metric("device.transfer.h2d_bytes") == h2d_bytes0
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +326,8 @@ def test_pipelined_replay_trace_has_device_lane_and_verify_route():
     """A pipelined replay with recording on, crossing an epoch boundary
     with the device sweeps installed (host JAX backend here — the same
     machinery chip_smoke.py drives on the chip), yields a Chrome
-    trace whose `device` lane carries compile AND transfer events; the
+    trace whose `device` lane carries the compiles and whose thread
+    lanes carry the transfers as facade spans inside the epoch pass; the
     flight lineage of every flushed block names the pairing route that
     verified its window."""
     pytest.importorskip("jax")
@@ -346,10 +374,22 @@ def test_pipelined_replay_trace_has_device_lane_and_verify_route():
     )
     by_name = {}
     for e in doc["traceEvents"]:
-        if e.get("ph") == "X" and e.get("tid") == device_lane:
+        if e.get("ph") == "X":
             by_name.setdefault(e["name"], []).append(e)
-    assert by_name.get("device.compile"), "no compile events on the lane"
-    assert by_name.get("device.h2d"), "no h2d transfer events on the lane"
+    compiles_on_lane = by_name.get("device.compile", [])
+    assert compiles_on_lane, "no compile events"
+    assert all(e["tid"] == device_lane for e in compiles_on_lane)
+    # the sweeps' uploads: facade spans from the h2d seam on the thread
+    # that ran the epoch stage, inside its span, with the bytes moved
+    by_id = {e["args"]["span_id"]: e for es in by_name.values() for e in es}
+    uploads = [e for name, es in by_name.items() if name.endswith(".h2d")
+               for e in es]
+    assert uploads, "no transfer span from the h2d seam"
+    for upload in uploads:
+        assert upload["name"].startswith("ops.sweeps.")
+        parent = by_id[upload["args"]["parent_id"]]
+        assert parent["tid"] == upload["tid"] != device_lane
+        assert upload["args"]["bytes"] > 0
 
     # lineage: every committed block that rode a non-empty flush window
     # carries the route that verified it (host on this box)

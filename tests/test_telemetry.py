@@ -289,10 +289,18 @@ def test_disabled_recording_overhead_within_threshold(monkeypatch):
     )
 
 
+def _span_totals():
+    return {k: v for k, v in metrics.snapshot().items()
+            if k.startswith("span.")}
+
+
 def test_disabled_span_microcost():
     """Absolute sanity bound on one disabled span (not a benchmark — a
-    regression tripwire: the disabled path must stay allocation-light)."""
+    regression tripwire: the disabled path must stay allocation-light),
+    and no ``span.*`` total moves while no sink is on."""
     assert not spans.RECORDER.enabled
+    assert spans.profiler_annotation() is None
+    totals0 = _span_totals()
     n = 20_000
     t0 = time.perf_counter()
     for _ in range(n):
@@ -300,6 +308,32 @@ def test_disabled_span_microcost():
             pass
     per_span = (time.perf_counter() - t0) / n
     assert per_span < 50e-6, f"{per_span * 1e6:.1f}µs per disabled span"
+    assert totals0 == _span_totals()
+
+
+def _best_per_call(fn, n=20_000, reps=7):
+    best = None
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            fn()
+        elapsed = (time.perf_counter() - t0) / n
+        best = elapsed if best is None or elapsed < best else best
+    return best
+
+
+def test_profiler_sink_check_fits_its_budget():
+    """The one check the profiler sink adds to the disabled path of
+    ``span`` (is a jax.profiler session live?) has a budget of 0.5 µs a
+    span, with jax imported and without: it never imports jax, it looks
+    in ``sys.modules`` and asks the profiler."""
+    pytest.importorskip("jax")
+    import jax.profiler  # noqa: F401  the dearer case: the module is there
+
+    assert spans.profiler_annotation() is None
+    loop = _best_per_call(lambda: None)
+    check = _best_per_call(spans.profiler_annotation) - loop
+    assert check < 0.5e-6, f"{check * 1e6:.2f}µs per profiler-sink check"
 
 
 # ---------------------------------------------------------------------------

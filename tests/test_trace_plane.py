@@ -245,10 +245,16 @@ def test_trace_endpoint_round_trip(live_server):
 
 
 def test_trace_endpoint_joins_device_evidence_from_both_rings(live_server):
-    """The ?id= device join: pre-timed device spans (completed ring)
-    AND device.route instants (events ring) land in ``device``, with
-    stamps rebased onto the recording origin so they sit inside the
-    trace's relative window."""
+    """The ?id= device join: pre-timed device spans (completed ring: a
+    compile) AND device.route instants (events ring) land in ``device``,
+    with stamps rebased onto the recording origin so they sit inside the
+    trace's relative window. A transfer is no longer on that lane: the
+    seam's facade span is a node of the trace's own tree, under the span
+    that paid for the copy."""
+    pytest.importorskip("jax")
+    np = pytest.importorskip("numpy")
+    from ethereum_consensus_tpu.telemetry import device as device_obs
+
     client = _client(live_server)
     with spans.recording(capacity=spans.DEFAULT_CAPACITY):
         recorder = spans.RECORDER
@@ -257,10 +263,10 @@ def test_trace_endpoint_joins_device_evidence_from_both_rings(live_server):
             ctx = trace.context()
             now = time.perf_counter()
             recorder.add_complete(
-                "device.h2d",
+                "device.compile",
                 now,
                 now + 1e-4,
-                {"site": "devjoin", "bytes": 8, "count": 1},
+                {"fn": "devjoin", "signature": "()", "recompile": False},
                 lane=lane,
             )
             recorder.add_instant(
@@ -269,15 +275,21 @@ def test_trace_endpoint_joins_device_evidence_from_both_rings(live_server):
                 {"kind": "verify", "choice": "device", "reason": "fits"},
                 lane=lane,
             )
+            device_obs.h2d("devjoin", np.arange(8, dtype=np.uint8))
         tree = client.get_trace(ctx.trace_id)
         names = [e["name"] for e in tree["device"]]
-        assert names == ["device.h2d", "device.route"]
+        assert names == ["device.compile", "device.route"]
         assert tree["device_count"] == 2
         t_lo = tree["t0_s"]
         t_hi = t_lo + tree["duration_s"]
         for event in tree["device"]:
             assert t_lo <= event["t0_s"] <= t_hi
         assert tree["device"][0]["duration_s"] == pytest.approx(1e-4)
+        by_name = {s["name"]: s for s in tree["spans"]}
+        upload = by_name["devjoin.h2d"]
+        assert upload["parent_id"] == by_name["pool.admit"]["span_id"]
+        assert upload["fields"]["bytes"] == 8
+        assert tree["connected"]
 
 
 def test_metrics_scrape_stays_classic_while_exemplars_live_on_trace(
