@@ -81,6 +81,8 @@ __all__ = [
     "flag_deltas_kernel",
     "apply_delta_pairs_kernel",
     "fused_epoch_kernel",
+    "u32_planes",
+    "u64_columns",
     "jitted_kernels",
     "EPOCH_VECTOR_MIN_VALIDATORS",
 ]
@@ -149,7 +151,10 @@ def jitted_kernels() -> dict:
     the device route, its compile/recompile telemetry, and the
     jit-identity tests all exercise the SAME wrapped callables. Returns
     ``{"inactivity_scores": fn, "flag_deltas": fn, "apply_delta_pairs":
-    fn}``; built once per process."""
+    fn, "fused_epoch": fn}``; built once per process. ``fused_epoch``
+    alone does not return its kernel's tuple: its two u64 columns leave
+    the program as their 32-bit words (``u32_planes``), which is the
+    form the host takes them off the device in."""
     if _JITTED_KERNELS:
         return _JITTED_KERNELS
     with _JITTED_KERNELS_LOCK:
@@ -171,6 +176,15 @@ def jitted_kernels() -> dict:
 
             run.__name__ = run.__qualname__ = name
             return run
+
+        def fused_epoch(*args):
+            """``fused_epoch_kernel`` with its two result columns split
+            into their 32-bit words inside the same program: the chip
+            has no 64-bit lanes, and a 64-bit array comes off it twenty
+            times slower a byte than the same bytes as u32 (PERF.md
+            section 7 (c))."""
+            scores, balances, wrapped = fused_epoch_kernel(jnp, *args)
+            return u32_planes(jnp, scores, balances), wrapped
 
         built = {
             "inactivity_scores": _device_obs.observe_jit(
@@ -198,7 +212,7 @@ def jitted_kernels() -> dict:
             # constants, so a steady-state replay compiles exactly once
             "fused_epoch": _device_obs.observe_jit(
                 jax.jit(
-                    bound("fused_epoch", fused_epoch_kernel),
+                    fused_epoch,
                     # bias, recovery, weights, weight_denominator,
                     # leaking, head/target flag indices
                     static_argnums=(11, 12, 13, 14, 15, 16, 17),
@@ -395,6 +409,36 @@ def fused_epoch_kernel(xp, balances, eff, prev_part, slashed, active_prev,
     if psum is not None:
         wrapped = psum(wrapped)
     return new_scores, new_balances, wrapped
+
+
+def u32_planes(xp, *columns):
+    """u64 columns as their 32-bit words, ``uint32[2 * len(columns), n]``:
+    each column's low plane, then its high plane. The form
+    ``jitted_kernels()['fused_epoch']`` returns its results in;
+    ``u64_columns`` is the inverse on the host."""
+    low_word = xp.uint64(0xFFFFFFFF)
+    planes = []
+    for column in columns:
+        planes.append((column & low_word).astype(xp.uint32))
+        planes.append((column >> xp.uint64(32)).astype(xp.uint32))
+    return xp.stack(planes)
+
+
+def u64_columns(planes) -> list:
+    """The host inverse of ``u32_planes``: one fresh, owned, contiguous
+    ``uint64[n]`` per (low, high) pair of rows, each plane written into
+    its strided half of the column's little-endian words (cheaper than
+    ``lo | hi << 32``, which makes two u64 temporaries)."""
+    np = _np()
+    n = planes.shape[1]
+    columns = []
+    for k in range(0, planes.shape[0], 2):
+        column = np.empty(n, dtype="<u8")
+        words = column.view("<u4").reshape(n, 2)
+        words[:, 0] = planes[k]
+        words[:, 1] = planes[k + 1]
+        columns.append(column)
+    return columns
 
 
 # ---------------------------------------------------------------------------
@@ -1106,7 +1150,7 @@ def _fused_route(ec, leaking: bool) -> bool:
                 ec.balances, ec.eff, ec.prev_part, ec.slashed,
                 ec.active_prev, ec.eligible, ec.inact,
             )
-            scores, balances, wrapped = ec.fused(
+            planes, wrapped = ec.fused(
                 *arrays,
                 jnp.uint64(increment),
                 jnp.uint64(brpi),
@@ -1120,6 +1164,9 @@ def _fused_route(ec, leaking: bool) -> bool:
                 int(TIMELY_HEAD_FLAG_INDEX),
                 _TIMELY_TARGET_FLAG_INDEX,
             )
+            # queue the results' copy behind the kernel now: it starts
+            # when the program ends, not when the host comes to ask
+            _device_obs.d2h_start(planes)
             # the first point that blocks on the kernel (and on whatever
             # of the upload its dispatch did not wait for)
             with trace.span("epoch_vector.fused.wait"):
@@ -1127,8 +1174,9 @@ def _fused_route(ec, leaking: bool) -> bool:
             if wrapped:
                 _fused_fallback(ec, "wrap_guard", validators=ec.n)
                 return False
-            new_scores = _device_obs.d2h("epoch_vector.fused", scores)
-            new_balances = _device_obs.d2h("epoch_vector.fused", balances)
+            planes = _device_obs.d2h("epoch_vector.fused", planes)
+            with trace.span("epoch_vector.fused.unpack"):
+                new_scores, new_balances = u64_columns(planes)
     except Exception as exc:  # noqa: BLE001 — host fallback
         _fused_fallback(
             ec, "device_unusable", error=repr(exc)[:160], validators=ec.n

@@ -482,6 +482,14 @@ def h2d_put(site: str, arrays, sharding=None):
     return out
 
 
+def d2h_start(array) -> None:
+    """Queue ``array``'s copy to the host behind whatever computes it
+    and return at once, so the copy overlaps what the caller does next.
+    No span and no ledger entry: the ``d2h`` that collects the copy
+    owns both."""
+    array.copy_to_host_async()
+
+
 def d2h(site: str, array):
     """``np.asarray`` the device value (the device→host seam) inside a
     facade span ``<site>.d2h``, recording against ``site`` while

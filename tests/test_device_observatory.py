@@ -222,6 +222,23 @@ def test_transfer_spans_need_no_observatory():
     assert _metric("device.transfer.h2d_bytes") == h2d_bytes0
 
 
+def test_a_started_download_is_one_span_and_one_ledger_entry():
+    """``d2h_start`` only queues the copy: no span and no ledger entry of
+    its own; the ``d2h`` that collects it owns both, once."""
+    pytest.importorskip("jax")
+    arr = np.arange(100, dtype=np.uint32).reshape(4, 25)  # 400 bytes
+    with device_obs.observing() as obs, spans.recording() as recorder:
+        placed = device_obs.h2d("started.site", arr)
+        device_obs.d2h_start(placed)
+        assert obs.transfer_summary()["sites"]["started.site"]["d2h_count"] == 0
+        back = device_obs.d2h("started.site", placed)
+        names = sorted(r.name for r in recorder.records())
+        site = obs.transfer_summary()["sites"]["started.site"]
+    assert np.array_equal(back, arr)
+    assert names == ["started.site.d2h", "started.site.h2d"]
+    assert site["d2h_count"] == 1 and site["d2h_bytes"] == 400
+
+
 # ---------------------------------------------------------------------------
 # routing journal
 # ---------------------------------------------------------------------------

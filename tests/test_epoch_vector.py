@@ -440,9 +440,13 @@ def test_kernels_jittable_bit_identical():
     assert np.array_equal(np.asarray(dev_bal), host_bal)
 
 
-def _fused_inputs(n=4097, seed=13):
+def _fused_inputs(n=4097, seed=13, high_words=False):
+    """``high_words``: every balance is over 2^32 gwei and the scores mix
+    small values with 2^32 + 1 and 2^63, so both result columns need both
+    of their 32-bit planes (a u64 product that wraps does so alike on the
+    host and under jit: the lane guard is the caller's)."""
     rng = np.random.default_rng(seed)
-    return dict(
+    k = dict(
         balances=rng.integers(0, 1 << 45, n, dtype=np.uint64),
         eff=rng.integers(1 << 30, 1 << 35, n, dtype=np.uint64),
         prev_part=rng.integers(0, 8, n, dtype=np.uint8),
@@ -451,17 +455,29 @@ def _fused_inputs(n=4097, seed=13):
         eligible=rng.random(n) < 0.96,
         scores=rng.integers(0, 1 << 20, n, dtype=np.uint64),
     )
+    if high_words:
+        k["balances"] += np.uint64(1 << 33)
+        k["scores"][1::3] = (1 << 32) + 1
+        k["scores"][2::3] = 1 << 63
+    return k
 
 
+# the second and third shapes are no multiple of 128 rows (the chip's
+# lane width) and fill the high words of both columns
+@pytest.mark.parametrize(
+    "n, high_words",
+    [(4097, False), (1000, True), (129, True)],
+    ids=["4097", "1000-high-words", "129-high-words"],
+)
 @pytest.mark.parametrize("leaking", [False, True])
-def test_fused_kernel_matches_staged_kernels_and_jit(leaking):
+def test_fused_kernel_matches_staged_kernels_and_jit(leaking, n, high_words):
     """The fused epoch kernel (ISSUE 14) must equal the staged kernels it
     collapses — inactivity update, three flag-delta pairs off in-kernel
     sums, inactivity penalties off post-update scores, in-order
     application — on host numpy AND bit-identically under jax.jit with
-    x64 (the jitted_kernels() discipline)."""
-    k = _fused_inputs()
-    n = k["balances"].shape[0]
+    x64 (the jitted_kernels() discipline), through the 32-bit planes the
+    jit route brings its two columns down as."""
+    k = _fused_inputs(n, high_words=high_words)
     increment, brpi = 10**9, 907
     weights, wd = (14, 26, 14), 64
     bias, recovery = 4, 16
@@ -511,14 +527,19 @@ def test_fused_kernel_matches_staged_kernels_and_jit(leaking):
     )
     assert np.array_equal(host_scores, staged_scores)
     assert np.array_equal(host_balances, staged_balances)
-    assert int(host_wrapped) == 0
+    if high_words:
+        # (outside a leak the recovery takes 2^32 + 1 back under 2^32)
+        for column in (staged_scores, staged_balances):
+            assert np.count_nonzero(column >> np.uint64(32)) > n // 4
+    else:
+        assert int(host_wrapped) == 0
 
     jax = pytest.importorskip("jax")
     jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
 
     fused = epoch_vector.jitted_kernels()["fused_epoch"]
-    dev_scores, dev_balances, dev_wrapped = fused(
+    planes, dev_wrapped = fused(
         jnp.asarray(k["balances"]), jnp.asarray(k["eff"]),
         jnp.asarray(k["prev_part"]), jnp.asarray(k["slashed"]),
         jnp.asarray(k["active_prev"]), jnp.asarray(k["eligible"]),
@@ -527,9 +548,38 @@ def test_fused_kernel_matches_staged_kernels_and_jit(leaking):
         jnp.uint64(active_increments), jnp.uint64(denominator),
         bias, recovery, weights, wd, leaking, 2, 1,
     )
-    assert np.array_equal(np.asarray(dev_scores), staged_scores)
-    assert np.array_equal(np.asarray(dev_balances), staged_balances)
-    assert int(dev_wrapped) == 0
+    assert planes.dtype == jnp.uint32 and planes.shape == (4, n)
+    dev_scores, dev_balances = epoch_vector.u64_columns(np.asarray(planes))
+    assert np.array_equal(dev_scores, staged_scores)
+    assert np.array_equal(dev_balances, staged_balances)
+    assert int(dev_wrapped) == int(host_wrapped)
+
+
+@pytest.mark.parametrize("n", [1, 127, 128, 1000])
+def test_u64_columns_rebuilds_what_u32_planes_split(n):
+    """The plane order is (low, high) per column, in column order; the
+    host side rebuilds fresh, owned, contiguous u64 columns from it."""
+    edges = np.array(
+        [0, 1, (1 << 32) - 1, 1 << 32, (1 << 32) + 1, 1 << 63, (1 << 64) - 1],
+        dtype=np.uint64,
+    )
+    rng = np.random.default_rng(n)
+    a = rng.integers(0, 1 << 64, n, dtype=np.uint64)
+    b = rng.integers(0, 1 << 64, n, dtype=np.uint64)
+    a[: len(edges)] = edges[:n]
+    b[: len(edges)] = edges[::-1][:n]
+    planes = epoch_vector.u32_planes(np, a, b)
+    assert planes.dtype == np.uint32 and planes.shape == (4, n)
+    for row, want in enumerate(
+        (a & 0xFFFFFFFF, a >> 32, b & 0xFFFFFFFF, b >> 32)
+    ):
+        assert np.array_equal(planes[row], want), row
+    got_a, got_b = epoch_vector.u64_columns(planes)
+    for got, want in ((got_a, a), (got_b, b)):
+        assert got.dtype == np.uint64 and got.shape == (n,)
+        assert got.flags.owndata and got.flags.c_contiguous
+        assert got.flags.writeable
+        assert np.array_equal(got, want)
 
 
 def test_fused_jit_route_bit_identical_through_the_pass(forced_engine,

@@ -154,11 +154,14 @@ def test_the_fused_route_shows_its_copies_and_its_wait(session):
     fused = _only(line, "ect:epoch_vector.fused")
     upload = _only(line, "ect:epoch_vector.fused.h2d")
     wait = _only(line, "ect:epoch_vector.fused.wait")
-    downloads = [e for e in line if e[0] == "ect:epoch_vector.fused.d2h"]
-    assert len(downloads) == 2  # scores, balances
+    # scores and balances come down as one uint32[4, n]: 16 B a row (the
+    # copy is started before the wait, so its span may find it done)
+    download = _only(line, "ect:epoch_vector.fused.d2h")
+    unpack = _only(line, "ect:epoch_vector.fused.unpack")
     assert fused[3]["route"] == "jit"
     assert int(upload[3]["bytes"]) > 0
-    order = [upload, wait, *downloads]
+    assert int(download[3]["bytes"]) == 16 * int(fused[3]["validators"]) > 0
+    order = [upload, wait, download, unpack]
     assert all(fused[1] <= e[1] and e[2] <= fused[2] for e in order)
     assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
 
@@ -189,6 +192,7 @@ def test_a_profiler_session_alone_moves_the_totals(session):
     for name in ("transition.process_epoch", "epoch_vector.sync",
                  "epoch_vector.pass", "epoch_vector.fused",
                  "epoch_vector.fused.h2d", "epoch_vector.fused.wait",
+                 "epoch_vector.fused.d2h", "epoch_vector.fused.unpack",
                  "epoch_vector.commit", "test.worker"):
         assert moved[f"span.{name}.n"] >= 1, name
         assert moved[f"span.{name}.ns"] > 0, name
