@@ -61,7 +61,13 @@ fallback + oracle.
 Telemetry: ``epoch_vector.epochs`` counts engaged passes,
 ``epoch_vector.fallback.{reason}`` every decline (one-shot trace event
 per reason), and per-stage spans (``epoch_vector.justification`` …
-``epoch_vector.commit``) give the bench its per-phase attribution.
+``epoch_vector.commit``) give the bench its per-phase attribution. What
+a pass did to the registry: ``epoch_vector.rows``, ``.rows_active``
+(where the churn limit was asked for), ``.registry.queued`` (rows whose
+eligibility epoch was stamped), ``.registry.activated`` (rows dequeued)
+and ``.validator_writes`` (validator fields written at commit); the
+registry span carries ``queued`` and ``activated``, the commit span
+``writes``.
 """
 
 from __future__ import annotations
@@ -1272,8 +1278,10 @@ def _registry_updates(ec) -> None:
     else:
         balance_rule = ec.eff == np.uint64(int(context.MAX_EFFECTIVE_BALANCE))
     queue_entry = (ec.elig == far) & balance_rule
-    if bool(queue_entry.any()):
+    queued = int(np.count_nonzero(queue_entry))
+    if queued:
         _own(ec, "elig")[queue_entry] = np.uint64(ec.cur + 1)
+        metrics.counter("epoch_vector.registry.queued").inc(queued)
 
     ejection = ec.active_cur & (
         ec.eff <= np.uint64(int(context.ejection_balance))
@@ -1292,24 +1300,22 @@ def _registry_updates(ec) -> None:
         ec.elig <= np.uint64(int(ec.state.finalized_checkpoint.epoch))
     ) & (ec.act == far)
     cand = np.nonzero(activatable)[0]
-    if cand.size == 0:
-        return
-    activation_epoch = np.uint64(
-        compute_activation_exit_epoch(ec.cur, context)
-    )
-    if ec.cfg["activation"] == "unbounded":
-        _own(ec, "act")[cand] = activation_epoch
-        return
-    # phase0..deneb: ascending (eligibility, index) queue, churn-capped
-    order = np.argsort(ec.elig[cand], kind="stable")
-    queue = cand[order]
-    limit = _churn_limit(ec)
-    if ec.cfg["activation"] == "activation_churn":
-        limit = min(
-            int(ec.context.max_per_epoch_activation_churn_limit), limit
+    if cand.size and ec.cfg["activation"] != "unbounded":
+        # phase0..deneb: ascending (eligibility, index) queue, churn-capped
+        limit = _churn_limit(ec)
+        if ec.cfg["activation"] == "activation_churn":
+            limit = min(
+                int(ec.context.max_per_epoch_activation_churn_limit), limit
+            )
+        order = np.argsort(ec.elig[cand], kind="stable")
+        cand = cand[order][:limit]
+    if cand.size:
+        _own(ec, "act")[cand] = np.uint64(
+            compute_activation_exit_epoch(ec.cur, context)
         )
-    if limit > 0:
-        _own(ec, "act")[queue[:limit]] = activation_epoch
+        metrics.counter("epoch_vector.registry.activated").inc(cand.size)
+    # what this boundary did to the queue, on the registry span's event
+    trace.note(queued=queued, activated=int(cand.size))
 
 
 def _initiate_exits_phase0(ec, indices) -> None:
@@ -1584,6 +1590,18 @@ def _commit(ec) -> None:
             writes += 1
         if writes:
             metrics.counter("epoch_vector.validator_writes").inc(writes)
+        trace.note(writes=writes)
+
+
+def _count_pass(ec) -> None:
+    """A finished pass in the registry's counters: the pass, the rows it
+    swept and, where a stage asked for it (the churn limit does, whenever
+    somebody waits in the activation queue), how many of them are active
+    this epoch. No sweep of its own."""
+    metrics.counter("epoch_vector.epochs").inc()
+    metrics.counter("epoch_vector.rows").inc(ec.n)
+    if ec._active_cur_count is not None:
+        metrics.counter("epoch_vector.rows_active").inc(ec._active_cur_count)
 
 
 class _PassComplete(Exception):
@@ -1698,7 +1716,7 @@ def process_epoch_columnar(state, context, fork: str) -> bool:
                 _effective_balance_updates(ec)
             _commit(ec)
         except _PassComplete:
-            metrics.counter("epoch_vector.epochs").inc()
+            _count_pass(ec)
             return True
         process_slashings_reset(state, context)
         process_randao_mixes_reset(state, context)
@@ -1748,5 +1766,5 @@ def process_epoch_columnar(state, context, fork: str) -> bool:
                 )
 
                 process_sync_committee_updates(state, context)
-    metrics.counter("epoch_vector.epochs").inc()
+    _count_pass(ec)
     return True

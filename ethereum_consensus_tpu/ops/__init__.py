@@ -80,9 +80,15 @@ def install(
     its host function (cross-checked in tests); the thresholds only decide
     where the work runs. Exact u64 arithmetic needs jax x64 mode, enabled
     here. The process-wide shuffle memo is dropped (as ``uninstall`` does),
-    so shuffles from here on take the installed route."""
+    so shuffles from here on take the installed route. The process's
+    allocator is told to keep what is freed (``utils/allocator.py``): the
+    host side of the epoch pass reuses its whole-registry temporaries
+    instead of faulting them in anew at every boundary."""
     import jax
 
+    from ..utils import allocator
+
+    allocator.keep_freed_memory()
     jax.config.update("jax_enable_x64", True)
     install_device_hasher(force=hasher_on_cpu)
     _device_flags.SWEEPS_MIN_N = sweeps_min_n

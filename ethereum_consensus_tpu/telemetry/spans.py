@@ -164,6 +164,7 @@ class SpanRecord:
         "flow_src",
         "child_ns",
         "ring",
+        "annotation",
     )
 
     def __init__(self, span_id: int, parent_id: int, name: str, lane: int,
@@ -188,6 +189,9 @@ class SpanRecord:
         # whether the ring keeps it (recording on at begin), or only the
         # totals do (a profiler session alone)
         self.ring = True
+        # the profiler's open annotation of this span, while a session is
+        # live: where ``note`` sends the fields the body learns late
+        self.annotation = None
 
     @property
     def duration_s(self) -> float:
@@ -311,6 +315,18 @@ class SpanRecorder:
         rec.ring = self.enabled
         stack.append(rec)
         return rec
+
+    def note(self, fields: dict) -> None:
+        """Add ``fields`` to the innermost span open on this thread: the
+        ring's record and, while a profiler session is live, the xplane's
+        event. Nothing is open where no timing sink is on."""
+        stack = getattr(self._tls, "stack", None)
+        if not stack:
+            return
+        rec = stack[-1]
+        rec.fields.update(fields)
+        if rec.annotation is not None:
+            rec.annotation.set_metadata(**fields)
 
     def end(self, rec: SpanRecord, error: "str | None" = None) -> None:
         rec.t1 = time.perf_counter()

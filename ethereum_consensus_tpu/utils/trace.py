@@ -47,6 +47,7 @@ from ..telemetry import spans as _spans
 __all__ = [
     "logger",
     "span",
+    "note",
     "event",
     "basic_setup",
     "TraceContext",
@@ -110,6 +111,7 @@ def span(name: str, **fields):
     if annotate is not None:
         annotation = annotate(_PROFILER_PREFIX + name, **fields)
         annotation.__enter__()
+        rec.annotation = annotation
     start = time.perf_counter()
     error = None
     try:
@@ -135,6 +137,14 @@ def span(name: str, **fields):
             _RECORDER.end(rec, error=error)
         if mem is not None:
             _MEMORY.phase_end(name, mem)
+
+
+def note(**fields) -> None:
+    """Fields that the innermost open span of this thread learns only in
+    its body (what a stage found, how many rows it wrote): they join the
+    span's record and its ``ect:`` event in a profiler trace. A no-op
+    while no timing sink is on."""
+    _RECORDER.note(fields)
 
 
 def event(name: str, **fields) -> None:

@@ -157,6 +157,120 @@ def test_columnar_epoch_bit_identity(fork, participation, forced_engine):
         assert_column_consistency(s_col, f"{fork} epoch {target_epoch}")
 
 
+def _mainnet_registry_world(seed):
+    """A registry of the benchmark's ``mainnet-deneb-2m`` composition at
+    2^13 entries (its groups times 2^-8: half the rows exited and
+    withdrawn, interleaved; slashed rows; an exit queue, an activation
+    queue and fresh deposits), on slot 63 with seeded participation, and a
+    refill of participation for each later epoch."""
+    import json
+
+    from benchmark import worlds
+
+    root = Path(__file__).parent.parent
+    with open(root / "benchmark/configs/mainnet-deneb-2m.json") as handle:
+        config = json.load(handle)
+    config["validators"] = 1 << 13
+    return worlds.build(
+        config,
+        {"kind": "mainnet_registry", "epoch": 1, "miss_share": [0.01, 0.03],
+         "chain_epochs": 6},
+        seed,
+    )
+
+
+@pytest.mark.parametrize("seed", [28, (1 << 31) + 28])
+def test_columnar_epoch_bit_identity_on_the_mainnet_registry(seed, forced_engine):
+    """``test_columnar_epoch_bit_identity``'s differential on a registry
+    nobody scrambled: the composition a mainnet node holds, over 6
+    epochs, root AND bytes, columns consistent after every boundary —
+    and the churn really ran (activations under the churn limit, stamps
+    on the fresh deposits, exits leaving the active set)."""
+    world = _mainnet_registry_world(seed)
+    ctx = world.context
+    sp = _slot_processing("deneb")
+    engaged_ctr = metrics.counter("epoch_vector.epochs")
+    s_col = world.pre.copy()
+    s_scl = world.pre.copy()
+    queue_before = sum(
+        1 for v in world.pre.validators
+        if int(v.activation_epoch) == FAR_FUTURE_EPOCH
+    )
+    for place in range(6):
+        slot = world.target_slot + 32 * place
+        for s in (s_col, s_scl):
+            if place:
+                sp.process_slots(s, slot - 1, ctx)
+                s.current_epoch_participation = world.refills[place - 1].tolist()
+        before = engaged_ctr.value()
+        sp.process_slots(s_col, slot, ctx)
+        assert engaged_ctr.value() - before == 1
+        os.environ["ECT_EPOCH_VECTOR"] = "off"
+        try:
+            sp.process_slots(s_scl, slot, ctx)
+        finally:
+            os.environ.pop("ECT_EPOCH_VECTOR", None)
+        assert_bit_identical(s_col, s_scl, f"mainnet registry crossing {place}")
+        assert_column_consistency(s_col, f"mainnet registry crossing {place}")
+    queue_after = sum(
+        1 for v in s_col.validators
+        if int(v.activation_epoch) == FAR_FUTURE_EPOCH
+    )
+    assert queue_before == 10 and queue_after == 0  # 8 queued, 2 fresh deposits
+    active = sum(
+        1 for v in s_col.validators
+        if int(v.activation_epoch) <= 7 < int(v.exit_epoch)
+    )
+    # 4,096 at epoch 1; six exits and four earlier activations have landed,
+    # and of the ten rows the chain activated those written for epochs 6, 7
+    assert active == 4096 - 6 + 4 + 7
+
+
+def test_pass_counters_and_span_fields_read_what_the_pass_did(forced_engine):
+    """``epoch_vector.rows``, ``.rows_active``, ``.registry.queued``,
+    ``.registry.activated`` and ``.validator_writes`` count one pass's
+    work, and the registry and commit spans carry the same numbers."""
+    from ethereum_consensus_tpu.telemetry import spans
+
+    world = _mainnet_registry_world(5)
+    state = world.pre.copy()
+    names = ("rows", "rows_active", "registry.queued", "registry.activated",
+             "validator_writes")
+
+    def read():
+        return {
+            name: metrics.counter(f"epoch_vector.{name}").value() for name in names
+        }
+
+    def cross(slot):
+        before = read()
+        with spans.recording():
+            _slot_processing("deneb").process_slots(state, slot, world.context)
+            fields = {
+                r.name: r.fields for r in spans.RECORDER.records()
+                if r.name in ("epoch_vector.registry", "epoch_vector.commit")
+            }
+        after = read()
+        return {name: after[name] - before[name] for name in names}, fields
+
+    # the first boundary: both fresh deposits stamped, the activation churn
+    # limit's worth (min(8, max(4, 4096 // 65536)) = 4) of the queue let in
+    moved, fields = cross(world.target_slot)
+    assert moved == {
+        "rows": 1 << 13, "rows_active": 4096, "registry.queued": 2,
+        "registry.activated": 4, "validator_writes": 6,
+    }
+    assert fields["epoch_vector.registry"] == {"queued": 2, "activated": 4}
+    assert fields["epoch_vector.commit"] == {"validators": 1 << 13, "writes": 6}
+    # the second: nobody new, the three rows left that were eligible at epoch 0
+    state.current_epoch_participation = world.refills[0].tolist()
+    moved, fields = cross(world.target_slot + 32)
+    assert moved["registry.queued"] == 0 and moved["registry.activated"] == 3
+    assert moved["rows_active"] == 4096 - 1 + 1  # one exit, one activation
+    assert fields["epoch_vector.registry"] == {"queued": 0, "activated": 3}
+    assert fields["epoch_vector.commit"]["writes"] == moved["validator_writes"] == 3
+
+
 def test_engine_declines_cleanly(forced_engine):
     """Every decline path leaves the state untouched for the literal
     list: env kill switches, the u64 lane guard, and the registry-size
@@ -440,11 +554,15 @@ def test_kernels_jittable_bit_identical():
     assert np.array_equal(np.asarray(dev_bal), host_bal)
 
 
-def _fused_inputs(n=4097, seed=13, high_words=False):
+def _fused_inputs(n=4097, seed=13, high_words=False, half_inactive=False):
     """``high_words``: every balance is over 2^32 gwei and the scores mix
     small values with 2^32 + 1 and 2^63, so both result columns need both
     of their 32-bit planes (a u64 product that wraps does so alike on the
-    host and under jit: the lane guard is the caller's)."""
+    host and under jit: the lane guard is the caller's).
+    ``half_inactive``: a registry as mainnet holds it: every other row or
+    so, interleaved, has exited and been withdrawn (no balance, no flags,
+    not eligible), some of those are slashed, and a few slashed rows are
+    still eligible (not yet withdrawable) with a balance to lose."""
     rng = np.random.default_rng(seed)
     k = dict(
         balances=rng.integers(0, 1 << 45, n, dtype=np.uint64),
@@ -459,25 +577,36 @@ def _fused_inputs(n=4097, seed=13, high_words=False):
         k["balances"] += np.uint64(1 << 33)
         k["scores"][1::3] = (1 << 32) + 1
         k["scores"][2::3] = 1 << 63
+    if half_inactive:
+        gone = rng.random(n) < 0.5
+        k["active_prev"] = ~gone
+        k["slashed"] = gone & (rng.random(n) < 0.02)
+        held = k["slashed"] & (rng.random(n) < 0.25)  # not yet withdrawable
+        k["eligible"] = ~gone | held
+        for name in ("balances", "eff", "prev_part", "scores"):
+            k[name][gone & ~held] = 0
+        assert 0.4 < gone.mean() < 0.6 and held.any() and (gone[1:] != gone[:-1]).sum() > n // 4
     return k
 
 
 # the second and third shapes are no multiple of 128 rows (the chip's
 # lane width) and fill the high words of both columns
 @pytest.mark.parametrize(
-    "n, high_words",
-    [(4097, False), (1000, True), (129, True)],
-    ids=["4097", "1000-high-words", "129-high-words"],
+    "n, high_words, half_inactive",
+    [(4097, False, False), (1000, True, False), (129, True, False),
+     (4096, False, True)],
+    ids=["4097", "1000-high-words", "129-high-words", "4096-half-inactive"],
 )
 @pytest.mark.parametrize("leaking", [False, True])
-def test_fused_kernel_matches_staged_kernels_and_jit(leaking, n, high_words):
+def test_fused_kernel_matches_staged_kernels_and_jit(leaking, n, high_words,
+                                                     half_inactive):
     """The fused epoch kernel (ISSUE 14) must equal the staged kernels it
     collapses — inactivity update, three flag-delta pairs off in-kernel
     sums, inactivity penalties off post-update scores, in-order
     application — on host numpy AND bit-identically under jax.jit with
     x64 (the jitted_kernels() discipline), through the 32-bit planes the
     jit route brings its two columns down as."""
-    k = _fused_inputs(n, high_words=high_words)
+    k = _fused_inputs(n, high_words=high_words, half_inactive=half_inactive)
     increment, brpi = 10**9, 907
     weights, wd = (14, 26, 14), 64
     bias, recovery = 4, 16
@@ -533,6 +662,12 @@ def test_fused_kernel_matches_staged_kernels_and_jit(leaking, n, high_words):
             assert np.count_nonzero(column >> np.uint64(32)) > n // 4
     else:
         assert int(host_wrapped) == 0
+    if half_inactive:
+        # a row that is gone is paid nothing and loses nothing
+        gone = ~k["eligible"]
+        assert gone.sum() > n // 3
+        assert not staged_balances[gone].any() and not staged_scores[gone].any()
+        assert (staged_balances != k["balances"])[k["eligible"]].mean() > 0.5
 
     jax = pytest.importorskip("jax")
     jax.config.update("jax_enable_x64", True)

@@ -149,3 +149,39 @@ def test_multi_epoch_chain_identical(attested_state, installed):
     assert type(host_state).hash_tree_root(host_state) == type(
         dev_state
     ).hash_tree_root(dev_state)
+
+
+def test_install_tells_the_allocator_to_keep_freed_memory(installed):
+    """``install()`` applies utils/allocator.py's two glibc settings: a
+    whole-registry temporary (16 MiB at 2^21 rows) comes from the heap and
+    not from a mapping of its own, and stays on the heap once freed, so
+    the next temporary of its size faults in no page."""
+    import ctypes
+
+    import numpy as np
+
+    from ethereum_consensus_tpu.utils import allocator
+
+    assert allocator.keep_freed_memory() is allocator.keep_freed_memory()
+    if not allocator.keep_freed_memory():
+        pytest.skip("not glibc: the settings do not exist here")
+
+    class Mallinfo2(ctypes.Structure):
+        _fields_ = [(name, ctypes.c_size_t) for name in (
+            "arena", "ordblks", "smblks", "hblks", "hblkhd", "usmblks",
+            "fsmblks", "uordblks", "fordblks", "keepcost",
+        )]
+
+    try:
+        mallinfo2 = ctypes.CDLL(None).mallinfo2
+    except AttributeError:
+        pytest.skip("glibc before 2.33: no mallinfo2")
+    mallinfo2.restype = Mallinfo2
+    rows = 1 << 21
+    before = mallinfo2()
+    column = np.ones(rows, dtype=np.uint64)
+    held = mallinfo2()
+    assert held.hblks == before.hblks, "the block is a mapping of its own"
+    assert held.uordblks >= before.uordblks + column.nbytes
+    del column
+    assert mallinfo2().fordblks >= rows * 8, "the heap gave the block back"

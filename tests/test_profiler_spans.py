@@ -68,6 +68,14 @@ def session(tmp_path_factory):
     spe = int(ctx.SLOTS_PER_EPOCH)
     sp.process_slots(state, spe, ctx)
     state.previous_epoch_participation = [0b111] * len(state.validators)
+    # a registry with something to do: three deposits no boundary has seen
+    # and an activation queue of five
+    far = (1 << 64) - 1
+    for i in range(88, 96):
+        validator = state.validators[i]
+        validator.activation_epoch = far
+        if i >= 93:
+            validator.activation_eligibility_epoch = far
     chain_utils._strip_spec_caches(state)
 
     def on_a_second_thread():
@@ -123,6 +131,29 @@ def _line_with(session, name):
 def _only(line, name):
     (event,) = [e for e in line if e[0] == name]
     return event
+
+
+def test_registry_and_commit_events_say_what_the_pass_did(session):
+    """Fields a span learns in its body (``trace.note``) are stats of its
+    event in the xplane: the registry span's queue, the commit's writes."""
+    line = _line_with(session, "ect:epoch_vector.pass")
+    registry = _only(line, "ect:epoch_vector.registry")[3]
+    commit = _only(line, "ect:epoch_vector.commit")[3]
+    # minimal preset: the churn limit is min(4, max(2, 88 // 32)) = 2
+    assert registry == {"queued": 3, "activated": 2}
+    assert commit == {"validators": 96, "writes": 5}
+
+
+def test_a_note_outside_any_span_is_a_no_op():
+    assert not spans.RECORDER.enabled
+    trace.note(rows=1)  # no sink, no open span: nothing to add to
+    with spans.recording():
+        with trace.span("test.noted", a=1):
+            trace.note(b=2)
+        (record,) = [
+            r for r in spans.RECORDER.records() if r.name == "test.noted"
+        ]
+    assert record.fields == {"a": 1, "b": 2}
 
 
 def test_the_session_is_the_switch(session):

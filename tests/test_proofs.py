@@ -63,6 +63,10 @@ def small_groups():
             ssz_core._DIRTY_TRACK_MIN_CHUNKS,
             ssz_core._BULK_ROOTS_MIN,
         ) = saved
+        # a genesis first built in here was warmed under the shrunk
+        # geometry, and every later copy in this process would carry it
+        chain_utils.cached_genesis.cache_clear()
+        chain_utils._cached_genesis_fork.cache_clear()
 
 
 # ---------------------------------------------------------------------------
