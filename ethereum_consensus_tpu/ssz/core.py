@@ -20,6 +20,7 @@ import time as _time
 from typing import Any
 
 from ..telemetry import memory as _memory
+from ..telemetry import metrics as _metrics
 from .hash import hash_level
 from .merkle import (
     BYTES_PER_CHUNK,
@@ -826,6 +827,36 @@ def _pack_tree_eligible(values, limit_chunks: int, count_chunks: int) -> bool:
     )
 
 
+# full packs served off a clean wire-width column instead of the list's
+# boxed ints (walks, and the bytes they wrote)
+_PACK_FROM_COLUMN = _metrics.counter("ssz.pack.from_column")
+_PACK_FROM_COLUMN_BYTES = _metrics.counter("ssz.pack.from_column_bytes")
+
+
+def _clean_wire_column(values: "CachedRootList", esize: int):
+    """The list-resident column when it IS the list's content at wire
+    width, else None: a ``("list", arr, vmax)`` record nobody has written
+    past (``_col_dirty == set()``; None means untracked), as long as the
+    list, of an unsigned dtype ``esize`` bytes wide. The adoption and
+    refresh contracts of models/ops_vector.py keep such a column equal to
+    the ints the list holds, so ``arr.astype("<u%d" % esize,
+    copy=False).tobytes()`` is the list's serialization (the astype is a
+    no-op on little-endian hosts and fixes the byte order on big-endian
+    ones). The one statement of that contract: _packed_splice packs its
+    dirty groups off it and _merkleize_homogeneous its full pack."""
+    cc = values._col_cache
+    if (
+        cc is not None
+        and cc[0] == "list"
+        and values._col_dirty == set()
+        and cc[1].shape[0] == len(values)
+        and cc[1].dtype.itemsize == esize
+        and cc[1].dtype.kind == "u"
+    ):
+        return cc[1]
+    return None
+
+
 def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
     """Dirty-group incremental root for a packed basic/bytes32 collection:
     re-serialize ONLY the dirty 4096-element groups into the retained raw
@@ -856,24 +887,11 @@ def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
         return root if len(raw) == n * esize else None
     gs = _DIRTY_GROUP_SHIFT
     gsize = 1 << gs
-    # write-direction shortcut: a CLEAN list-resident column cache whose
-    # dtype matches the wire width IS the list's content (the adoption /
-    # refresh contracts of models/ops_vector.py), so dirty groups can
+    # write-direction shortcut (_clean_wire_column): dirty groups
     # serialize straight off the array at C speed instead of converting
     # Python ints per element — the big win for the columnar-primary
     # epoch commit, whose bulk_store dirties every balance group at once
-    col_arr = None
-    if esize != BYTES_PER_CHUNK:
-        cc = getattr(values, "_col_cache", None)
-        if (
-            cc is not None
-            and cc[0] == "list"
-            and values._col_dirty == set()
-            and cc[1].shape[0] == n
-            and cc[1].dtype.itemsize == esize
-            and cc[1].dtype.kind == "u"
-        ):
-            col_arr = cc[1]
+    col_arr = _clean_wire_column(values, esize)
     # serialize every dirty range BEFORE touching the memo, with the same
     # strictness as serialize(): a non-conforming value sends the whole
     # walk to the fallback path and its structured errors
@@ -887,8 +905,6 @@ def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
                 continue
             stop = min(n, start + gsize)
             if col_arr is not None:
-                # astype(copy=False) is a no-op on little-endian hosts
-                # and fixes the byte order on big-endian ones
                 seg = col_arr[start:stop].astype(
                     "<u%d" % esize, copy=False
                 ).tobytes()
@@ -1427,12 +1443,23 @@ def _merkleize_homogeneous(elem: SSZType, values: list, limit_elems: int) -> byt
             hit = _packed_splice(elem, values, key, limit)
             if hit is not None:
                 return hit
-        all_int = getattr(values, "_uniform_kind", None) == ("int",)
+        # the full pack of a list that already holds its content as a
+        # column (the participation rotation's fresh zeros, a list adopted
+        # under the tracking threshold) takes the column's bytes instead
+        # of unboxing every int again; a uint column certifies the
+        # verdict the scan would reach, as bulk_store does from a dtype
+        col_arr = None
+        if isinstance(values, CachedRootList) and isinstance(elem, _UintType):
+            col_arr = _clean_wire_column(values, elem.byte_length)
+        all_int = (
+            col_arr is not None
+            or getattr(values, "_uniform_kind", None) == ("int",)
+        )
         if not all_int and values and set(map(type, values)) == {int}:
             all_int = True  # C-speed scan; keeps serialize()'s
             # bool/float rejections out of the numpy path
-            if isinstance(values, CachedRootList):
-                values._uniform_kind = ("int",)  # mutators maintain it
+        if all_int and isinstance(values, CachedRootList):
+            values._uniform_kind = ("int",)  # mutators maintain it
         if (
             isinstance(elem, _UintType)
             and elem.byte_length in (1, 2, 4, 8)
@@ -1450,16 +1477,21 @@ def _merkleize_homogeneous(elem: SSZType, values: list, limit_elems: int) -> byt
             # astype matches serialize().
             _obs = _memory.OBSERVATORY
             _t0 = _time.perf_counter() if _obs.active else 0.0
-            try:
-                import numpy as _np
+            size = elem.byte_length
+            if col_arr is not None:
+                raw = col_arr.astype("<u%d" % size, copy=False).tobytes()
+                _PACK_FROM_COLUMN.inc()
+                _PACK_FROM_COLUMN_BYTES.inc(len(raw))
+            else:
+                try:
+                    import numpy as _np
 
-                col = _np.asarray(values, dtype="<u8")
-                size = elem.byte_length
-                if size < 8 and bool((col >> (8 * size)).any()):
-                    raise OverflowError  # out of range for the width
-                raw = col.astype("<u%d" % size).tobytes()
-            except (OverflowError, TypeError, ValueError):
-                raw = b"".join(elem.serialize(v) for v in values)
+                    col = _np.asarray(values, dtype="<u8")
+                    if size < 8 and bool((col >> (8 * size)).any()):
+                        raise OverflowError  # out of range for the width
+                    raw = col.astype("<u%d" % size).tobytes()
+                except (OverflowError, TypeError, ValueError):
+                    raw = b"".join(elem.serialize(v) for v in values)
             if _obs.active:
                 # bandwidth: the full wire-width column materialization
                 # (a whole-collection re-pack — the cost _packed_splice

@@ -157,6 +157,51 @@ def test_columnar_epoch_bit_identity(fork, participation, forced_engine):
         assert_column_consistency(s_col, f"{fork} epoch {target_epoch}")
 
 
+@pytest.mark.parametrize("fork", FORKS[1:])
+def test_post_epoch_root_packs_from_the_columns(fork, forced_engine):
+    """The first ``hash_tree_root`` after the columnar pass is the literal
+    oracle's, and the lists the pass left as clean columns with no memo
+    to serve them pack from those columns (``ssz.pack.from_column``): the
+    rotation's fresh ``current_epoch_participation`` at every boundary,
+    and, at test size, the adopted ``balances`` once rewards move them
+    (under the tracking threshold they have no ``_pack_tree`` to splice)."""
+    from ethereum_consensus_tpu.ssz import core as ssz_core
+
+    state, ctx = chain_utils.fresh_genesis_fork(fork, 96, "minimal")
+    sp = _slot_processing(fork)
+    spe = int(ctx.SLOTS_PER_EPOCH)
+    packs = metrics.counter("ssz.pack.from_column")
+    widths = {
+        "balances": 8, "inactivity_scores": 8,
+        "previous_epoch_participation": 1, "current_epoch_participation": 1,
+    }
+    s_col, s_lit = state.copy(), state.copy()
+    for epoch in (1, 2):
+        for s in (s_col, s_lit):
+            n = len(s.validators)
+            s.previous_epoch_participation = [0b111] * n
+            s.current_epoch_participation = [0b110] * n
+        sp.process_slots(s_col, epoch * spe, ctx)
+        os.environ["ECT_EPOCH_VECTOR"] = "off"
+        try:
+            sp.process_slots(s_lit, epoch * spe, ctx)
+        finally:
+            os.environ.pop("ECT_EPOCH_VECTOR", None)
+        served = [
+            name for name, size in widths.items()
+            for lst in [getattr(s_col, name)]
+            if ssz_core._clean_wire_column(lst, size) is not None
+            and lst._pack_gen != lst._mut_gen
+        ]
+        assert "current_epoch_participation" in served
+        assert ("balances" in served) == (epoch == 2)
+        before = packs.value()
+        root = type(s_col).hash_tree_root(s_col)
+        assert root == type(s_lit).hash_tree_root(s_lit), f"{fork} epoch {epoch}"
+        assert packs.value() - before == len(served), served
+        assert_bit_identical(s_col, s_lit, f"{fork} epoch {epoch}")
+
+
 def _mainnet_registry_world(seed):
     """A registry of the benchmark's ``mainnet-deneb-2m`` composition at
     2^13 entries (its groups times 2^-8: half the rows exited and

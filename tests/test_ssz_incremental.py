@@ -31,6 +31,7 @@ from ethereum_consensus_tpu.ssz.core import (
     Container,
     List,
     bulk_store,
+    uint8,
     uint64,
 )
 
@@ -64,13 +65,17 @@ def _naive_list_root(values, limit: int) -> bytes:
     return _h(root + len(values).to_bytes(32, "little"))
 
 
-def _naive_u64_list_root(values, limit: int) -> bytes:
-    packed = b"".join(int(v).to_bytes(8, "little") for v in values)
+def _naive_uint_list_root(values, limit: int, size: int) -> bytes:
+    packed = b"".join(int(v).to_bytes(size, "little") for v in values)
     if len(packed) % 32:
         packed += b"\x00" * (32 - len(packed) % 32)
     chunks = [packed[i : i + 32] for i in range(0, len(packed), 32)]
-    root = _naive_merkleize(chunks, (limit * 8 + 31) // 32)
+    root = _naive_merkleize(chunks, (limit * size + 31) // 32)
     return _h(root + len(values).to_bytes(32, "little"))
+
+
+def _naive_u64_list_root(values, limit: int) -> bytes:
+    return _naive_uint_list_root(values, limit, 8)
 
 
 @pytest.fixture
@@ -404,6 +409,145 @@ def test_every_manifest_mutator_keeps_incremental_root(small_groups):
     got = CLT.hash_tree_root(values)
     want = CLT.hash_tree_root(CachedRootList(Val(a=v.a, b=v.b) for v in values))
     assert got == want
+
+
+# ---------------------------------------------------------------------------
+# the full pack off a clean wire-width column (_clean_wire_column)
+# ---------------------------------------------------------------------------
+
+# element type (its name is its wire-width numpy dtype's) -> (descriptor,
+# the vmax its column record carries, a length under the tracking
+# threshold of small_groups (4 chunks), one over it)
+_COLUMN_CASES = {
+    "uint8": (uint8, 0xFF, 40, 300),
+    "uint64": (uint64, 2**64 - 1, 12, 50),
+}
+_COLUMN_LIMIT = 1 << 12
+
+
+def _column_list(width: str, side: str, how: str):
+    """(list type, a never-rooted CachedRootList that holds its content as
+    a clean column, the same ints as a plain list, the element's bytes)."""
+    import numpy as np
+
+    from ethereum_consensus_tpu.models import ops_vector
+
+    elem, vmax, under, over = _COLUMN_CASES[width]
+    n = under if side == "under" else over
+    if how == "installed":  # the participation rotation's fresh zeros
+        ints = [0] * n
+        lst = CachedRootList(ints)
+        ops_vector.install_zero_column(lst, n, vmax)
+    else:  # an epoch commit: the authoritative array spliced in
+        rng = random.Random(n)
+        ints = [rng.randrange(min(vmax, 2**63)) for _ in range(n)]
+        lst = CachedRootList([0] * n)
+        arr = np.array(ints, dtype=width)
+        ops_vector.adopt_list_column(lst, arr, np.nonzero(arr)[0], vmax)
+    assert lst._pack_tree is None and lst._pack_memo is None
+    assert lst._col_dirty == set() and list(lst) == ints
+    return List[elem, _COLUMN_LIMIT], lst, ints, elem.byte_length
+
+
+def _from_column() -> tuple:
+    return (
+        ssz_core._PACK_FROM_COLUMN.value(),
+        ssz_core._PACK_FROM_COLUMN_BYTES.value(),
+    )
+
+
+def _memo_state(lst) -> tuple:
+    pt = lst._pack_tree
+    tree = None if pt is None else (
+        pt[0], bytes(pt[1]), [bytes(lv) for lv in pt[2].levels], pt[3]
+    )
+    return tree, lst._pack_memo, lst._dirty_groups, lst._uniform_kind
+
+
+@pytest.mark.parametrize("how", ["installed", "adopted"])
+@pytest.mark.parametrize("side", ["under", "over"])
+@pytest.mark.parametrize("width", ["uint8", "uint64"])
+def test_column_pack_roots_as_the_plain_list(width, side, how, small_groups):
+    """(a) a clean column and no _pack_tree: the plain list's root, the
+    plain list's memos, one engagement of the column's byte length."""
+    LT, lst, ints, size = _column_list(width, side, how)
+    before = _from_column()
+    got = LT.hash_tree_root(lst)
+    assert _from_column() == (before[0] + 1, before[1] + len(ints) * size)
+    assert got == LT.hash_tree_root(list(ints))
+    assert got == _naive_uint_list_root(ints, _COLUMN_LIMIT, size)
+    plain = CachedRootList(ints)
+    assert LT.hash_tree_root(plain) == got
+    assert _from_column()[0] == before[0] + 1  # the plain list has no column
+    assert _memo_state(lst) == _memo_state(plain)
+    assert (lst._pack_tree is not None) == (side == "over")
+
+
+@pytest.mark.parametrize("fault", ["dirty", "untracked", "width", "length"])
+@pytest.mark.parametrize("side", ["under", "over"])
+@pytest.mark.parametrize("width", ["uint8", "uint64"])
+def test_column_pack_refused(width, side, fault, small_groups):
+    """(b) a column that is not provably the list's content at wire width
+    is not read, and the root is the ints'."""
+    import numpy as np
+
+    LT, lst, ints, size = _column_list(width, side, "adopted")
+    vmax = lst._col_cache[2]
+    if fault == "dirty":
+        lst[5] = ints[5] = 7  # the instrumented mutator names the index
+        assert lst._col_dirty == {5}
+    elif fault == "untracked":
+        lst._col_dirty = None
+    elif fault == "width":
+        # the values fit the narrow width both ways, so only the dtype's
+        # itemsize can refuse it
+        ints = [v & 0x7F for v in ints]
+        lst = CachedRootList(ints)
+        other = "uint64" if width == "uint8" else "uint8"
+        lst._col_cache = ("list", np.array(ints, dtype=other), vmax)
+        lst._col_dirty = set()
+    else:
+        lst._col_cache = ("list", np.zeros(len(ints) + 1, dtype=width), vmax)
+    before = _from_column()
+    assert LT.hash_tree_root(lst) == _naive_uint_list_root(
+        ints, _COLUMN_LIMIT, size
+    )
+    assert _from_column() == before
+
+
+@pytest.mark.parametrize("side", ["under", "over"])
+@pytest.mark.parametrize("width", ["uint8", "uint64"])
+def test_column_pack_second_root_moves_no_counter(width, side, small_groups):
+    """(c) the shortcut builds the memo the next walk is served from."""
+    LT, lst, _ints, _size = _column_list(width, side, "installed")
+    first = LT.hash_tree_root(lst)
+    before = _from_column()
+    digests = ssz_hash.digest_count()
+    lst._root_cache.clear()  # past the per-descriptor root cache
+    assert LT.hash_tree_root(lst) == first
+    assert _from_column() == before
+    # generation memo under the threshold, the splice's clean return over
+    # it: neither packs nor hashes the elements again (the length mix-in)
+    assert ssz_hash.digest_count() - digests <= 1
+
+
+@pytest.mark.parametrize("side", ["under", "over"])
+@pytest.mark.parametrize("width", ["uint8", "uint64"])
+def test_column_pack_then_write(width, side, small_groups):
+    """(d) dirty tracking is armed by the shortcut's memo as by any full
+    pack: a write marks its group and the next root is the plain list's."""
+    LT, lst, ints, size = _column_list(width, side, "adopted")
+    LT.hash_tree_root(lst)
+    i = len(ints) - 3
+    lst[i] = ints[i] = 99
+    assert lst._col_dirty == {i}
+    if side == "over":
+        assert lst._dirty_groups == {i >> ssz_core._DIRTY_GROUP_SHIFT}
+    before = _from_column()
+    assert LT.hash_tree_root(lst) == _naive_uint_list_root(
+        ints, _COLUMN_LIMIT, size
+    )
+    assert _from_column() == before  # the column is dirty now
 
 
 # ---------------------------------------------------------------------------
