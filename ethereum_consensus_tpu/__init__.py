@@ -5,14 +5,14 @@ A ground-up reimplementation of the capabilities of
 `ralexstokes/ethereum_consensus` (the Rust reference surveyed in SURVEY.md)
 designed for TPUs: spec logic is host Python with exact u64 semantics; the
 hot paths — SHA-256 merkleization, batched BLS aggregate verification,
-shuffling, and per-validator epoch sweeps — run as JAX/XLA/Pallas kernels
-sharded over device meshes.
+shuffling, and the epoch pass's inactivity and rewards — run as
+JAX/XLA/Pallas kernels sharded over device meshes.
 
 Layout:
   ssz/       SSZ type algebra, codec, merkleization (replaces ssz_rs)
   crypto/    BLS12-381 + KZG (replaces blst/c-kzg) with oracle + device paths
   models/    per-fork spec modules (phase0..electra) + polymorphic types
-  ops/       JAX/Pallas device kernels (sha256, merkle, shuffle, sweeps)
+  ops/       JAX/Pallas device kernels (sha256, merkle, shuffle, BLS)
   parallel/  mesh construction, shard_map distributed reductions
   config/    presets, network configs, Context, networks
   utils/     clock, serde presentation helpers, math

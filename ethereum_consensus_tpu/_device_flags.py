@@ -1,13 +1,13 @@
 """Runtime switches for device acceleration of spec-path functions.
 
 Deliberately free of any jax import: the host layers (models/, ssz/)
-consult these flags on every call and only lazily import the ops package
-when a flag is on, so a host-only process never pays for jax. The flags
-are set by ``ops.install()`` (and unset by ``ops.uninstall()``).
+consult these flags on every call and only lazily import jax (the ops
+package, the jitted epoch kernel) when a flag is on, so a host-only
+process never pays for jax (tests/test_host_only.py holds it to that).
+The flags are set by ``ops.install()`` (and unset by ``ops.uninstall()``).
 
-Thresholds are minimum element counts: device sweeps/shuffles win only
-above a size where kernel launch + host<->device packing amortizes; below
-the threshold the spec functions keep their host path.
+Thresholds are minimum element counts: below the threshold the host path
+runs. What each one selects is in its predicate's docstring.
 
 These predicates are ALSO the routing journal's primary source
 (telemetry/device.py): every consult is a device-vs-host decision, so
@@ -41,8 +41,14 @@ def _journal(kind: str, routed: bool, n: int, threshold: "int | None") -> None:
 
 
 def sweeps_enabled(n: int) -> bool:
-    """Route registry sweeps (flag deltas, inactivity, hysteresis) to
-    device for an ``n``-validator registry?"""
+    """Does the columnar epoch pass (models/epoch_vector.py) of an
+    altair-family fork run ``jitted_kernels()["fused_epoch"]`` in place
+    of the host kernels for its inactivity + rewards step, on an
+    ``n``-validator registry? Nothing else reads this gate: its one
+    caller is ``process_epoch_columnar``, and the literal per-fork
+    functions are host code whatever is installed. Renaming the journal
+    kind (``sweeps``) and ``ops.install``'s keyword (``sweeps_min_n``)
+    for what they select is the benchmark's to do (ROADMAP D1d)."""
     routed = SWEEPS_MIN_N is not None and n >= SWEEPS_MIN_N
     if _device_obs.OBSERVATORY.active:
         _journal("sweeps", routed, n, SWEEPS_MIN_N)

@@ -9,7 +9,6 @@ altair process_epoch:305.
 
 from __future__ import annotations
 
-from ... import _device_flags
 from ...primitives import GENESIS_EPOCH
 from ..phase0.epoch_processing import (  # noqa: F401 — fork-diff re-exports
     process_effective_balance_updates,
@@ -83,7 +82,7 @@ def _host_deltas_vectorized(state, context, hm, inactivity_quotient_name):
     leaking = hm.is_in_inactivity_leak(state, context)
     denom_w = np.uint64(WEIGHT_DENOMINATOR)
 
-    from ...ops.registry_columns import unslashed_flag_mask
+    from ..registry_columns import unslashed_flag_mask
 
     out = []
     target_unslashed = None
@@ -153,24 +152,9 @@ def process_justification_and_finalization(state, context) -> None:
 
 
 def process_inactivity_updates(state, context) -> None:
-    """(epoch_processing.rs:104) — whole-registry sweep; device twin above
-    threshold (ops/sweeps.py inactivity_updates_device)."""
+    """(epoch_processing.rs:104) — whole-registry sweep."""
     current_epoch = h.get_current_epoch(state, context)
     if current_epoch == GENESIS_EPOCH:
-        return
-    if _device_flags.sweeps_enabled(len(state.validators)):
-        from ...ops import sweeps as _sweeps
-
-        prev_epoch = h.get_previous_epoch(state, context)
-        packed = _sweeps.pack_registry(
-            state, prev_epoch,
-            use_current_participation=(prev_epoch == current_epoch),
-        )
-        scores = _sweeps.inactivity_updates_device(
-            packed, context, h.is_in_inactivity_leak(state, context)
-        )
-        for i, score in enumerate(scores):
-            state.inactivity_scores[i] = int(score)
         return
     n = len(state.validators)
     prev_epoch = h.get_previous_epoch(state, context)
@@ -189,7 +173,7 @@ def process_inactivity_updates(state, context) -> None:
         scores = packed["inactivity_scores"]
         bias = int(context.inactivity_score_bias)
         if int(scores.max()) < 2**64 - bias:
-            from ...ops.registry_columns import unslashed_flag_mask
+            from ..registry_columns import unslashed_flag_mask
 
             participating = unslashed_flag_mask(
                 packed, TIMELY_TARGET_FLAG_INDEX
@@ -242,38 +226,14 @@ def process_rewards_and_penalties(
 ) -> None:
     """(epoch_processing.rs:160) — flag deltas + inactivity penalties.
 
-    Device path packs the registry ONCE and reuses it for all four delta
-    sweeps (the registry fields the sweeps read don't change until the
-    deltas are applied below). ``helpers`` / ``inactivity_quotient_name``
-    let later forks reuse this body with their helpers module and quotient
-    (bellatrix+)."""
+    ``helpers`` / ``inactivity_quotient_name`` let later forks reuse this
+    body with their helpers module and quotient (bellatrix+)."""
     hm = helpers or h
     current_epoch = hm.get_current_epoch(state, context)
     if current_epoch == GENESIS_EPOCH:
         return
     n = len(state.validators)
-    if _device_flags.sweeps_enabled(n):
-        from ...ops import sweeps as _sweeps
-
-        prev_epoch = hm.get_previous_epoch(state, context)
-        packed = _sweeps.pack_registry(
-            state, prev_epoch,
-            use_current_participation=(prev_epoch == current_epoch),
-        )
-        total_active = hm.get_total_active_balance(state, context)
-        is_leaking = hm.is_in_inactivity_leak(state, context)
-        deltas = [
-            _sweeps.flag_deltas_device(
-                packed, flag_index, total_active, context, is_leaking
-            )
-            for flag_index in range(len(PARTICIPATION_FLAG_WEIGHTS))
-        ]
-        deltas.append(
-            ([0] * n, _sweeps.inactivity_penalties_device(
-                packed, context, getattr(context, inactivity_quotient_name)
-            ))
-        )
-    elif n >= _VECTORIZED_DELTAS_MIN_N:
+    if n >= _VECTORIZED_DELTAS_MIN_N:
         deltas = _host_deltas_vectorized(
             state, context, hm, inactivity_quotient_name
         )

@@ -13,7 +13,6 @@ Unchanged phase0 helpers are re-exported so altair callers use one module.
 
 from __future__ import annotations
 
-from ... import _device_flags
 from ...crypto import bls
 from ...domains import DomainType
 from ...error import StateTransitionError, checked_add
@@ -215,28 +214,8 @@ def get_attestation_participation_flag_indices(
 
 
 def get_flag_index_deltas(state, flag_index: int, context):
-    """(helpers.rs:265) — whole-registry sweep; routed to the device twin
-    (ops/sweeps.py flag_deltas_device, bit-identical) above the installed
-    threshold."""
+    """(helpers.rs:265) — whole-registry sweep."""
     n = len(state.validators)
-    if _device_flags.sweeps_enabled(n):
-        from ...ops import sweeps as _sweeps
-
-        prev_epoch = get_previous_epoch(state, context)
-        packed = _sweeps.pack_registry(
-            state, prev_epoch,
-            use_current_participation=(
-                prev_epoch == get_current_epoch(state, context)
-            ),
-        )
-        rewards, penalties = _sweeps.flag_deltas_device(
-            packed,
-            flag_index,
-            get_total_active_balance(state, context),
-            context,
-            is_in_inactivity_leak(state, context),
-        )
-        return [int(r) for r in rewards], [int(p) for p in penalties]
     rewards = [0] * n
     penalties = [0] * n
     previous_epoch = get_previous_epoch(state, context)
@@ -270,23 +249,8 @@ def get_flag_index_deltas(state, flag_index: int, context):
 
 
 def get_inactivity_penalty_deltas(state, context):
-    """(helpers.rs get_inactivity_penalty_deltas, altair quotient) — device
-    twin above threshold (ops/sweeps.py inactivity_penalties_device)."""
+    """(helpers.rs get_inactivity_penalty_deltas, altair quotient)"""
     n = len(state.validators)
-    if _device_flags.sweeps_enabled(n):
-        from ...ops import sweeps as _sweeps
-
-        prev_epoch = get_previous_epoch(state, context)
-        packed = _sweeps.pack_registry(
-            state, prev_epoch,
-            use_current_participation=(
-                prev_epoch == get_current_epoch(state, context)
-            ),
-        )
-        penalties = _sweeps.inactivity_penalties_device(
-            packed, context, context.INACTIVITY_PENALTY_QUOTIENT_ALTAIR
-        )
-        return [0] * n, [int(p) for p in penalties]
     rewards = [0] * n
     penalties = [0] * n
     previous_epoch = get_previous_epoch(state, context)

@@ -9,7 +9,6 @@ compute_timestamp_at_slot:243.
 
 from __future__ import annotations
 
-from ... import _device_flags
 from ...error import checked_add
 from ...primitives import GENESIS_SLOT
 from ..altair.constants import (
@@ -41,23 +40,8 @@ __all__ = [
 
 
 def get_inactivity_penalty_deltas(state, context):
-    """(helpers.rs:14) — INACTIVITY_PENALTY_QUOTIENT_BELLATRIX. Device twin
-    above threshold (ops/sweeps.py inactivity_penalties_device)."""
+    """(helpers.rs:14) — INACTIVITY_PENALTY_QUOTIENT_BELLATRIX."""
     n = len(state.validators)
-    if _device_flags.sweeps_enabled(n):
-        from ...ops import sweeps as _sweeps
-
-        prev_epoch = get_previous_epoch(state, context)
-        packed = _sweeps.pack_registry(
-            state, prev_epoch,
-            use_current_participation=(
-                prev_epoch == get_current_epoch(state, context)
-            ),
-        )
-        penalties = _sweeps.inactivity_penalties_device(
-            packed, context, context.INACTIVITY_PENALTY_QUOTIENT_BELLATRIX
-        )
-        return [0] * n, [int(p) for p in penalties]
     rewards = [0] * n
     penalties = [0] * n
     previous_epoch = get_previous_epoch(state, context)

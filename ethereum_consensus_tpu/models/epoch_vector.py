@@ -18,10 +18,10 @@ consolidations). Each fork's ``process_epoch`` calls
 ``process_epoch_columnar(state, context, fork)`` first and falls back
 to its literal stage list when the engine declines — no numpy, the
 engine disabled (``ECT_OPS_VECTOR=off`` / ``ECT_EPOCH_VECTOR=off``),
-registry below ``EPOCH_VECTOR_MIN_VALIDATORS``, device sweeps
-installed, or a value outside the u64 lane contract. The literal loops
-remain the oracle: tests/test_epoch_vector.py diffs root AND bytes
-across every fork, including the churn scenarios.
+registry below ``EPOCH_VECTOR_MIN_VALIDATORS``, or a value outside the
+u64 lane contract. The literal loops remain the oracle:
+tests/test_epoch_vector.py diffs root AND bytes across every fork,
+including the churn scenarios.
 
 Soundness rules:
 
@@ -50,10 +50,11 @@ u64-overflow guards live in the CALLER, which routes pathological
 states to exact Python-int fallbacks before any kernel runs. On the
 device routes the altair-family inactivity + rewards stages collapse
 into the ONE ``fused_epoch_kernel`` dispatch (``jitted_kernels()``'s
-``fused_epoch`` via the ops.install sweeps flag; ``MeshEpochSweeps
-.fused`` under ``ECT_MESH``) — packed columns upload once and stay on
-device across the stages, with the staged host kernels as the live
-fallback (declines in ``epoch_vector.fused_fallback.{reason}``).
+``fused_epoch``, selected by ops.install's ``sweeps_min_n`` gate and
+by nothing else; ``MeshEpochSweeps.fused`` under ``ECT_MESH``) — packed
+columns upload once and stay on device across the stages, with the
+staged host kernels as the live fallback (declines in
+``epoch_vector.fused_fallback.{reason}``).
 phase0's justification and rewards are fed by the committee-mask
 kernel (``models/committees.py``), with the spec-helper walks as
 fallback + oracle.
@@ -122,9 +123,8 @@ def _np():
 def fallback(reason: str, **inputs) -> None:
     """Count a decline to the literal epoch path (trace event once per
     reason per process, mirroring ops_vector.fallback). EVERY decline
-    path runs through here — including the deliberate ones
-    (``below_threshold``, ``device_sweeps``) that used to be silent
-    outside the bench harness: a production-threshold decline is a
+    path runs through here — including the deliberate one
+    (``below_threshold``): a production-threshold decline is a
     routing decision worth seeing. While the device observatory is on,
     the decline also lands in its routing journal with the threshold
     inputs (telemetry/device.py)."""
@@ -509,8 +509,8 @@ class _EpochColumns:
         # the mesh runner for this pass (parallel/runtime.py) — None
         # when the mesh is off/declined, and the host kernels run
         "mesh",
-        # the jitted fused epoch kernel when ops.install routed the
-        # sweeps device-ward (None = host/mesh routes decide)
+        # the jitted fused epoch kernel when ops.install's sweeps gate
+        # selected it (None = host/mesh routes decide)
         "fused",
     )
 
@@ -1063,8 +1063,8 @@ def _fused_fallback(ec, reason: str, **inputs) -> None:
 def _fused_route(ec, leaking: bool) -> bool:
     """Run inactivity + rewards as ONE fused dispatch — mesh-sharded
     (parallel/epoch.py) when the mesh owns the pass, jitted
-    (``jitted_kernels()['fused_epoch']``) when ``ops.install`` routed the
-    sweeps device-ward. Returns True with ``ec.inact``/``ec.balances``
+    (``jitted_kernels()['fused_epoch']``) when ``ops.install``'s sweeps
+    gate selected it. Returns True with ``ec.inact``/``ec.balances``
     rebound; False = run the staged host kernels (live fallback,
     bit-identical)."""
     if ec.mesh is None and ec.fused is None:
@@ -1633,23 +1633,15 @@ def process_epoch_columnar(state, context, fork: str) -> bool:
     if _disabled():
         fallback("disabled", validators=n)
         return False
-    fused_jit = False
-    if _device_flags.sweeps_enabled(n):
-        if _FORK_CFG[fork]["family"] == "altair":
-            # ops.install routed the sweeps device-ward: the pass stays
-            # COLUMNAR and runs inactivity + rewards as the ONE jitted
-            # fused kernel (ISSUE 14) — the per-stage device sweeps the
-            # literal path would have dispatched collapse into a single
-            # compile + a single column upload
-            fused_jit = True
-        else:
-            # phase0 keeps the literal path's device hysteresis routing
-            fallback(
-                "device_sweeps",
-                validators=n,
-                sweeps_min_n=_device_flags.SWEEPS_MIN_N,
-            )
-            return False
+    # the one thing ops.install's sweeps gate selects: an altair-family
+    # pass runs inactivity + rewards as the ONE jitted fused kernel
+    # (one compile, one column upload) in place of the host kernels.
+    # phase0 has no fused kernel: its pass runs the host kernels whatever
+    # is installed, and the gate is not consulted
+    fused_jit = (
+        _FORK_CFG[fork]["family"] == "altair"
+        and _device_flags.sweeps_enabled(n)
+    )
     if _np() is None:
         fallback("no_numpy", validators=n)
         return False

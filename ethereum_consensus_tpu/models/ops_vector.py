@@ -379,7 +379,7 @@ def gather_rows(bundle: dict, indices, fields=None) -> "dict | None":
 
 def pack_registry_cached(state, previous_epoch: int,
                          use_current_participation: bool = False) -> dict:
-    """Cache-backed twin of ``ops.registry_columns.pack_registry`` — the
+    """Cache-backed twin of ``registry_columns.pack_registry`` — the
     same dict shape and the same ``activity_masks`` eligibility formula,
     fed from the delta-refreshed columns instead of per-call fromiter
     walks. Falls back to the literal packing when columns are
@@ -392,7 +392,7 @@ def pack_registry_cached(state, previous_epoch: int,
         )
     if packed is None:
         fallback("pack_registry")
-        from ..ops.registry_columns import pack_registry
+        from .registry_columns import pack_registry
 
         return pack_registry(state, previous_epoch, use_current_participation)
     return packed
@@ -425,7 +425,7 @@ def _pack_from_columns(cols, state, previous_epoch,
     balances = cols.list_column(state, "balances")
     if balances is None:
         return None
-    from ..ops.registry_columns import activity_masks
+    from .registry_columns import activity_masks
 
     active_previous, eligible = activity_masks(
         vc["activation_epoch"],

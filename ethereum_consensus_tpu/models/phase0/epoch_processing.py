@@ -5,14 +5,13 @@ LoC): process_epoch:1039, justification/finalization :173, rewards &
 penalties :217 (component deltas :762-995), registry updates :253,
 slashings :321, final resets :366-525.
 
-These whole-registry sweeps are the epoch-boundary hot path; ops/sweeps.py
-provides the vectorized device twin, cross-checked against this host
-implementation.
+These whole-registry sweeps are the epoch-boundary hot path; above
+``EPOCH_VECTOR_MIN_VALIDATORS`` the columnar pass (models/epoch_vector.py)
+runs in their place and is held bit-identical to these stage lists.
 """
 
 from __future__ import annotations
 
-from ... import _device_flags
 from ...error import StateTransitionError, saturating_sub
 from ...primitives import GENESIS_EPOCH
 from . import helpers as h
@@ -735,26 +734,12 @@ def process_eth1_data_reset(state, context) -> None:
 
 
 def process_effective_balance_updates(state, context) -> None:
-    """Hysteresis sweep over the whole registry; device twin above
-    threshold (ops/sweeps.py effective_balance_updates_device), columnar
-    host twin (models/ops_vector.py effective_balance_update_hits) above
-    the vectorized threshold, literal loop as oracle/fallback."""
+    """Hysteresis sweep over the whole registry; columnar host twin
+    (models/ops_vector.py effective_balance_update_hits) above the
+    vectorized threshold, literal loop as oracle/fallback."""
     # the ONLY spec site that mutates effective balances: drop the
     # total-active-balance memo (helpers.get_total_active_balance)
     state.__dict__.pop("_total_active_balance_cache", None)
-    if _device_flags.sweeps_enabled(len(state.validators)):
-        from ...ops import sweeps as _sweeps
-
-        packed = _sweeps.pack_registry(state, h.get_current_epoch(state, context))
-        updated = _sweeps.effective_balance_updates_device(packed, context)
-        for index, validator in enumerate(state.validators):
-            value = int(updated[index])
-            # only real changes write: an unconditional store would pop
-            # every validator's root cache (and the registry freshness)
-            # for the hysteresis-typical no-op case
-            if validator.effective_balance != value:
-                validator.effective_balance = value
-        return
     if len(state.validators) >= _VECTORIZED_REWARDS_MIN_N:
         from ..ops_vector import effective_balance_update_hits
 

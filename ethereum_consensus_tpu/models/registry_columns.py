@@ -1,10 +1,10 @@
 """Host-side registry column extraction (jax-free).
 
-The packed columns feed BOTH the device sweeps (ops/sweeps.py — jnp twins
-of the epoch loops) and the numpy host twins
-(models/altair/epoch_processing._host_deltas_vectorized); keeping the
-eligibility formula and the genesis participation corner in ONE place
-stops the two consumers drifting (code-review r5)."""
+The packed columns feed the numpy host twins inside the literal stage
+functions (models/altair/epoch_processing._host_deltas_vectorized) and,
+through ``ops_vector.pack_registry_cached``, the cache-backed packing;
+keeping the eligibility formula and the genesis participation corner in
+ONE place stops the two packings drifting (code-review r5)."""
 
 from __future__ import annotations
 
@@ -27,7 +27,7 @@ def activity_masks(activation, exit_epoch, withdrawable, slashed, previous_epoch
 
 
 def pack_registry(state, previous_epoch: int, use_current_participation: bool = False) -> dict:
-    """Host→device packing of the registry fields the sweeps touch.
+    """Literal (fromiter) packing of the registry fields the sweeps touch.
     Activity/eligibility are evaluated at ``previous_epoch`` (the epoch the
     deltas reward/penalize, altair helpers.rs:265).
 

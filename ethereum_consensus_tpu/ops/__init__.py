@@ -1,9 +1,12 @@
 """Device kernels (JAX/XLA + Pallas TPU): SHA-256 merkleization, shuffling,
-epoch-processing sweeps.
+BLS field/curve/pairing arithmetic.
 
 Import of this package pulls in jax; the pure-host layers (ssz/, models/)
-never import it directly — device acceleration is installed explicitly via
-``install()``.
+import from it only behind a gate that ``install()`` has switched on (today
+one site: the committee shuffle, models/phase0/helpers.py) — device
+acceleration is installed explicitly via ``install()``. The epoch pass's
+jitted kernel is not here: it is the ``xp``-generic kernel of
+models/epoch_vector.py, jitted there.
 """
 
 from .. import _device_flags, _env
@@ -14,27 +17,20 @@ _enable_jax_cache()
 from .merkle import merkleize_chunks_device  # noqa: E402
 from .sha256 import install_device_hasher, sha256_64b_pallas, sha256_64b_xla
 
-# crossover vs the (O(n)-hoisted) host sweeps, measured on the v5e chip:
-# a single routed sweep breaks even near 2^18 validators, but the epoch
-# path packs once for four sweeps, which moves the win to ~2^17
+# Registry size from which the columnar epoch pass of an altair-family
+# fork runs the fused jitted kernel in place of its host kernels
+# (_device_flags.sweeps_enabled). The crossover against the host kernels
+# is not measured (PERF.md section 7): the value is a guess
 DEFAULT_SWEEPS_MIN_N = 1 << 17
 DEFAULT_SHUFFLE_MIN_N = 1 << 15
 DEFAULT_BLS_AGG_MIN_N = 1 << 12
-# Device RLC multi-pairing (ops/pairing.py): auto-thresholded. The kernel
-# is bit-identical to the native backend and fully routed, but a SINGLE
-# chip without native wide-integer multiply (v5e: u64 lane products are
-# emulated) loses to the host IFMA engine (~119µs/pair) at block-sized
-# batches, so small flushes must stay host. What changed with the chain
-# pipeline (pipeline/engine.py): cross-block windowed flushes now reach
-# hundreds of sets per call, the scale where the set axis shards over the
-# mesh (parallel/pairing.py — N chips buy ~N× batch throughput) and the
-# mont7 int8-MXU multiplier amortizes its launch cost. The auto default
-# therefore routes only those large coalesced flushes to the device;
-# everything below the threshold keeps the host engine. Override with
-# ECT_PAIRING_MIN_SETS=<n> (fleet chips measured better/worse) or
-# ECT_PAIRING_MIN_SETS=off to pin the host engine unconditionally; any
-# device trouble still falls back to host without changing verdicts
-# (crypto/bls.py _batch_device_pairing).
+# Device RLC multi-pairing (ops/pairing.py): batches of at least this many
+# signature sets go to the device kernel (bit-identical to the native
+# backend), smaller ones keep the host engine. The crossover is not
+# measured; PERF.md section 6: stage B 11.9 s a flush. Override with
+# ECT_PAIRING_MIN_SETS=<n>, or ECT_PAIRING_MIN_SETS=off to pin the host
+# engine unconditionally; any device trouble still falls back to host
+# without changing verdicts (crypto/bls.py _batch_device_pairing).
 _AUTO_PAIRING_MIN_SETS = 512
 
 
@@ -69,14 +65,17 @@ def install(
       compression is ~30x slower than the native C++ hasher, so routing
       is skipped unless ``hasher_on_cpu`` forces it (device-wiring tests
       / deliberate jnp-hasher benches);
-    * epoch-processing registry sweeps (altair+ flag deltas, inactivity
-      updates/penalties, effective-balance hysteresis) above
-      ``sweeps_min_n`` validators;
+    * from ``sweeps_min_n`` validators up, the columnar epoch pass of an
+      altair-family fork runs its inactivity + rewards step as the fused
+      jitted kernel (``models/epoch_vector.jitted_kernels()
+      ["fused_epoch"]``) in place of the host kernels. Nothing else hangs
+      on this keyword: phase0's pass and every literal per-fork function
+      are host code whatever is installed;
     * whole-list committee shuffling above ``shuffle_min_n`` indices;
     * G1 pubkey aggregation (fast_aggregate_verify / batched signature
       sets) above ``bls_agg_min_n`` total points.
 
-    Spec semantics are unchanged — every device twin is bit-identical to
+    Spec semantics are unchanged — every device route is bit-identical to
     its host function (cross-checked in tests); the thresholds only decide
     where the work runs. Exact u64 arithmetic needs jax x64 mode, enabled
     here. The process-wide shuffle memo is dropped (as ``uninstall`` does),

@@ -217,7 +217,7 @@ def make_epoch_sweep_step(
     arrays → ``(new_balances, new_scores, total_active_balance)``.
     ``participation`` is the uint8 flag byte for the delta epoch
     (previous, or current in the genesis corner — the caller picks when
-    packing, see ops.sweeps.pack_registry).
+    packing, see models.registry_columns.pack_registry).
 
     Precondition for the bit-identical guarantee: every
     ``effective_balance * inactivity_score`` product must fit in uint64,
@@ -227,9 +227,7 @@ def make_epoch_sweep_step(
     returned step wraps the jitted kernel with a host-side check of
     ``max(effective) * max(scores)`` (one small device reduction + sync
     per call) and raises ``OverflowError`` when the bound is exceeded —
-    that epoch must then run through the host spec path (the
-    single-device twin, ops.sweeps.inactivity_penalties_device, reroutes
-    itself). Pass ``check_score_bound=False`` to get the raw jitted step
+    that epoch must then run through the host spec path. Pass ``check_score_bound=False`` to get the raw jitted step
     for composition inside a larger jit.
 
     The context object is unhashable, so this wrapper extracts the five

@@ -236,7 +236,8 @@ class DeviceObservatory:
     def record_route(self, kind: str, choice: str, reason: str,
                      inputs: dict) -> None:
         """One device-vs-host decision: ``kind`` names the gate
-        (``pairing``, ``sweeps``, ``shuffle``, ``bls_agg``,
+        (``pairing``, ``sweeps`` (the epoch pass's: ``device`` = the
+        fused kernel was selected), ``shuffle``, ``bls_agg``,
         ``epoch_vector``), ``choice`` where it went (``device`` /
         ``host`` / ``columnar`` / ``literal``), ``reason`` why, and
         ``inputs`` the threshold arithmetic behind it."""

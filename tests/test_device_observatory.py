@@ -13,7 +13,11 @@ from pathlib import Path
 import pytest
 
 sys.path.insert(0, str(Path(__file__).parent))
-from chain_utils import fresh_genesis, produce_chain  # noqa: E402
+from chain_utils import (  # noqa: E402
+    fresh_genesis,
+    fresh_genesis_altair,
+    produce_chain,
+)
 
 from ethereum_consensus_tpu import _device_flags  # noqa: E402
 from ethereum_consensus_tpu.executor import Executor  # noqa: E402
@@ -36,6 +40,13 @@ def _observatory_off_between_tests():
 
 def _metric(name):
     return metrics.counter(name).value()
+
+
+def _fallback_total():
+    return sum(
+        v for k, v in metrics.snapshot().items()
+        if k.startswith("epoch_vector.fallback.")
+    )
 
 
 def _recorded_events(name):
@@ -62,36 +73,25 @@ def test_compile_ledger_and_jit_cache_hits():
     compile with its signature; the same shape again is a jit-cache
     hit, not a compile."""
     pytest.importorskip("jax")
-    from ethereum_consensus_tpu.ops import sweeps
+    from ethereum_consensus_tpu.ops import shuffle
 
-    class Ctx:
-        inactivity_score_bias = 4
-        inactivity_score_recovery_rate = 16
-
-    n = 67  # a shape nothing else in the battery uses
-    packed = {
-        "inactivity_scores": np.zeros(n, np.uint64),
-        "previous_participation": np.zeros(n, np.uint8),
-        "slashed": np.zeros(n, bool),
-        "active_previous": np.ones(n, bool),
-        "eligible": np.ones(n, bool),
-    }
+    n, seed, rounds = 67, b"\x37" * 32, 10  # a shape nothing else here uses
     with device_obs.observing() as obs:
         compiles0 = _metric("device.compiles")
         hits0 = _metric("device.jit_cache.hits")
-        sweeps.inactivity_updates_device(packed, Ctx, False)
+        shuffle.shuffled_indices_device(n, seed, rounds)
         compiles_after_first = _metric("device.compiles")
-        sweeps.inactivity_updates_device(packed, Ctx, False)
+        shuffle.shuffled_indices_device(n, seed, rounds)
         assert compiles_after_first == compiles0 + 1
         assert _metric("device.compiles") == compiles_after_first
         assert _metric("device.jit_cache.hits") >= hits0 + 1
         ledger = obs.compiles()
     mine = [c for c in ledger
-            if c["fn"] == "ops.sweeps._inactivity_updates"
+            if c["fn"] == "ops.shuffle._shuffle_rounds"
             and f"[{n}]" in c["signature"]]
     assert len(mine) == 1
     assert mine[0]["compile_s"] > 0
-    assert f"uint64[{n}]" in mine[0]["signature"]
+    assert f"uint32[{n}]" in mine[0]["signature"]
 
 
 def test_recompile_sentinel_fires_once_with_both_signatures():
@@ -290,13 +290,24 @@ def test_pairing_route_journaled_and_thread_local(monkeypatch):
 
 
 def test_epoch_vector_decline_reasons_counted_and_one_shot(monkeypatch):
-    """ISSUE 10 satellite: the previously-silent declines
-    (below_threshold, device_sweeps) get the PR 5 treatment — a counter
-    per occurrence and ONE trace event per reason per process — and
-    land in the routing journal with their threshold inputs."""
+    """ISSUE 10 satellite: the previously-silent decline
+    (below_threshold) gets the PR 5 treatment — a counter per occurrence
+    and ONE trace event per reason per process — and lands in the
+    routing journal with its threshold inputs. Its opposite: an
+    installed sweeps gate is no reason to decline. A phase0 pass above
+    the (lowered) engine threshold engages with the gate on, counts no
+    fallback, never consults the gate (it selects the altair family's
+    fused kernel and nothing else) and leaves the literal stage list's
+    state."""
     from ethereum_consensus_tpu.models import epoch_vector
+    from ethereum_consensus_tpu.models.phase0 import epoch_processing
+    from ethereum_consensus_tpu.models.phase0.slot_processing import (
+        process_slots,
+    )
 
     state, ctx = fresh_genesis(64, "minimal")
+    state = state.copy()
+    process_slots(state, int(ctx.SLOTS_PER_EPOCH) - 1, ctx)
     # a clean slate for the one-shot set so this test is order-free
     monkeypatch.setattr(epoch_vector, "_FALLBACK_SEEN", set())
 
@@ -308,30 +319,40 @@ def test_epoch_vector_decline_reasons_counted_and_one_shot(monkeypatch):
         assert (
             _metric("epoch_vector.fallback.below_threshold") == below0 + 2
         )
+        literal = state.copy()
+        epoch_processing.process_epoch(literal, ctx)  # declines: the list
 
-        # device_sweeps: above the (lowered) engine threshold but with
-        # the device sweeps installed, the engine must stand aside —
-        # visibly
         monkeypatch.setattr(epoch_vector, "EPOCH_VECTOR_MIN_VALIDATORS", 0)
         monkeypatch.setattr(_device_flags, "SWEEPS_MIN_N", 1)
-        sweeps0 = _metric("epoch_vector.fallback.device_sweeps")
-        assert not epoch_vector.process_epoch_columnar(state, ctx, "phase0")
-        assert not epoch_vector.process_epoch_columnar(state, ctx, "phase0")
-        assert _metric("epoch_vector.fallback.device_sweeps") == sweeps0 + 2
+        declines0 = _fallback_total()
+        epochs0 = _metric("epoch_vector.epochs")
+        columnar = state.copy()
+        assert epoch_vector.process_epoch_columnar(columnar, ctx, "phase0")
+        assert _fallback_total() == declines0
+        assert _metric("epoch_vector.epochs") == epochs0 + 1
         journal = [r for r in obs.routes() if r["kind"] == "epoch_vector"]
+        gate = [r for r in obs.routes() if r["kind"] == "sweeps"]
         events = _recorded_events("epoch_vector.fallback")
     spans.stop_recording()
 
     by_reason = {}
     for e in events:
         by_reason.setdefault(e["args"]["reason"], []).append(e)
+    assert list(by_reason) == ["below_threshold"]
     assert len(by_reason["below_threshold"]) == 1  # one-shot
-    assert len(by_reason["device_sweeps"]) == 1
     below = [r for r in journal if r["reason"] == "below_threshold"]
-    assert below and below[0]["inputs"]["validators"] == 64
+    assert len(below) == 3  # the two above and the literal run's own
+    assert below[0]["inputs"]["validators"] == 64
     assert below[0]["inputs"]["threshold"] > 64
-    swept = [r for r in journal if r["reason"] == "device_sweeps"]
-    assert swept and swept[0]["inputs"]["sweeps_min_n"] == 1
+    engaged = [r for r in journal if r["reason"] == "engaged"]
+    assert len(engaged) == 1 and engaged[0]["choice"] == "columnar"
+    assert gate == []
+    assert type(columnar).serialize(columnar) == type(literal).serialize(
+        literal
+    )
+    assert type(columnar).hash_tree_root(columnar) == type(
+        literal
+    ).hash_tree_root(literal)
 
 
 # ---------------------------------------------------------------------------
@@ -339,9 +360,9 @@ def test_epoch_vector_decline_reasons_counted_and_one_shot(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_pipelined_replay_trace_has_device_lane_and_verify_route():
+def test_pipelined_replay_trace_has_device_lane_and_verify_route(monkeypatch):
     """A pipelined replay with recording on, crossing an epoch boundary
-    with the device sweeps installed (host JAX backend here — the same
+    with the fused epoch kernel routed (host JAX backend here — the same
     machinery chip_smoke.py drives on the chip), yields a Chrome
     trace whose `device` lane carries the compiles and whose thread
     lanes carry the transfers as facade spans inside the epoch pass; the
@@ -349,17 +370,29 @@ def test_pipelined_replay_trace_has_device_lane_and_verify_route():
     verified its window."""
     pytest.importorskip("jax")
     from ethereum_consensus_tpu import ops
+    from ethereum_consensus_tpu.models import epoch_vector
+    from ethereum_consensus_tpu.models.altair.slot_processing import (
+        process_slots,
+    )
 
-    state, ctx = fresh_genesis(64, "minimal")
-    n_blocks = 12  # minimal SLOTS_PER_EPOCH=8: crosses one boundary
-    blocks = produce_chain(state, ctx, n_blocks)
+    # the fused route is the altair family's, and the genesis epoch's
+    # boundary runs no inactivity or rewards stage: start in epoch 1
+    # (minimal SLOTS_PER_EPOCH=8) and cross the boundary at slot 16
+    state, ctx = fresh_genesis_altair(64, "minimal")
+    state = state.copy()
+    process_slots(state, 11, ctx)
+    blocks = produce_chain(state, ctx, 8, fork_name="altair")
 
-    sequential = Executor(state.copy(), ctx)
+    sequential = Executor(state.copy(), ctx)  # the literal stage list
     for b in blocks:
         sequential.apply_block(b)
 
+    # the columnar pass engages at 64 validators for this run, and its
+    # gate selects the fused jitted kernel
+    monkeypatch.setattr(epoch_vector, "EPOCH_VECTOR_MIN_VALIDATORS", 0)
+    fused0 = _metric("epoch_vector.fused.jit")
     ops.install(
-        sweeps_min_n=1,            # route the epoch sweeps through XLA
+        sweeps_min_n=1,            # the epoch pass runs the fused kernel
         shuffle_min_n=1 << 30,     # keep everything else host-side
         bls_agg_min_n=1 << 30,
         pairing_min_sets=None,
@@ -381,7 +414,10 @@ def test_pipelined_replay_trace_has_device_lane_and_verify_route():
     assert (
         ex.state.hash_tree_root() == sequential.state.hash_tree_root()
     )
-    assert compiles, "epoch-boundary sweeps should have compiled"
+    assert _metric("epoch_vector.fused.jit") == fused0 + 1
+    assert any(
+        c["fn"] == "epoch_vector.fused_epoch_kernel" for c in compiles
+    ), "the epoch boundary's fused kernel should have compiled"
 
     assert "device" in _lane_names(doc)
     device_lane = next(
@@ -396,15 +432,16 @@ def test_pipelined_replay_trace_has_device_lane_and_verify_route():
     compiles_on_lane = by_name.get("device.compile", [])
     assert compiles_on_lane, "no compile events"
     assert all(e["tid"] == device_lane for e in compiles_on_lane)
-    # the sweeps' uploads: facade spans from the h2d seam on the thread
-    # that ran the epoch stage, inside its span, with the bytes moved
+    # the fused route's uploads: facade spans from the h2d seam on the
+    # thread that ran the epoch stage, inside its span, with the bytes moved
     by_id = {e["args"]["span_id"]: e for es in by_name.values() for e in es}
     uploads = [e for name, es in by_name.items() if name.endswith(".h2d")
                for e in es]
     assert uploads, "no transfer span from the h2d seam"
     for upload in uploads:
-        assert upload["name"].startswith("ops.sweeps.")
+        assert upload["name"].startswith("epoch_vector.fused")
         parent = by_id[upload["args"]["parent_id"]]
+        assert parent["name"] == "epoch_vector.fused"
         assert parent["tid"] == upload["tid"] != device_lane
         assert upload["args"]["bytes"] > 0
 

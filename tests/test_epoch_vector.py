@@ -792,6 +792,66 @@ def test_fused_jit_route_bit_identical_through_the_pass(forced_engine,
     assert type(host).serialize(host) == type(dev).serialize(dev)
 
 
+def test_phase0_pass_runs_its_host_kernels_with_the_gate_on(forced_engine,
+                                                           monkeypatch):
+    """The sweeps gate selects the altair family's fused kernel and
+    nothing else: a phase0 pass with the gate on engages (it used to
+    stand aside for a staged device hysteresis), declines nothing, runs
+    no jitted kernel, and leaves the bytes of the gate-off pass and of
+    the literal stage list."""
+    from ethereum_consensus_tpu import _device_flags
+    from ethereum_consensus_tpu.models.phase0 import epoch_processing
+    from ethereum_consensus_tpu.models.phase0.state_transition import (
+        state_transition,
+    )
+
+    state, ctx = chain_utils.fresh_genesis_fork("phase0", 96, "minimal")
+    spe = int(ctx.SLOTS_PER_EPOCH)
+    # attested slots through epoch 1, so the rewards stage has pending
+    # attestations of the previous epoch to pay
+    for block in chain_utils.produce_chain(state, ctx, 2 * spe - 1):
+        state_transition(state, block, ctx)
+    assert int(state.slot) == 2 * spe - 1
+    assert len(state.previous_epoch_attestations) > 0
+    # stage triggers that leave the attested epochs' committees alone:
+    # hysteresis both ways, ejection candidates, a slashing that falls due
+    rng = random.Random(30)
+    for i in rng.sample(range(96), 8):
+        state.balances[i] = rng.choice([10**9, 33 * 10**9, 62 * 10**9])
+    for i in rng.sample(range(96), 4):
+        state.validators[i].effective_balance = int(ctx.ejection_balance)
+    half = int(ctx.EPOCHS_PER_SLASHINGS_VECTOR) // 2
+    for i in rng.sample(range(96), 3):
+        state.validators[i].slashed = True
+        state.validators[i].withdrawable_epoch = 1 + half
+    state.slashings[1 % int(ctx.EPOCHS_PER_SLASHINGS_VECTOR)] = 10**9
+    chain_utils._strip_spec_caches(state)
+
+    literal = state.copy()
+    os.environ["ECT_EPOCH_VECTOR"] = "off"
+    try:
+        epoch_processing.process_epoch(literal, ctx)
+    finally:
+        os.environ.pop("ECT_EPOCH_VECTOR", None)
+
+    gate_off = state.copy()
+    assert epoch_vector.process_epoch_columnar(gate_off, ctx, "phase0")
+
+    monkeypatch.setattr(_device_flags, "SWEEPS_MIN_N", 1)
+    before = metrics.snapshot()
+    gate_on = state.copy()
+    assert epoch_vector.process_epoch_columnar(gate_on, ctx, "phase0")
+    moved = {k: v for k, v in metrics.delta(before).items() if v}
+    assert moved.get("epoch_vector.epochs") == 1
+    assert not [k for k in moved if k.startswith("epoch_vector.fallback.")]
+    assert not [k for k in moved if k.startswith("epoch_vector.fused")]
+    assert not [k for k in moved if k.startswith("device.")]
+
+    assert_bit_identical(gate_on, gate_off, "phase0, gate on vs off")
+    assert_bit_identical(gate_on, literal, "phase0, gate on vs literal")
+    assert type(state).serialize(state) != type(literal).serialize(literal)
+
+
 # ---------------------------------------------------------------------------
 # bench smoke: the 2^18 columnar-primary engagement check (make bench-smoke)
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
 """ops.install() routing: the spec path must produce bit-identical results
-with device sweeps/shuffle routing on vs off (VERDICT #7 — the twins are
-cross-checked numerically in test_ops_sweeps; here the *wiring* through the
-real spec functions is proven)."""
+with the fused epoch kernel and the device shuffle routed on vs off (the
+kernels are cross-checked numerically in test_epoch_vector and
+test_ops_shuffle; here the *wiring* through ``process_slots`` and the
+committee helpers is proven)."""
 
 import sys
 from pathlib import Path
@@ -20,7 +21,6 @@ from chain_utils import (  # noqa: E402
 )
 
 from ethereum_consensus_tpu import ops  # noqa: E402
-from ethereum_consensus_tpu.models import altair  # noqa: E402
 from ethereum_consensus_tpu.models.altair.state_transition import (  # noqa: E402
     state_transition,
 )
@@ -49,9 +49,14 @@ def attested_state():
 
 
 @pytest.fixture
-def installed():
+def installed(monkeypatch):
     """Device routing with thresholds lowered so a 32-validator registry
-    takes the device path."""
+    takes the device routes: the columnar epoch pass engages
+    (``EPOCH_VECTOR_MIN_VALIDATORS`` 0) and its gate selects the fused
+    jitted kernel (``sweeps_min_n`` 1); the shuffle goes to its kernel."""
+    from ethereum_consensus_tpu.models import epoch_vector
+
+    monkeypatch.setattr(epoch_vector, "EPOCH_VECTOR_MIN_VALIDATORS", 0)
     ops.install(sweeps_min_n=1, shuffle_min_n=1)
     try:
         yield
@@ -59,62 +64,138 @@ def installed():
         ops.uninstall()
 
 
-def test_flag_deltas_identical(attested_state, installed):
+def _skewed(state):
+    """A copy with scores that move, a missed-target penalty to pay and
+    hysteresis to fire both ways."""
+    state = state.copy()
+    for i, score in ((2, 7), (5, 40), (11, 3)):
+        state.inactivity_scores[i] = score
+    state.balances[0] += 10**9
+    state.balances[1] -= min(3 * 10**9, state.balances[1])
+    return state
+
+
+def _balances(state):
+    return list(state.balances)
+
+
+def _inactivity_scores(state):
+    return list(state.inactivity_scores)
+
+
+def _effective_balances(state):
+    return [v.effective_balance for v in state.validators]
+
+
+def _root(state):
+    return type(state).hash_tree_root(state)
+
+
+@pytest.mark.parametrize(
+    "result",
+    [_balances, _inactivity_scores, _effective_balances, _root],
+    ids=["balances", "inactivity_scores", "effective_balances", "chain_root"],
+)
+def test_fused_route_identical_through_process_slots(
+    attested_state, installed, result
+):
+    """``process_slots`` over three epoch boundaries (the genesis epoch's,
+    which runs no inactivity or rewards stage, and two that do) leaves the
+    same stage-visible result with routing on (the pass runs inactivity +
+    rewards as the fused jitted kernel, counted) as with routing off (the
+    same pass on its host kernels)."""
+    from ethereum_consensus_tpu.models.phase0 import helpers as ph
+    from ethereum_consensus_tpu.telemetry import metrics
+
     state, ctx = attested_state
-    h = altair.build(ctx.preset)  # noqa: F841 — force container build
+    state = _skewed(state)
+    target = (3 * ctx.SLOTS_PER_EPOCH) + 1
+    fused = metrics.counter("epoch_vector.fused.jit")
+    epochs = metrics.counter("epoch_vector.epochs")
+
+    ops.uninstall()
+    at = fused.value(), epochs.value()
+    host_state = state.copy()
+    process_slots(host_state, target, ctx)
+    assert (fused.value(), epochs.value()) == (at[0], at[1] + 3)
+
+    ops.install(sweeps_min_n=1, shuffle_min_n=1)
+    ph._SHUFFLE_CACHE.clear()
+    dev_state = state.copy()
+    process_slots(dev_state, target, ctx)
+    assert (fused.value(), epochs.value()) == (at[0] + 2, at[1] + 6)
+
+    assert result(dev_state) == result(host_state)
+    assert result(host_state) != result(state)
+
+
+def _flag_deltas(state, ctx):
     from ethereum_consensus_tpu.models.altair import helpers as ah
 
-    for flag_index in range(3):
-        ops.uninstall()
-        host = ah.get_flag_index_deltas(state, flag_index, ctx)
-        ops.install(sweeps_min_n=1, shuffle_min_n=1)
-        dev = ah.get_flag_index_deltas(state, flag_index, ctx)
-        assert [list(x) for x in dev] == [list(x) for x in host]
+    return [ah.get_flag_index_deltas(state, flag, ctx) for flag in range(3)]
 
 
-def test_inactivity_identical(attested_state, installed):
-    state, ctx = attested_state
+def _penalties_altair(state, ctx):
     from ethereum_consensus_tpu.models.altair import helpers as ah
+
+    return ah.get_inactivity_penalty_deltas(state, ctx)
+
+
+def _penalties_bellatrix(state, ctx):
+    from ethereum_consensus_tpu.models.bellatrix import helpers as bh
+
+    return bh.get_inactivity_penalty_deltas(state, ctx)
+
+
+def _inactivity_updates(state, ctx):
     from ethereum_consensus_tpu.models.altair.epoch_processing import (
         process_inactivity_updates,
     )
 
-    ops.uninstall()
-    host_pair = ah.get_inactivity_penalty_deltas(state, ctx)
-    host_state = state.copy()
-    process_inactivity_updates(host_state, ctx)
-
-    ops.install(sweeps_min_n=1, shuffle_min_n=1)
-    dev_pair = ah.get_inactivity_penalty_deltas(state, ctx)
-    dev_state = state.copy()
-    process_inactivity_updates(dev_state, ctx)
-
-    assert [list(x) for x in dev_pair] == [list(x) for x in host_pair]
-    assert list(dev_state.inactivity_scores) == list(host_state.inactivity_scores)
+    process_inactivity_updates(state, ctx)
+    return _inactivity_scores(state)
 
 
-def test_effective_balance_identical(attested_state, installed):
-    state, ctx = attested_state
+def _effective_balance_updates(state, ctx):
     from ethereum_consensus_tpu.models.phase0.epoch_processing import (
         process_effective_balance_updates,
     )
 
-    # skew some balances so hysteresis actually fires
+    process_effective_balance_updates(state, ctx)
+    return _effective_balances(state)
+
+
+@pytest.mark.parametrize(
+    "literal",
+    [
+        _flag_deltas, _penalties_altair, _penalties_bellatrix,
+        _inactivity_updates, _effective_balance_updates,
+    ],
+    ids=lambda fn: fn.__name__.lstrip("_"),
+)
+def test_literal_functions_are_host_code_whatever_is_installed(
+    attested_state, installed, monkeypatch, literal
+):
+    """The oracle the tests and the spec-test rewards surface hold every
+    fast path to: with the gate on (and the columnar pass out of the
+    way) a literal per-fork function consults no gate, opens no transfer
+    seam, compiles nothing, and answers as it does with nothing
+    installed."""
+    from ethereum_consensus_tpu.telemetry import device as device_obs
+
+    monkeypatch.setenv("ECT_EPOCH_VECTOR", "off")
+    state, ctx = attested_state
     state = state.copy()
-    state.balances[0] += 10**9
-    state.balances[1] -= min(10**9, state.balances[1])
+    process_slots(state, 2 * ctx.SLOTS_PER_EPOCH - 1, ctx)
+    state = _skewed(state)
 
+    with device_obs.observing() as obs:
+        got = literal(state.copy(), ctx)
+        assert obs.routes() == []
+        assert obs.compiles() == []
+        assert obs.transfer_summary()["sites"] == {}
     ops.uninstall()
-    host_state = state.copy()
-    process_effective_balance_updates(host_state, ctx)
-
-    ops.install(sweeps_min_n=1, shuffle_min_n=1)
-    dev_state = state.copy()
-    process_effective_balance_updates(dev_state, ctx)
-
-    assert [v.effective_balance for v in dev_state.validators] == [
-        v.effective_balance for v in host_state.validators
-    ]
+    assert got == literal(state.copy(), ctx)
 
 
 def test_committee_identical(attested_state, installed):
@@ -127,28 +208,6 @@ def test_committee_identical(attested_state, installed):
     ph._SHUFFLE_CACHE.clear()
     dev = ph.get_beacon_committee(state, state.slot, 0, ctx)
     assert dev == host
-
-
-def test_multi_epoch_chain_identical(attested_state, installed):
-    """A full multi-slot chain segment produces the same state root with
-    routing on vs off (the epoch boundary exercises every routed sweep)."""
-    state, ctx = attested_state
-    target = (2 * ctx.SLOTS_PER_EPOCH) + 1
-
-    ops.uninstall()
-    host_state = state.copy()
-    process_slots(host_state, target, ctx)
-
-    ops.install(sweeps_min_n=1, shuffle_min_n=1)
-    from ethereum_consensus_tpu.models.phase0 import helpers as ph
-
-    ph._SHUFFLE_CACHE.clear()
-    dev_state = state.copy()
-    process_slots(dev_state, target, ctx)
-
-    assert type(host_state).hash_tree_root(host_state) == type(
-        dev_state
-    ).hash_tree_root(dev_state)
 
 
 def test_install_tells_the_allocator_to_keep_freed_memory(installed):

@@ -162,7 +162,7 @@ from ethereum_consensus_tpu.models.altair import helpers as ah
 from ethereum_consensus_tpu.models.altair.epoch_processing import (
     process_inactivity_updates, process_rewards_and_penalties,
 )
-from ethereum_consensus_tpu.ops.sweeps import pack_registry
+from ethereum_consensus_tpu.models.registry_columns import pack_registry
 from ethereum_consensus_tpu.parallel import chip_mesh
 from ethereum_consensus_tpu.parallel.step import (
     make_epoch_sweep_step, pad_registry_for_mesh,
