@@ -523,53 +523,59 @@ def _sync(state, context, fork):
     if cols is None:
         fallback("columns_unavailable")
         return None
-    vc = cols.validator_columns(state)
-    balances = cols.list_column(state, "balances")
-    if vc is None or balances is None:
-        fallback("columns_unavailable")
-        return None
-    n = len(state.validators)
-    if balances.shape[0] != n:
-        fallback("length_mismatch")
-        return None
-    ec = _EpochColumns()
-    ec.np = np
-    ec.state = state
-    ec.context = context
-    ec.fork = fork
-    ec.cfg = _FORK_CFG[fork]
-    ec.n = n
-    cur = int(state.slot) // int(context.SLOTS_PER_EPOCH)
-    ec.cur = cur
-    ec.prev = GENESIS_EPOCH if cur == GENESIS_EPOCH else cur - 1
-    ec.increment = int(context.EFFECTIVE_BALANCE_INCREMENT)
-    ec.b_eff = vc["effective_balance"]
-    ec.b_elig = vc["activation_eligibility_epoch"]
-    ec.b_act = vc["activation_epoch"]
-    ec.b_exit = vc["exit_epoch"]
-    ec.b_wdr = vc["withdrawable_epoch"]
-    ec.b_prefix = vc["withdrawal_prefix"]
-    ec.slashed = vc["slashed"]
-    ec.b_balances = balances
-    if ec.cfg["family"] == "altair":
-        prev_part = cols.list_column(state, "previous_epoch_participation")
-        cur_part = cols.list_column(state, "current_epoch_participation")
-        inact = cols.list_column(state, "inactivity_scores")
-        if prev_part is None or cur_part is None or inact is None:
+    # the column acquisition, apart from the guards and masks below
+    with trace.span("epoch_vector.sync.columns"):
+        vc = cols.validator_columns(state)
+        balances = cols.list_column(state, "balances")
+        if vc is None or balances is None:
             fallback("columns_unavailable")
             return None
-        if (
-            prev_part.shape[0] != n
-            or cur_part.shape[0] != n
-            or inact.shape[0] != n
-        ):
+        n = len(state.validators)
+        if balances.shape[0] != n:
             fallback("length_mismatch")
             return None
-        ec.prev_part = prev_part
-        ec.cur_part = cur_part
-        ec.b_inact = inact
-    else:
-        ec.prev_part = ec.cur_part = ec.b_inact = None
+        ec = _EpochColumns()
+        ec.np = np
+        ec.state = state
+        ec.context = context
+        ec.fork = fork
+        ec.cfg = _FORK_CFG[fork]
+        ec.n = n
+        cur = int(state.slot) // int(context.SLOTS_PER_EPOCH)
+        ec.cur = cur
+        ec.prev = GENESIS_EPOCH if cur == GENESIS_EPOCH else cur - 1
+        ec.increment = int(context.EFFECTIVE_BALANCE_INCREMENT)
+        ec.b_eff = vc["effective_balance"]
+        ec.b_elig = vc["activation_eligibility_epoch"]
+        ec.b_act = vc["activation_epoch"]
+        ec.b_exit = vc["exit_epoch"]
+        ec.b_wdr = vc["withdrawable_epoch"]
+        ec.b_prefix = vc["withdrawal_prefix"]
+        ec.slashed = vc["slashed"]
+        ec.b_balances = balances
+        if ec.cfg["family"] == "altair":
+            prev_part = cols.list_column(
+                state, "previous_epoch_participation"
+            )
+            cur_part = cols.list_column(
+                state, "current_epoch_participation"
+            )
+            inact = cols.list_column(state, "inactivity_scores")
+            if prev_part is None or cur_part is None or inact is None:
+                fallback("columns_unavailable")
+                return None
+            if (
+                prev_part.shape[0] != n
+                or cur_part.shape[0] != n
+                or inact.shape[0] != n
+            ):
+                fallback("length_mismatch")
+                return None
+            ec.prev_part = prev_part
+            ec.cur_part = cur_part
+            ec.b_inact = inact
+        else:
+            ec.prev_part = ec.cur_part = ec.b_inact = None
 
     # --- u64 lane guards: everything the pass adds/multiplies must stay
     # below 2^63 so no kernel op can wrap; a state outside the lane

@@ -54,7 +54,7 @@ from __future__ import annotations
 import threading
 
 from .. import _env
-from ..ssz.core import CachedRootList, bulk_store
+from ..ssz.core import CachedRootList, _clean_pack_bytes, bulk_store
 from ..telemetry import device as _device_obs
 from ..telemetry import memory as _memory
 from ..telemetry import metrics
@@ -230,15 +230,28 @@ def _build_list_col(src, dtype, vmax):
     np = _np()
     if np is None or src.__class__ is not CachedRootList:
         return None
-    try:
-        wide = np.array(src, dtype=np.uint64)
-    except (TypeError, ValueError, OverflowError):
-        return None
-    if wide.ndim != 1 or wide.shape[0] != len(src):
-        return None
-    if vmax < (1 << 64) - 1 and bool((wide > vmax).any()):
-        return None
-    arr = wide.astype(dtype) if dtype is not np.uint64 else wide
+    esize = np.dtype(dtype).itemsize
+    raw = _clean_pack_bytes(src, esize)
+    if raw is not None:
+        # the list's serialization is already in memory (its clean
+        # _pack_tree): the column is those bytes, as an owned copy (the
+        # buffer is spliced in place and shared with copy siblings)
+        arr = np.frombuffer(raw, dtype="<u%d" % esize, count=len(src)).astype(
+            dtype
+        )
+        if vmax < (1 << (8 * esize)) - 1 and bool((arr > vmax).any()):
+            return None
+        metrics.counter("ops_vector.columns.from_pack").inc()
+    else:
+        try:
+            wide = np.array(src, dtype=np.uint64)
+        except (TypeError, ValueError, OverflowError):
+            return None
+        if wide.ndim != 1 or wide.shape[0] != len(src):
+            return None
+        if vmax < (1 << 64) - 1 and bool((wide > vmax).any()):
+            return None
+        arr = wide.astype(dtype) if dtype is not np.uint64 else wide
     src._col_cache = ("list", arr, vmax)
     src._col_owned = True
     src._col_dirty = set()

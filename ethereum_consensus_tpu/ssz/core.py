@@ -857,6 +857,34 @@ def _clean_wire_column(values: "CachedRootList", esize: int):
     return None
 
 
+def _clean_pack_bytes(values: "CachedRootList", esize: int):
+    """_clean_wire_column's read-direction twin: the raw buffer of the
+    list's ``_pack_tree`` when it IS the list's serialization at
+    ``esize`` bytes an element, else None: a tree rooted under the
+    basic-uint key of that width, nothing marked since
+    (``_dirty_groups == set()``; None means untracked), every element
+    still an int, and exactly ``len(values) * esize`` bytes. These are
+    the conditions under which _packed_splice hands back the stored root
+    without looking at an element, so a column made from the buffer
+    trusts nothing the state root does not trust. The buffer is a
+    bytearray that _packed_splice writes in place and that copy siblings
+    share while ``_memos_owned`` is false: callers copy, never keep a
+    view (models/ops_vector.py::_build_list_col is the one reader)."""
+    pt = values._pack_tree
+    if pt is None or values._dirty_groups != set():
+        return None
+    key = pt[0]
+    if (
+        key[0] != "u"
+        or not isinstance(key[1], _UintType)
+        or key[1].byte_length != esize
+        or values._uniform_kind != ("int",)
+        or len(pt[1]) != len(values) * esize
+    ):
+        return None
+    return pt[1]
+
+
 def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
     """Dirty-group incremental root for a packed basic/bytes32 collection:
     re-serialize ONLY the dirty 4096-element groups into the retained raw

@@ -40,3 +40,35 @@ def run_in_cpu_mesh(code: str, n_devices: int = 8, timeout: int = 600) -> str:
 @pytest.fixture
 def cpu_mesh():
     return run_in_cpu_mesh
+
+
+@pytest.fixture
+def small_groups():
+    """Shrink the dirty-group geometry so small collections exercise many
+    stored-level groups (the module globals exist for exactly this, and
+    the walkers read them live): tier-1 covers the tracked-list branches
+    cheaply."""
+    from ethereum_consensus_tpu.ssz import core as ssz_core
+
+    saved = (
+        ssz_core._DIRTY_GROUP_SHIFT,
+        ssz_core._DIRTY_TRACK_MIN_CHUNKS,
+        ssz_core._BULK_ROOTS_MIN,
+    )
+    ssz_core._DIRTY_GROUP_SHIFT = 2
+    ssz_core._DIRTY_TRACK_MIN_CHUNKS = 1 << 2
+    ssz_core._BULK_ROOTS_MIN = 4
+    try:
+        yield
+    finally:
+        (
+            ssz_core._DIRTY_GROUP_SHIFT,
+            ssz_core._DIRTY_TRACK_MIN_CHUNKS,
+            ssz_core._BULK_ROOTS_MIN,
+        ) = saved
+        # a genesis first built in here was warmed under the shrunk
+        # geometry, and every later copy in this process would carry it
+        chain_utils = sys.modules.get("chain_utils")
+        if chain_utils is not None:
+            chain_utils.cached_genesis.cache_clear()
+            chain_utils._cached_genesis_fork.cache_clear()

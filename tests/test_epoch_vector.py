@@ -202,6 +202,61 @@ def test_post_epoch_root_packs_from_the_columns(fork, forced_engine):
         assert_bit_identical(s_col, s_lit, f"{fork} epoch {epoch}")
 
 
+@pytest.mark.parametrize("fork", FORKS[1:])
+def test_sync_takes_columns_from_clean_pack_trees(
+    fork, forced_engine, small_groups
+):
+    """A list that was assigned and then rooted holds its serialization
+    (a clean ``_pack_tree``) and no column: ``_sync`` makes the column
+    from those bytes (``ops_vector.columns.from_pack``), once for each
+    such list and for no other, and the post-state is the literal
+    oracle's, root AND bytes. 160 validators put every scalar list over
+    the tracking threshold of ``small_groups``."""
+    from ethereum_consensus_tpu.ssz import core as ssz_core
+
+    state, ctx = chain_utils.fresh_genesis_fork(fork, 160, "minimal")
+    sp = _slot_processing(fork)
+    spe = int(ctx.SLOTS_PER_EPOCH)
+    from_pack = metrics.counter("ops_vector.columns.from_pack")
+    engaged = metrics.counter("epoch_vector.epochs")
+    widths = {
+        "balances": 8, "inactivity_scores": 8,
+        "previous_epoch_participation": 1, "current_epoch_participation": 1,
+    }
+    s_col, s_lit = state.copy(), state.copy()
+    for epoch in (1, 2):
+        for s in (s_col, s_lit):
+            sp.process_slots(s, epoch * spe - 1, ctx)
+            n = len(s.validators)
+            s.previous_epoch_participation = [0b111] * n
+            s.current_epoch_participation = [0b110] * n
+            type(s).hash_tree_root(s)
+        served = [
+            name for name, size in widths.items()
+            for lst in [getattr(s_col, name)]
+            if ssz_core._clean_pack_bytes(lst, size) is not None
+            and lst._col_cache is None
+        ]
+        assert {
+            "previous_epoch_participation", "current_epoch_participation"
+        } <= set(served)
+        if epoch == 2:
+            # the first boundary's commit left these two their columns
+            assert "balances" not in served
+            assert "inactivity_scores" not in served
+        before = from_pack.value(), engaged.value()
+        sp.process_slots(s_col, epoch * spe, ctx)
+        assert from_pack.value() - before[0] == len(served), served
+        assert engaged.value() - before[1] == 1
+        os.environ["ECT_EPOCH_VECTOR"] = "off"
+        try:
+            sp.process_slots(s_lit, epoch * spe, ctx)
+        finally:
+            os.environ.pop("ECT_EPOCH_VECTOR", None)
+        assert_bit_identical(s_col, s_lit, f"{fork} epoch {epoch}")
+        assert_column_consistency(s_col, f"{fork} epoch {epoch}")
+
+
 def _mainnet_registry_world(seed):
     """A registry of the benchmark's ``mainnet-deneb-2m`` composition at
     2^13 entries (its groups times 2^-8: half the rows exited and

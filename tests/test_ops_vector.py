@@ -377,6 +377,178 @@ def test_exotic_value_disarms_column():
 
 
 # ---------------------------------------------------------------------------
+# a column off the list's clean _pack_tree (ssz/core.py _clean_pack_bytes)
+# ---------------------------------------------------------------------------
+
+# element type (its name is its numpy dtype's) -> (the vmax its column
+# record carries, a length over the tracking threshold of small_groups)
+_PACK_CASES = {"uint8": (0xFF, 300), "uint64": (2**64 - 1, 50)}
+_PACK_LIMIT = 1 << 12
+_PACK_FAULTS = ["dirty", "untracked", "absent", "width", "length", "kind"]
+
+
+def _pack_list_type(width):
+    from ethereum_consensus_tpu.ssz import core as ssz_core
+
+    return ssz_core.List[getattr(ssz_core, width), _PACK_LIMIT]
+
+
+def _pack_ints(width, top=None):
+    """(seeded ints of the case's length up to ``top``, the case's vmax)."""
+    vmax, n = _PACK_CASES[width]
+    rng = random.Random(n)
+    top = min(vmax, 2**63) if top is None else top
+    return [rng.randrange(top + 1) for _ in range(n)], vmax
+
+
+def _rooted_list(width, top=None):
+    """(list type, a CachedRootList rooted once and holding no column, the
+    same ints as a plain list, the column record's vmax)."""
+    from ethereum_consensus_tpu.ssz.core import CachedRootList
+
+    ints, vmax = _pack_ints(width, top)
+    lst = CachedRootList(ints)
+    LT = _pack_list_type(width)
+    LT.hash_tree_root(lst)
+    assert lst._pack_tree is not None and lst._dirty_groups == set()
+    assert lst._col_cache is None and lst._uniform_kind == ("int",)
+    return LT, lst, ints, vmax
+
+
+def _column_counters() -> tuple:
+    return (
+        metrics.counter("ops_vector.columns.from_pack").value(),
+        metrics.counter("ops_vector.columns.builds").value(),
+    )
+
+
+@pytest.mark.parametrize("width", ["uint8", "uint64"])
+def test_pack_column_is_the_lists_ints(width, small_groups):
+    """(a) no column, a clean _pack_tree of the asked width: the column
+    is the tree's bytes, counted, installed owned and clean."""
+    import numpy as np
+
+    _LT, lst, ints, vmax = _rooted_list(width)
+    before = _column_counters()
+    col = ops_vector._sync_list_col(lst, np.dtype(width), vmax)
+    assert _column_counters() == (before[0] + 1, before[1] + 1)
+    assert col.dtype == np.dtype(width) and col.flags.owndata
+    assert np.array_equal(col, np.array(list(lst), dtype=np.uint64).astype(width))
+    assert lst._col_cache[0] == "list" and lst._col_cache[1] is col
+    assert lst._col_cache[2] == vmax
+    assert lst._col_owned is True and lst._col_dirty == set()
+    # served from the record now: neither counter moves again
+    assert ops_vector._sync_list_col(lst, np.dtype(width), vmax) is col
+    assert _column_counters() == (before[0] + 1, before[1] + 1)
+    assert list(lst) == ints
+
+
+@pytest.mark.parametrize("fault", _PACK_FAULTS)
+@pytest.mark.parametrize("width", ["uint8", "uint64"])
+def test_pack_column_refused(width, fault, small_groups):
+    """(b) a tree that is not provably the list's serialization at the
+    asked width is not read: the ints are unboxed as before."""
+    import numpy as np
+
+    from ethereum_consensus_tpu.ssz.core import CachedRootList
+
+    asked = width
+    _LT, lst, ints, vmax = _rooted_list(
+        width, top=0x7F if fault == "width" else None
+    )
+    if fault == "dirty":
+        lst[5] = ints[5] = 7  # one write since the root
+        assert lst._dirty_groups == {5 >> 2}
+    elif fault == "untracked":
+        lst._dirty_groups = None
+    elif fault == "absent":
+        lst = CachedRootList(ints)  # never rooted
+        assert lst._pack_tree is None
+    elif fault == "width":
+        # the values fit both widths, so only the tree's key and its
+        # byte length can refuse the other dtype
+        asked = "uint64" if width == "uint8" else "uint8"
+        vmax = _PACK_CASES[asked][0]
+    elif fault == "length":
+        lst._pack_tree[1].extend(bytes(np.dtype(width).itemsize))
+    else:
+        lst._uniform_kind = None
+    before = _column_counters()
+    col = ops_vector._sync_list_col(lst, np.dtype(asked), vmax)
+    assert _column_counters() == (before[0], before[1] + 1)
+    assert col.dtype == np.dtype(asked)
+    assert np.array_equal(col, np.array(ints, dtype=np.uint64).astype(asked))
+    assert lst._col_owned is True and lst._col_dirty == set()
+
+
+@pytest.mark.parametrize("width", ["uint8", "uint64"])
+def test_pack_column_is_a_copy(width, small_groups):
+    """(c) the tree's buffer is spliced in place by the next root: the
+    array handed out earlier must not follow it behind _col_dirty."""
+    import numpy as np
+
+    LT, lst, ints, vmax = _rooted_list(width)
+    col = ops_vector._sync_list_col(lst, np.dtype(width), vmax)
+    raw = lst._pack_tree[1]
+    i = len(ints) - 3
+    old, new = ints[i], (ints[i] + 1) & 0x7F
+    lst[i] = new
+    assert lst._col_dirty == {i}
+    LT.hash_tree_root(lst)
+    size = np.dtype(width).itemsize
+    assert lst._pack_tree[1] is raw  # owned: spliced where it lies
+    assert int.from_bytes(raw[i * size:(i + 1) * size], "little") == new
+    assert int(col[i]) == old and lst._col_dirty == {i}
+    before = _column_counters()
+    rows = metrics.counter("ops_vector.columns.refresh_rows").value()
+    again = ops_vector._sync_list_col(lst, np.dtype(width), vmax)
+    assert again is col and int(again[i]) == new
+    assert _column_counters() == before  # a one-row refresh, no build
+    assert metrics.counter("ops_vector.columns.refresh_rows").value() == rows + 1
+    assert lst._col_dirty == set()
+
+
+@pytest.mark.parametrize("width", ["uint8", "uint64"])
+def test_pack_column_of_copy_siblings(width, small_groups):
+    """(d) copies share the tree (``_memos_owned`` false on both): each
+    builds its own column off it, and one's writes stay its own."""
+    import numpy as np
+
+    from ethereum_consensus_tpu.ssz.core import CachedRootList, Container
+
+    class Holder(Container):
+        values: _pack_list_type(width)
+
+    ints, vmax = _pack_ints(width)
+    a = Holder(values=list(ints))
+    Holder.hash_tree_root(a)
+    b = a.copy()
+    la, lb = a.values, b.values
+    assert la.__class__ is CachedRootList and lb.__class__ is CachedRootList
+    assert la._pack_tree is lb._pack_tree and la._pack_tree is not None
+    assert la._memos_owned is False and lb._memos_owned is False
+    before = _column_counters()
+    col_a = ops_vector._sync_list_col(la, np.dtype(width), vmax)
+    i = 7
+    new = (ints[i] + 1) & 0x7F
+    la[i] = new
+    Holder.hash_tree_root(a)  # clones the shared buffer, then splices
+    assert la._pack_tree is not lb._pack_tree
+    col_b = ops_vector._sync_list_col(lb, np.dtype(width), vmax)
+    assert _column_counters() == (before[0] + 2, before[1] + 2)
+    assert col_a is not col_b
+    assert np.array_equal(col_b, np.array(ints, dtype=np.uint64).astype(width))
+    col_a = ops_vector._sync_list_col(la, np.dtype(width), vmax)
+    assert int(col_a[i]) == new and int(col_b[i]) == ints[i]
+    lb[i + 1] = 3
+    assert int(ops_vector._sync_list_col(lb, np.dtype(width), vmax)[i + 1]) == 3
+    assert int(col_a[i + 1]) == ints[i + 1] and list(la)[i + 1] == ints[i + 1]
+    assert Holder.hash_tree_root(b) == Holder.hash_tree_root(
+        Holder(values=ints[:i + 1] + [3] + ints[i + 2:])
+    )
+
+
+# ---------------------------------------------------------------------------
 # withdrawal sweep parity
 # ---------------------------------------------------------------------------
 

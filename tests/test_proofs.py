@@ -42,33 +42,6 @@ from ethereum_consensus_tpu.ssz.merkle import (  # noqa: E402
 from ethereum_consensus_tpu.telemetry import metrics  # noqa: E402
 
 
-@pytest.fixture
-def small_groups():
-    """Shrunk dirty-group geometry (the ssz-incremental fixture): small
-    collections exercise many stored-level groups, and the walker reads
-    the live globals, so tier-1 covers multi-group branches cheaply."""
-    saved = (
-        ssz_core._DIRTY_GROUP_SHIFT,
-        ssz_core._DIRTY_TRACK_MIN_CHUNKS,
-        ssz_core._BULK_ROOTS_MIN,
-    )
-    ssz_core._DIRTY_GROUP_SHIFT = 2
-    ssz_core._DIRTY_TRACK_MIN_CHUNKS = 1 << 2
-    ssz_core._BULK_ROOTS_MIN = 4
-    try:
-        yield
-    finally:
-        (
-            ssz_core._DIRTY_GROUP_SHIFT,
-            ssz_core._DIRTY_TRACK_MIN_CHUNKS,
-            ssz_core._BULK_ROOTS_MIN,
-        ) = saved
-        # a genesis first built in here was warmed under the shrunk
-        # geometry, and every later copy in this process would carry it
-        chain_utils.cached_genesis.cache_clear()
-        chain_utils._cached_genesis_fork.cache_clear()
-
-
 # ---------------------------------------------------------------------------
 # satellite: the three branch sources pinned identical at the chunk layer
 # ---------------------------------------------------------------------------

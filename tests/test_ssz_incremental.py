@@ -78,34 +78,6 @@ def _naive_u64_list_root(values, limit: int) -> bytes:
     return _naive_uint_list_root(values, limit, 8)
 
 
-@pytest.fixture
-def small_groups():
-    """Shrink the dirty-group geometry so small collections exercise many
-    groups (the module globals exist for exactly this)."""
-    saved = (
-        ssz_core._DIRTY_GROUP_SHIFT,
-        ssz_core._DIRTY_TRACK_MIN_CHUNKS,
-        ssz_core._BULK_ROOTS_MIN,
-    )
-    ssz_core._DIRTY_GROUP_SHIFT = 2
-    ssz_core._DIRTY_TRACK_MIN_CHUNKS = 1 << 2
-    ssz_core._BULK_ROOTS_MIN = 4
-    try:
-        yield
-    finally:
-        (
-            ssz_core._DIRTY_GROUP_SHIFT,
-            ssz_core._DIRTY_TRACK_MIN_CHUNKS,
-            ssz_core._BULK_ROOTS_MIN,
-        ) = saved
-        # a genesis first built in here was warmed under the shrunk
-        # geometry, and every later copy in this process would carry it
-        import chain_utils
-
-        chain_utils.cached_genesis.cache_clear()
-        chain_utils._cached_genesis_fork.cache_clear()
-
-
 # ---------------------------------------------------------------------------
 # work-done regression (real 4096-leaf geometry)
 # ---------------------------------------------------------------------------
