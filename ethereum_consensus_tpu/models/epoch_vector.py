@@ -215,13 +215,16 @@ def jitted_kernels() -> dict:
             # the FUSED device epoch kernel (ISSUE 14): inactivity +
             # flag deltas + inactivity penalties + application as ONE
             # dispatch — dynamic per-epoch u64 scalars, static chain
-            # constants, so a steady-state replay compiles exactly once
+            # constants, so a steady-state replay compiles exactly once.
+            # ``leaking`` (argument 15) is traced with the scalars: the
+            # boundary at which finality is lost, or comes back, runs
+            # the program the boundaries before it compiled
             "fused_epoch": _device_obs.observe_jit(
                 jax.jit(
                     fused_epoch,
                     # bias, recovery, weights, weight_denominator,
-                    # leaking, head/target flag indices
-                    static_argnums=(11, 12, 13, 14, 15, 16, 17),
+                    # head/target flag indices
+                    static_argnums=(11, 12, 13, 14, 16, 17),
                 ),
                 "epoch_vector.fused_epoch_kernel",
             ),
@@ -327,11 +330,15 @@ def fused_epoch_kernel(xp, balances, eff, prev_part, slashed, active_prev,
     the live host fallback), so the outputs are bit-identical u64.
 
     ``increment``/``brpi``/``active_increments``/``denominator`` are
-    DYNAMIC u64 scalars (a steady-state replay compiles once);
-    ``bias``/``recovery_rate``/``weights``/``weight_denominator``/
-    ``leaking``/flag indices are static chain constants. ``psum`` wraps
-    the scalar reductions for the mesh-sharded twin
-    (parallel/epoch.py); None runs them whole-array.
+    DYNAMIC u64 scalars (a steady-state replay compiles once), and so is
+    ``leaking`` (a bool): it only selects, with ``where``, between two
+    pairs of scalars (the recovery rate or 0, a flag's increments or 0),
+    so one program serves the epochs with finality and those without,
+    neither crossing compiles, and the rows' arithmetic is the same.
+    ``bias``/``recovery_rate``/``weights``/``weight_denominator``/flag
+    indices are static chain constants. ``psum`` wraps the scalar
+    reductions for the mesh-sharded twin (parallel/epoch.py); None runs
+    them whole-array.
 
     Returns ``(new_scores, new_balances, wrapped_lanes)`` — a nonzero
     wrap count means a u64 wrap the caller's lane guards should have
@@ -352,11 +359,13 @@ def fused_epoch_kernel(xp, balances, eff, prev_part, slashed, active_prev,
     new_scores = xp.where(
         eligible & ~participating, new_scores + xp.uint64(bias), new_scores
     )
-    if not leaking:
-        rec = xp.uint64(recovery_rate)
-        new_scores = xp.where(
-            eligible, new_scores - xp.minimum(rec, new_scores), new_scores
-        )
+    # a leak's two differences are two scalars, so no row pays for the
+    # choice: the recovery rate is 0, and no increment earns a flag reward
+    finalizing = xp.logical_not(leaking)
+    rec = xp.where(finalizing, xp.uint64(recovery_rate), zero)
+    new_scores = xp.where(
+        eligible, new_scores - xp.minimum(rec, new_scores), new_scores
+    )
 
     base_reward = (eff // increment) * brpi
     divisor = active_increments * xp.uint64(weight_denominator)
@@ -375,14 +384,12 @@ def fused_epoch_kernel(xp, balances, eff, prev_part, slashed, active_prev,
         # get_total_balance floors at one increment
         unslashed_increments = xp.maximum(increment, flag_sum) // increment
         w = xp.uint64(weight)
-        if leaking:
-            rewards = xp.zeros_like(base_reward)
-        else:
-            rewards = xp.where(
-                eligible & unslashed,
-                base_reward * w * unslashed_increments // divisor,
-                zero,
-            )
+        rewarded_increments = xp.where(finalizing, unslashed_increments, zero)
+        rewards = xp.where(
+            eligible & unslashed,
+            base_reward * w * rewarded_increments // divisor,
+            zero,
+        )
         if flag_index == head_flag_index:
             penalties = xp.zeros_like(base_reward)
         else:
@@ -1217,6 +1224,8 @@ def _inactivity_and_rewards(ec) -> None:
         get_finality_delay(ec.state, ec.context)
         > ec.context.MIN_EPOCHS_TO_INACTIVITY_PENALTY
     )
+    if leaking:
+        metrics.counter("epoch_vector.leak.epochs").inc()
     if _fused_route(ec, leaking):
         return
     with trace.span("epoch_vector.inactivity"):
@@ -1560,43 +1569,63 @@ def _commit(ec) -> None:
     inactivity scores) with exact changed indices, per-hit instrumented
     writes for the handful of changed validator epoch fields and
     credential switches. After this the SSZ state and the (now clean,
-    owned) column caches agree by construction."""
+    owned) column caches agree by construction.
+
+    With finality every score is 0 and stays 0, so the scores' commit
+    finds nothing and the boundary pays one registry-sized store; in a
+    leak the scores move on every boundary, interleaved, and it pays two,
+    plus the effective balances the hysteresis stepped down. The cell
+    ``deneb-1m.epoch-leak`` runs that commit; the three child spans and
+    the two counters below are how it is read."""
     np = ec.np
     state = ec.state
     with trace.span("epoch_vector.commit", validators=ec.n):
-        if ec.balances is not ec.b_balances:
-            ops_vector.adopt_list_column(
-                state.balances,
-                ec.balances,
-                np.nonzero(ec.balances != ec.b_balances)[0],
-                _U64_MAX,
-            )
-        if ec.inact is not None and ec.inact is not ec.b_inact:
-            ops_vector.adopt_list_column(
-                state.inactivity_scores,
-                ec.inact,
-                np.nonzero(ec.inact != ec.b_inact)[0],
-                _U64_MAX,
-            )
-        validators = state.validators
-        writes = 0
-        for field, work_name, base_name in _VAL_FIELD_COLS:
-            work = getattr(ec, work_name)
-            base = getattr(ec, base_name)
-            if work is base:
-                continue
-            for i in np.nonzero(work != base)[0].tolist():
-                setattr(validators[i], field, int(work[i]))
+        with trace.span("epoch_vector.commit.balances"):
+            if ec.balances is not ec.b_balances:
+                ops_vector.adopt_list_column(
+                    state.balances,
+                    ec.balances,
+                    np.nonzero(ec.balances != ec.b_balances)[0],
+                    _U64_MAX,
+                )
+        scores_changed = 0
+        with trace.span("epoch_vector.commit.scores"):
+            if ec.inact is not None and ec.inact is not ec.b_inact:
+                changed = np.nonzero(ec.inact != ec.b_inact)[0]
+                scores_changed = int(changed.size)
+                ops_vector.adopt_list_column(
+                    state.inactivity_scores, ec.inact, changed, _U64_MAX
+                )
+        writes = eff_changed = 0
+        with trace.span("epoch_vector.commit.validators"):
+            validators = state.validators
+            for field, work_name, base_name in _VAL_FIELD_COLS:
+                work = getattr(ec, work_name)
+                base = getattr(ec, base_name)
+                if work is base:
+                    continue
+                hits = np.nonzero(work != base)[0].tolist()
+                for i in hits:
+                    setattr(validators[i], field, int(work[i]))
+                writes += len(hits)
+                if field == "effective_balance":
+                    eff_changed = len(hits)
+            for i in ec.credential_switches:
+                v = validators[i]
+                v.withdrawal_credentials = (
+                    b"\x02" + bytes(v.withdrawal_credentials)[1:]
+                )
                 writes += 1
-        for i in ec.credential_switches:
-            v = validators[i]
-            v.withdrawal_credentials = (
-                b"\x02" + bytes(v.withdrawal_credentials)[1:]
-            )
-            writes += 1
         if writes:
             metrics.counter("epoch_vector.validator_writes").inc(writes)
-        trace.note(writes=writes)
+        if scores_changed:
+            metrics.counter("epoch_vector.scores.changed").inc(scores_changed)
+        if eff_changed:
+            metrics.counter("epoch_vector.eff.changed").inc(eff_changed)
+        trace.note(
+            writes=writes, scores_changed=scores_changed,
+            eff_changed=eff_changed,
+        )
 
 
 def _count_pass(ec) -> None:

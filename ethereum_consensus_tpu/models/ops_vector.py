@@ -479,7 +479,12 @@ def adopt_list_column(lst, arr, changed_indices, vmax) -> None:
     mutate it afterwards (the epoch engine drops its working references
     at commit). ``changed_indices`` must name every position whose value
     differs from the list's current content (the ``bulk_store``
-    certification contract). A no-change commit is free."""
+    certification contract). A no-change commit is free: with finality
+    that is the inactivity scores' commit at every boundary (all 0, and
+    they stay 0), so a boundary pays one registry-sized ``bulk_store``,
+    the balances'. Without finality the scores move too, and the
+    boundary pays two: the cell ``deneb-1m.epoch-leak`` is the one that
+    runs the two-store commit."""
     np = _np()
     n = len(lst)
     if np is None or arr.shape[0] != n:

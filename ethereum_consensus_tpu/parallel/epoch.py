@@ -91,7 +91,6 @@ def _fused_sharded(
     recovery_rate: int,
     weights: tuple,
     weight_denominator: int,
-    leaking: bool,
     head_flag_index: int,
     target_flag_index: int,
 ):
@@ -100,11 +99,14 @@ def _fused_sharded(
     its scalar reductions wrapped in ``psum`` — inactivity update, flag
     deltas, inactivity penalties, and in-order application in ONE
     dispatch, so the packed columns ship to the devices once and stay
-    there across every stage."""
+    there across every stage. ``leaking`` is a replicated scalar beside
+    the four u64 ones, as in the jit route: one program in and out of a
+    leak."""
     from ..models.epoch_vector import fused_epoch_kernel
 
     def body(balances, eff, prev_part, slashed, active_prev, eligible,
-             scores, increment, brpi, active_increments, denominator):
+             scores, increment, brpi, active_increments, denominator,
+             leaking):
         return fused_epoch_kernel(
             jnp, balances, eff, prev_part, slashed, active_prev, eligible,
             scores, increment, brpi, active_increments, denominator,
@@ -119,7 +121,7 @@ def _fused_sharded(
             jax.shard_map(
                 body,
                 mesh=mesh,
-                in_specs=(spec,) * 7 + (P(),) * 4,
+                in_specs=(spec,) * 7 + (P(),) * 5,
                 out_specs=(spec, spec, P()),
                 check_vma=False,
             )
@@ -311,7 +313,6 @@ class MeshEpochSweeps:
             int(recovery_rate),
             tuple(int(w) for w in weights),
             int(weight_denominator),
-            bool(leaking),
             int(head_flag_index),
             int(target_flag_index),
         )
@@ -330,6 +331,7 @@ class MeshEpochSweeps:
             jnp.uint64(brpi),
             jnp.uint64(active_increments),
             jnp.uint64(denominator),
+            jnp.bool_(leaking),
         )
         new_scores, new_balances, wrapped = kernel(*sharded, *scalars)
         if int(wrapped):

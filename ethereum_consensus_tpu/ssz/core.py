@@ -434,7 +434,7 @@ class CachedRootList(list):
     __slots__ = ("_root_cache", "_pack_memo", "_uniform_kind",
                  "_elems_fresh", "_parents_registered", "_self_ref",
                  "_container_parents", "_mut_gen", "_pack_gen",
-                 "_dirty_groups", "_tree_memo", "_pack_tree",
+                 "_dirty_groups", "_dirty_elems", "_tree_memo", "_pack_tree",
                  "_memos_owned", "_col_dirty", "_col_cache", "_col_owned",
                  "__weakref__")
 
@@ -456,6 +456,16 @@ class CachedRootList(list):
         # elements, by Container.__setattr__ through the weak-parent chain
         # using the element's stamped index.
         self._dirty_groups: "set | None" = None
+        # The same marks at element precision, for lists of scalar-leaf
+        # containers: the indices Container.__setattr__ reported since the
+        # last serviced walk. A set only while EVERY mark since then came
+        # through that edge; a mutation through the list itself is known
+        # by group alone and sets it None until the next walk re-arms it.
+        # With it the splice re-hashes the written elements of a dirty
+        # group instead of visiting all 4096 (an inactivity leak steps
+        # some 700 scattered validators down a boundary: every group of
+        # the registry is dirty, and a handful of its rows).
+        self._dirty_elems: "set | None" = None
         # (key, chunks bytearray, IncrementalPaddedTree, root) for lists of
         # scalar-leaf containers: chunks = the joined element roots, tree =
         # the 4096-chunk group mids. Survives mutation (dirty groups name
@@ -644,6 +654,7 @@ def _instrument(name):
                 self._dirty_groups = None
             else:
                 dg.update(marks)
+            self._dirty_elems = None  # known by group from here on
         cd = self._col_dirty
         if cd is not None:
             elems = _mutation_elems(name, args, pre_len, len(self))
@@ -1210,32 +1221,53 @@ def _tree_splice(elem, values, tkey) -> "bytes | None":
         del chunks[32 * n :]
     htr = elem.hash_tree_root
     sticky = set()
+    # element precision, where every mark came through an element: the
+    # other elements' roots are the ones the chunks already hold
+    written = {}
+    if values._dirty_elems is not None and len(chunks) == 32 * n:
+        for i in values._dirty_elems:
+            written.setdefault(i >> gs, []).append(i)
     for g in sorted(dg):
         start = g << gs
         if start >= n:
             continue
         stop = min(n, start + gsize)
-        parts = []
         clean = True
-        for v in list.__getitem__(values, slice(start, stop)):
-            r = v.__dict__.get("_htr_cache")
-            if r is None:
-                r = htr(v)
-                if "_htr_cache" not in v.__dict__:
-                    # element refused caching (a mutable field value can
-                    # change without notifying): its group must recompute
-                    # on every walk until the value is replaced
-                    clean = False
-            parts.append(r)
+        rows = written.get(g)
+        if rows:
+            for i in rows:
+                v = list.__getitem__(values, i)
+                r = v.__dict__.get("_htr_cache")
+                if r is None:
+                    r = htr(v)
+                    if "_htr_cache" not in v.__dict__:
+                        clean = False  # as below
+                chunks[32 * i : 32 * i + 32] = r
+            seg = bytes(chunks[32 * start : 32 * stop])
+        else:
+            parts = []
+            for v in list.__getitem__(values, slice(start, stop)):
+                r = v.__dict__.get("_htr_cache")
+                if r is None:
+                    r = htr(v)
+                    if "_htr_cache" not in v.__dict__:
+                        # element refused caching (a mutable field value
+                        # can change without notifying): its group must
+                        # recompute on every walk until the value is
+                        # replaced
+                        clean = False
+                parts.append(r)
+            seg = b"".join(parts)
+            chunks[32 * start : 32 * stop] = seg
         if not clean:
             sticky.add(g)
-        seg = b"".join(parts)
-        chunks[32 * start : 32 * stop] = seg
         tree.set_node(g, merkleize_chunks(seg, limit=gsize))
     tree.truncate((n + gsize - 1) >> gs)
     root = tree.root()
     tm[3] = root
     values._dirty_groups = sticky
+    # a sticky group is re-walked whole every time: group precision only
+    values._dirty_elems = None if sticky else set()
     values._elems_fresh = not sticky
     return root
 
@@ -1330,7 +1362,7 @@ def _register_and_activate(elem, values, tkey) -> None:
     values._elems_fresh = all_cached
     tm = values._tree_memo
     if not (all_cached and tm is not None and tm[0] == tkey and tm[2] is not None):
-        values._dirty_groups = None
+        values._dirty_groups = values._dirty_elems = None
         return
     if values._dirty_groups is None and stamped is None:
         # reactivation after an untracked mutation: stamps may be stale —
@@ -1350,6 +1382,7 @@ def _register_and_activate(elem, values, tkey) -> None:
                 break
             d["_ssz_idx"] = i
     values._dirty_groups = set() if stamped in (None, True) else None
+    values._dirty_elems = set() if stamped in (None, True) else None
 
 
 def bulk_store(values, new_values, changed_indices=None) -> None:
@@ -1430,6 +1463,7 @@ def _bulk_store_impl(values, new_values, changed_indices=None) -> None:
     if dg is None and cd is None:
         return
     gs = _DIRTY_GROUP_SHIFT
+    values._dirty_elems = None  # a bulk store is known by group alone
     if changed_indices is None:
         # uncertified: every element may differ — columnar consumers
         # rebuild rather than refresh
@@ -2199,6 +2233,9 @@ class Container(metaclass=_ContainerMeta):
                         if dg is not None:
                             if stamped:
                                 dg.add(idx >> _DIRTY_GROUP_SHIFT)
+                                de = p._dirty_elems
+                                if de is not None:
+                                    de.add(idx)
                             else:
                                 p._dirty_groups = None
                         if cd is not None:
@@ -2242,6 +2279,9 @@ class Container(metaclass=_ContainerMeta):
                             and list.__getitem__(p, idx) is self
                         ):
                             dg.add(idx >> _DIRTY_GROUP_SHIFT)
+                            de = p._dirty_elems
+                            if de is not None:
+                                de.add(idx)
                         else:
                             p._dirty_groups = None
                 else:
@@ -2527,6 +2567,8 @@ def _copy_value(typ: SSZType, value: Any):
                     shared_memos = True
                 dg = value._dirty_groups
                 copied._dirty_groups = set(dg) if dg is not None else None
+                de = value._dirty_elems
+                copied._dirty_elems = set(de) if de is not None else None
             elif value and isinstance(value[0], Container):
                 copied = CachedRootList(v.copy() for v in value)
             elif value and value[0].__class__ is bytes:

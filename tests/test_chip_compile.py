@@ -26,9 +26,10 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 N_VALIDATORS = 1 << 20
-# the altair-family chain constants the fused epoch kernel is jitted with
-# (static arguments): inactivity bias / recovery rate, flag weights,
-# weight denominator, leaking, head and target flag indices
+# what follows the four u64 scalars in the fused epoch kernel's arguments:
+# the altair-family chain constants it is jitted with (static: inactivity
+# bias / recovery rate, flag weights, weight denominator, head and target
+# flag indices) and, fifth of them, ``leaking``, which is traced
 FUSED_STATICS = (4, 16, (14, 26, 14), 64, False, 2, 1)
 
 
@@ -212,11 +213,13 @@ def _fused_columns(sharding):
 def _fused_sharded_compile(mesh):
     from ethereum_consensus_tpu.parallel import epoch
 
-    scalar = _shape((), jnp.uint64, NamedSharding(mesh, P()))
-    kernel = epoch._fused_sharded(mesh, *FUSED_STATICS)
+    replicated = NamedSharding(mesh, P())
+    scalar = _shape((), jnp.uint64, replicated)
+    kernel = epoch._fused_sharded(mesh, *FUSED_STATICS[:4], *FUSED_STATICS[5:])
     return kernel.__wrapped__.lower(
         *_fused_columns(NamedSharding(mesh, P(epoch.SHARD_AXIS))),
         *(scalar,) * 4,
+        _shape((), jnp.bool_, replicated),  # leaking
     ).compile()
 
 

@@ -361,7 +361,10 @@ def test_pass_counters_and_span_fields_read_what_the_pass_did(forced_engine):
         "registry.activated": 4, "validator_writes": 6,
     }
     assert fields["epoch_vector.registry"] == {"queued": 2, "activated": 4}
-    assert fields["epoch_vector.commit"] == {"validators": 1 << 13, "writes": 6}
+    assert fields["epoch_vector.commit"] == {
+        "validators": 1 << 13, "writes": 6, "scores_changed": 0,
+        "eff_changed": 0,
+    }
     # the second: nobody new, the three rows left that were eligible at epoch 0
     state.current_epoch_participation = world.refills[0].tolist()
     moved, fields = cross(world.target_slot + 32)

@@ -141,7 +141,9 @@ def test_registry_and_commit_events_say_what_the_pass_did(session):
     commit = _only(line, "ect:epoch_vector.commit")[3]
     # minimal preset: the churn limit is min(4, max(2, 88 // 32)) = 2
     assert registry == {"queued": 3, "activated": 2}
-    assert commit == {"validators": 96, "writes": 5}
+    assert commit == {
+        "validators": 96, "writes": 5, "scores_changed": 0, "eff_changed": 0,
+    }
 
 
 def test_a_note_outside_any_span_is_a_no_op():

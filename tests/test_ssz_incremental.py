@@ -149,6 +149,85 @@ def test_digest_count_bulk_store_few_groups():
     assert root == LT.hash_tree_root(CachedRootList(new))
 
 
+def _armed_registry(count: int):
+    LT = List[Val, 1 << 40]
+    values = CachedRootList(
+        Val(a=i, b=i.to_bytes(4, "little") * 8) for i in range(count)
+    )
+    LT.hash_tree_root(values)
+    assert values._dirty_groups == set() and values._dirty_elems == set()
+    return LT, values
+
+
+def _cold_root(LT, values) -> bytes:
+    return LT.hash_tree_root(CachedRootList(Val(a=v.a, b=v.b) for v in values))
+
+
+def test_splice_rehashes_the_written_rows_not_their_groups():
+    """A few scattered field writes dirty every group they fall in; the
+    splice re-hashes those rows and takes every other element's root from
+    the chunks it holds. Shown by an element it must not look at: a root
+    cache planted wrong, without notice, on an unwritten row of a dirty
+    group would come out in a whole-group walk."""
+    LT, values = _armed_registry(3 * 4096 + 100)
+    written = [7, 4096 + 9, 2 * 4096 + 11, 3 * 4096 + 50]
+    for i in written:
+        values[i].a = 10**15 + i
+    assert values._dirty_groups == {0, 1, 2, 3}
+    assert values._dirty_elems == set(written)
+    true_root = values[8].__dict__["_htr_cache"]
+    values[8].__dict__["_htr_cache"] = b"\x11" * 32  # never read
+    before = ssz_hash.digest_count()
+    root = LT.hash_tree_root(values)
+    assert ssz_hash.digest_count() - before <= 4 * 4096 + 64
+    values[8].__dict__["_htr_cache"] = true_root
+    assert root == _cold_root(LT, values)
+    assert values._dirty_groups == set() and values._dirty_elems == set()
+
+
+@pytest.mark.parametrize("through_the_list", ["setitem", "append", "bulk_store"])
+def test_a_mutation_through_the_list_falls_back_to_group_precision(through_the_list):
+    """An element stored, appended or bulk-stored is known by group alone:
+    element precision is off until the walk has serviced it, the root is
+    right, and the next walk is armed again."""
+    LT, values = _armed_registry(2 * 4096 + 5)
+    values[10].a = 123  # an element write first: precision still on
+    assert values._dirty_elems == {10}
+    fresh = Val(a=77, b=b"\x07" * 32)
+    if through_the_list == "setitem":
+        values[4096 + 1] = fresh
+    elif through_the_list == "append":
+        values.append(fresh)
+    else:
+        new = list(values)
+        new[4096 + 1] = fresh
+        bulk_store(values, new, [4096 + 1])
+    assert values._dirty_elems is None and values._dirty_groups
+    values[20].a = 456  # and one after: marked by group
+    assert LT.hash_tree_root(values) == _cold_root(LT, values)
+    assert values._dirty_elems == set()
+    values[4096 + 1].a = 78  # the stored element is wired like the others
+    assert values._dirty_elems == {4096 + 1}
+    assert LT.hash_tree_root(values) == _cold_root(LT, values)
+
+
+def test_copies_carry_their_own_written_rows():
+    class Reg(Container):
+        vals: List[Val, 1 << 40]
+
+    reg = Reg(vals=[Val(a=i, b=i.to_bytes(4, "little") * 8) for i in range(8200)])
+    Reg.hash_tree_root(reg)
+    reg.vals[5].a = 1
+    twin = reg.copy()
+    assert twin.vals._dirty_elems == {5} and twin.vals._dirty_elems is not reg.vals._dirty_elems
+    twin.vals[5000].a = 2
+    reg.vals[8100].a = 3
+    assert reg.vals._dirty_elems == {5, 8100} and twin.vals._dirty_elems == {5, 5000}
+    for side in (reg, twin):
+        cold = Reg(vals=[Val(a=v.a, b=v.b) for v in side.vals])
+        assert Reg.hash_tree_root(side) == Reg.hash_tree_root(cold)
+
+
 # ---------------------------------------------------------------------------
 # bit-identity property (shrunk geometry, independent naive reference)
 # ---------------------------------------------------------------------------
