@@ -4,9 +4,10 @@ The ownership inversion this module implements: for the epoch hot path
 the ``RegistryColumns`` arrays are the AUTHORITATIVE store of validator
 epoch fields, balances, participation, inactivity and slashed /
 credential-prefix data, and the SSZ list elements are a materialization
-— produced once per epoch, at commit, through ``bulk_store``'s
-changed-indices contract (``ops_vector.adopt_list_column`` — the
-``_col_dirty`` machinery driven in the write direction). Everything the
+— produced once per epoch, at commit: the validators' changed fields
+by per-hit writes, the scalar lists by handing the finished column to
+``ops_vector.adopt_list_column``, which makes it the list's content
+without boxing a row (``ssz/column_list.py``). Everything the
 epoch transition computes between sync and commit reads and writes the
 arrays; no stage walks ``state.validators`` elements, so the pass costs
 vector passes + a handful of per-hit writes instead of ~10 Python
@@ -1565,11 +1566,14 @@ _VAL_FIELD_COLS = (
 
 
 def _commit(ec) -> None:
-    """Materialize: ONE adopted bulk_store per scalar list (balances,
-    inactivity scores) with exact changed indices, per-hit instrumented
-    writes for the handful of changed validator epoch fields and
-    credential switches. After this the SSZ state and the (now clean,
-    owned) column caches agree by construction.
+    """Materialize: ONE adopted column per scalar list (balances,
+    inactivity scores), handed to ``ops_vector.adopt_list_column`` with
+    the comparison that says which rows moved: the list turns
+    column-primary (``ssz/column_list.py``), its dirty groups are marked
+    from the mask and no row is boxed. Per-hit instrumented writes for
+    the handful of changed validator epoch fields and credential
+    switches. After this the SSZ state and the columns agree by
+    construction: for the two lists the column is the content.
 
     With finality every score is 0 and stays 0, so the scores' commit
     finds nothing and the boundary pays one registry-sized store; in a
@@ -1585,16 +1589,17 @@ def _commit(ec) -> None:
                 ops_vector.adopt_list_column(
                     state.balances,
                     ec.balances,
-                    np.nonzero(ec.balances != ec.b_balances)[0],
+                    ec.balances != ec.b_balances,
                     _U64_MAX,
                 )
         scores_changed = 0
         with trace.span("epoch_vector.commit.scores"):
             if ec.inact is not None and ec.inact is not ec.b_inact:
-                changed = np.nonzero(ec.inact != ec.b_inact)[0]
-                scores_changed = int(changed.size)
-                ops_vector.adopt_list_column(
-                    state.inactivity_scores, ec.inact, changed, _U64_MAX
+                scores_changed = ops_vector.adopt_list_column(
+                    state.inactivity_scores,
+                    ec.inact,
+                    ec.inact != ec.b_inact,
+                    _U64_MAX,
                 )
         writes = eff_changed = 0
         with trace.span("epoch_vector.commit.validators"):

@@ -54,6 +54,7 @@ from __future__ import annotations
 import threading
 
 from .. import _env
+from ..ssz import column_list as _column_list
 from ..ssz.core import CachedRootList, _clean_pack_bytes, bulk_store
 from ..telemetry import device as _device_obs
 from ..telemetry import memory as _memory
@@ -151,7 +152,13 @@ def _read_validator_row(v):
 # _col_cache records, stored ON the CachedRootList itself so they travel
 # across state copies (ssz/core.py _share_col_cache — structural share,
 # copy-on-write via _col_owned): ("validators", arrays_dict) for the
-# registry, ("list", arr, vmax) for scalar lists.
+# registry, ("list", arr, vmax) for scalar lists (a fourth entry, where
+# present, is column-primary storage's growth buffer and nobody else's).
+
+# the exact classes whose scalar-list column this module serves: the
+# plain list (a cache beside the boxed content) and the column-primary
+# one (ssz/column_list.py: the column IS the content)
+_COLUMN_BEARING = (CachedRootList, _column_list.ColumnList)
 
 
 def _build_validator_cols(vals) -> "dict | None":
@@ -228,6 +235,11 @@ def _sync_validator_cols(vals) -> "dict | None":
 
 def _build_list_col(src, dtype, vmax):
     np = _np()
+    # a column-primary list holds the column asked for by construction
+    # (_sync_list_col found it): one that reaches here is asked for
+    # another dtype or cap, or lost an invariant, and is rebuilt as the
+    # plain list it becomes (``ssz.column_list.left`` counts it)
+    _column_list.leave(src)
     if np is None or src.__class__ is not CachedRootList:
         return None
     esize = np.dtype(dtype).itemsize
@@ -278,6 +290,8 @@ def _sync_list_col(src, dtype, vmax):
             src._col_cache = ("list", arr, vmax)
             src._col_owned = True
         for i in cd:
+            # (a column-primary list keeps cd empty; were it not, its slot
+            # holds the sentinel, no int: it leaves the mode just below)
             v = list.__getitem__(src, i)
             if type(v) is not int or v < 0 or v > vmax:
                 src._col_dirty = None
@@ -334,7 +348,7 @@ class RegistryColumns:
         vmax = self.LIST_FIELDS[field]
         dtype = np.dtype(np.uint8) if vmax == 0xFF else np.dtype(np.uint64)
         src = getattr(state, field, None)
-        if src is None or src.__class__ is not CachedRootList:
+        if src is None or src.__class__ not in _COLUMN_BEARING:
             return None
         arr = _sync_list_col(src, dtype, vmax)
         if arr is None:
@@ -464,48 +478,58 @@ def _pack_from_columns(cols, state, previous_epoch,
 # ---------------------------------------------------------------------------
 
 
-def adopt_list_column(lst, arr, changed_indices, vmax) -> None:
+def adopt_list_column(lst, arr, changed, vmax) -> int:
     """Columnar-primary commit of a scalar-list column: ``arr`` is the
     AUTHORITATIVE new content (the epoch engine computed the whole epoch
-    on it), the SSZ list is the materialization. One ``bulk_store`` with
-    the exact changed indices splices the values in (so incremental HTR
-    re-merkleizes only the touched 4096-element groups), and ``arr``
-    itself becomes the list's column cache — owned, with a CLEAN dirty
-    set — instead of paying a read-direction refresh of rows we just
-    wrote. This is the ``_col_dirty`` machinery driven in the write
-    direction (docs/OPS_VECTOR.md).
+    on it) and becomes the list's content as it is. A plain
+    ``CachedRootList`` given a ``uint64`` column of its length turns
+    column-primary (``ssz/column_list.py adopt``): the dirty 4096-element
+    groups are marked from ``changed`` (so incremental HTR re-merkleizes
+    only what moved), the array is installed as the owned clean column,
+    and NO row is boxed: reads, single writes and ``append`` are served
+    by the array until a structural mutation makes the list box itself
+    again (docs/OPS_VECTOR.md, "The storage contract"). Anything else (a
+    list of another class, a narrower column) takes the ``bulk_store``
+    it always took: one ``tolist``, one slice store, the array as the
+    clean cache beside the boxed content.
+
+    ``changed`` names every position whose value differs from the list's
+    current content: a boolean mask over the rows (the comparison
+    itself; the store takes its groups from it without an index array)
+    or the indices. Returns how many rows that is.
 
     Ownership contract: the caller HANDS OVER ``arr`` — it must never
     mutate it afterwards (the epoch engine drops its working references
-    at commit). ``changed_indices`` must name every position whose value
-    differs from the list's current content (the ``bulk_store``
-    certification contract). A no-change commit is free: with finality
-    that is the inactivity scores' commit at every boundary (all 0, and
-    they stay 0), so a boundary pays one registry-sized ``bulk_store``,
-    the balances'. Without finality the scores move too, and the
-    boundary pays two: the cell ``deneb-1m.epoch-leak`` is the one that
-    runs the two-store commit."""
+    at commit). A no-change commit stores nothing: with finality that is
+    the inactivity scores' commit at every boundary (all 0, and they
+    stay 0), so a boundary pays one registry-sized store, the balances'.
+    Without finality the scores move too, and the boundary pays two: the
+    cell ``deneb-1m.epoch-leak`` is the one that runs the two-store
+    commit."""
     np = _np()
     n = len(lst)
     if np is None or arr.shape[0] != n:
         fallback("adopt_shape")
-        bulk_store(lst, [int(x) for x in arr], changed_indices)
-        return
-    changed = np.asarray(changed_indices, dtype=np.int64)
-    if changed.size:
-        # hand bulk_store the wire-width column itself: ONE tolist boxing
-        # inside it, uniformity certified from the dtype — the old
-        # tolist-here-then-type-scan-there double materialization is gone
-        bulk_store(lst, arr, changed)
+        changed = [int(i) for i in changed]
+        bulk_store(lst, [int(x) for x in arr], changed)
+        return len(changed)
+    changed = np.asarray(changed)
+    is_mask = changed.dtype.kind == "b"
+    n_changed = int(np.count_nonzero(changed)) if is_mask else int(changed.size)
+    if n_changed and _column_list.adopt(lst, arr, changed, vmax):
+        metrics.counter("ops_vector.columns.adopted").inc()
+        return n_changed
+    if n_changed:
+        bulk_store(lst, arr, np.flatnonzero(changed) if is_mask else changed)
         metrics.counter("ops_vector.bulk_store.calls").inc()
-        metrics.counter("ops_vector.bulk_store.elements").inc(
-            int(changed.size)
-        )
-    if lst.__class__ is CachedRootList:
+        metrics.counter("ops_vector.bulk_store.elements").inc(n_changed)
+    if lst.__class__ in _COLUMN_BEARING:
+        # equal content, a fresh array: ours alone again after a copy
         lst._col_cache = ("list", arr, vmax)
         lst._col_owned = True
         lst._col_dirty = set()
         metrics.counter("ops_vector.columns.adopted").inc()
+    return n_changed
 
 
 def install_zero_column(lst, n: int, vmax: int = 0xFF) -> None:

@@ -103,7 +103,10 @@ def assert_column_consistency(state, where: str = "") -> None:
     """Every list-resident column cache on ``state`` must agree
     element-for-element with the literal SSZ values, and syncing must
     drain its ``_col_dirty`` channel. Lists without a cache are vacuously
-    consistent (nothing resident to go stale)."""
+    consistent (nothing resident to go stale). A column-primary list
+    (ssz/column_list.py) is checked like any other: its values are read
+    through the list, its column through ``list_column``, and the two
+    must be one array's worth."""
     state = _unwrap(state)
     cols = ops_vector.columns_for(state)
     if cols is None:  # no numpy / engine disabled: nothing cached anywhere
@@ -132,7 +135,7 @@ def assert_column_consistency(state, where: str = "") -> None:
         )
     for field in ops_vector.RegistryColumns.LIST_FIELDS:
         src = getattr(state, field, None)
-        if src is None or src.__class__ is not CachedRootList:
+        if src is None or src.__class__ not in ops_vector._COLUMN_BEARING:
             continue
         if src._col_cache is None:
             continue

@@ -612,6 +612,23 @@ def _mutation_elems(name, args, pre_len, post_len):
     return None  # sort/reverse permute in place: index map gone
 
 
+def _mark_mutated(values) -> None:
+    """What every mutation channel owes the caches before it is done:
+    the per-descriptor roots go, the generation moves, and the
+    containers whose instance root covers this list hear of it. (The
+    instrumented wrappers below do the same inline: they run per element
+    write.)"""
+    values._root_cache.clear()
+    values._elems_fresh = False
+    values._mut_gen += 1
+    cps = values._container_parents
+    if cps is not None:
+        for ref in cps:
+            p = ref()
+            if p is not None:
+                p._ssz_root_dirty()
+
+
 def _instrument(name):
     base = getattr(list, name)
     # single-element writers can keep the uniform-bytes verdict alive
@@ -746,6 +763,18 @@ for _name in INSTRUMENTED_LIST_MUTATORS:
     setattr(CachedRootList, _name, _instrument(_name))
 del _name
 
+# The one module besides this one that may call the BASE list mutators,
+# and the only two it may call: column-primary storage swaps a list's
+# slots for its sentinel on entry and boxes them back on leaving (one
+# slice ``__setitem__`` each), and appends one sentinel a row ``append``
+# grew the column by. tools/speclint's mutation analyzer reads both
+# names from here and flags any other raw call in that module.
+COLUMN_LIST_MODULE = "ssz/column_list.py"
+COLUMN_LIST_RAW_CALLS = (
+    "__setitem__",
+    "append",
+)
+
 
 def instrumented_surface() -> dict:
     """Machine-readable manifest of the instrumented mutation surface.
@@ -775,6 +804,13 @@ def instrumented_surface() -> dict:
       columnar view stays delta-refreshable without any consumer-side
       hooks. Single consumer per list; drained under the same
       single-writer discipline as ``_dirty_groups``.
+    * ``column_list`` — column-primary storage (``ssz/column_list.py``):
+      a list whose whole content ``adopt`` took from a ``uint64`` column
+      is a ``ColumnList`` until a mutator outside ``stays`` (or a value
+      the column cannot hold, or a ``bulk_store``) makes it ``leave``.
+      ``raw_list_calls`` are the base-class calls that module is
+      sanctioned to make; a column-primary list keeps ``_col_dirty``
+      empty and marks ``_dirty_groups`` itself.
     """
     return {
         "list_type": "CachedRootList",
@@ -792,6 +828,14 @@ def instrumented_surface() -> dict:
                 "Container.__setattr__",
                 "bulk_store",
             ),
+        },
+        "column_list": {
+            "module": COLUMN_LIST_MODULE,
+            "list_type": "ColumnList",
+            "entry": "adopt",
+            "exit": "leave",
+            "stays": ("__setitem__", "append"),
+            "raw_list_calls": COLUMN_LIST_RAW_CALLS,
         },
     }
 
@@ -949,7 +993,7 @@ def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
                 ).tobytes()
                 segs.append((start, stop, seg))
                 continue
-            seg_vals = list.__getitem__(values, slice(start, stop))
+            seg_vals = values[start:stop]
             if esize == BYTES_PER_CHUNK:
                 seg = b"".join(seg_vals)
                 if len(seg) != BYTES_PER_CHUNK * (stop - start):
@@ -1413,11 +1457,19 @@ def _bulk_store_impl(values, new_values, changed_indices=None) -> None:
     changed a few thousand entries re-merkleizes a few groups, not the
     whole collection (docs/INCREMENTAL_HTR.md).
 
-    ``new_values`` may be a 1-D unsigned numpy array (the columnar epoch
-    commit's wire-width buffer): the content splices in via ONE
+    ``new_values`` may be a 1-D unsigned numpy array (phase0's inline
+    numpy rewards branch; an epoch commit's column that the
+    column-primary store does not take): the content splices in via ONE
     ``tolist`` boxing and the uniformity verdict is certified from the
-    dtype — no second per-element materialization, no type scan."""
+    dtype — no second per-element materialization, no type scan. The
+    columnar epoch commit's ``uint64`` lists do not come here any more:
+    they turn column-primary and box nothing (ssz/column_list.py). A
+    column-primary list aimed at boxes its column first (``leave``) and
+    is stored into as the plain list it then is."""
     n = len(values)
+    # a column-primary list takes a bulk store as a plain list: it boxes
+    # its column first (a no-op on any other list)
+    _column_list.leave(values)
     uint_column = (
         getattr(getattr(new_values, "dtype", None), "kind", "") == "u"
         and getattr(new_values, "ndim", 0) == 1
@@ -1432,9 +1484,7 @@ def _bulk_store_impl(values, new_values, changed_indices=None) -> None:
         values[:] = new_values
         return
     list.__setitem__(values, slice(0, n), new_values)
-    values._root_cache.clear()
-    values._elems_fresh = False
-    values._mut_gen += 1
+    _mark_mutated(values)
     # re-certify uniformity NOW (one C-speed pass — or for free from an
     # adopted column's dtype): the dirty-group splice only engages on a
     # certified collection, and deferring the scan to the next walk
@@ -1452,12 +1502,6 @@ def _bulk_store_impl(values, new_values, changed_indices=None) -> None:
             values._uniform_kind = ("bytes", len(new_values[0]))
         else:
             values._uniform_kind = None
-    cps = values._container_parents
-    if cps is not None:
-        for ref in cps:
-            p = ref()
-            if p is not None:
-                p._ssz_root_dirty()
     dg = values._dirty_groups
     cd = values._col_dirty
     if dg is None and cd is None:
@@ -1749,7 +1793,7 @@ def _merkleize_homogeneous(elem: SSZType, values: list, limit_elems: int) -> byt
         else:
             root = merkleize_chunks(chunks, limit=limit_elems)
             values._root_cache[("tree", elem, limit_elems)] = (chunks, root)
-        if values and isinstance(list.__getitem__(values, 0), Container):
+        if values and isinstance(values[0], Container):
             _register_nested_freshness(values)
         return root
     return merkleize_chunks(chunks, limit=limit_elems)
@@ -2131,7 +2175,7 @@ def _try_cache_nested_root(cls, value, root: bytes) -> None:
             if "_htr_cache" not in v.__dict__:
                 return  # child uncovered: its mutations couldn't notify
             containers.append(v)
-        elif t is CachedRootList:
+        elif t is CachedRootList or t is _column_list.ColumnList:
             kind = v._uniform_kind
             if kind is None and not all(
                 x.__class__ is int or x.__class__ is bool or x.__class__ is bytes
@@ -2341,7 +2385,11 @@ class Container(metaclass=_ContainerMeta):
             tv = v.__class__
             if tv is int or tv is bytes or tv is bool:
                 continue
-            if tv is CachedRootList or tv is list:
+            if (
+                tv is CachedRootList
+                or tv is list
+                or tv is _column_list.ColumnList
+            ):
                 nd[key] = _copy_value(typ, v)
             elif isinstance(v, Container):
                 nd[key] = v.copy()
@@ -2581,7 +2629,12 @@ def _copy_value(typ: SSZType, value: Any):
             else:
                 copied = CachedRootList(_copy_value(elem, v) for v in value)
         else:
-            copied = CachedRootList(value)
+            if value.__class__ is _column_list.ColumnList:
+                # column-primary: the copy is column-primary over the
+                # same array and no row is boxed
+                copied = _column_list.share(value)
+            else:
+                copied = CachedRootList(value)
             if isinstance(value, CachedRootList):
                 if value._pack_tree is not None:
                     copied._pack_tree = value._pack_tree
@@ -2842,3 +2895,9 @@ def prove(typ, value, gindex: int) -> list[bytes]:
         branch.append(compute_subtree_root(typ, value, g ^ 1))
         g >>= 1
     return branch
+
+
+# column-primary storage subclasses CachedRootList, and bulk_store, the
+# copies and the nested-root scan above have to know its class: imported
+# last, when everything it takes from this module is defined
+from . import column_list as _column_list  # noqa: E402

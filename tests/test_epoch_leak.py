@@ -196,6 +196,56 @@ def test_every_boundary_steps_balances_down_and_the_counters_say_so(fused_route)
     assert min(counts["scores_changed"]) > SMALL * 0.35
 
 
+def column_list_counters() -> dict:
+    return {
+        name: metrics.counter(f"ssz.column_list.{name}").value()
+        for name in ("stores", "left", "boxed_rows")
+    }
+
+
+def test_the_two_store_commit_boxes_nothing(fused_route):
+    """A leaking boundary stores two registry-sized lists and, here, steps
+    some hundreds of validators down: both lists turn column-primary
+    (ssz/column_list.py), two stores a boundary, no row boxed at any of
+    them nor by the writes between them, and the roots are the literal
+    spec functions'."""
+    from ethereum_consensus_tpu.ssz.column_list import ColumnList
+
+    world = leak_world(7, chain=4)
+    pre = world.pre.copy()
+    online = [
+        i for i, (score, v) in enumerate(zip(pre.inactivity_scores, pre.validators))
+        if score < 4096 and int(v.effective_balance) == 32 * 10**9
+    ]
+    for i in online[:400]:  # under the downward threshold of 31.75 ETH
+        pre.balances[i] = 31_700_000_000 + i
+    columnar, literal = pre.copy(), pre.copy()
+    for place in range(4):
+        before, moved_before = column_list_counters(), counters()
+        cross(columnar, world, place)
+        after = column_list_counters()
+        assert after == {**before, "stores": before["stores"] + 2}, place
+        assert columnar.balances.__class__ is ColumnList
+        assert columnar.inactivity_scores.__class__ is ColumnList
+        stepped = counters()["eff.changed"] - moved_before["eff.changed"]
+        assert stepped >= (400 if place == 0 else 1)
+        os.environ["ECT_EPOCH_VECTOR"] = "off"
+        try:
+            cross(literal, world, place)
+        finally:
+            os.environ.pop("ECT_EPOCH_VECTOR", None)
+        assert column_list_counters() == after  # the literal side: no store
+        assert_bit_identical(columnar, literal, f"leaking crossing {place}")
+        assert_column_consistency(columnar, f"leaking crossing {place}")
+        # what a block does between two boundaries, on both sides
+        for state in (columnar, literal):
+            state.balances[place] = int(state.balances[place]) + 1
+            state.inactivity_scores[place + 9] = 5
+        assert column_list_counters() == after
+    assert list(columnar.balances) == list(literal.balances)
+    assert list(columnar.inactivity_scores) == list(literal.inactivity_scores)
+
+
 def test_entering_a_leak_compiles_nothing_at_the_boundary(fused_route):
     """A chain that starts four epochs after finality: its first boundary
     still pays flag rewards and recovers scores, its second is a leaking

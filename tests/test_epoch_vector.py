@@ -427,10 +427,12 @@ def test_engine_threshold_without_override():
 
 
 def test_adopt_list_column_contract():
-    """adopt_list_column materializes the authoritative array into the
-    SSZ list via ONE certified bulk_store and installs the array itself
-    as the clean, owned column cache — and the incremental root off the
-    adopted commit matches a cold recompute."""
+    """adopt_list_column makes the authoritative array the SSZ list's
+    content without boxing a row (the list turns column-primary,
+    ssz/column_list.py) and installs it as the clean, owned column —
+    and the incremental root off the adopted commit matches a cold
+    recompute."""
+    from ethereum_consensus_tpu.ssz.column_list import UNBOXED, ColumnList
     from ethereum_consensus_tpu.ssz.core import List, uint64
 
     typ = List[uint64, 1 << 20]
@@ -447,8 +449,9 @@ def test_adopt_list_column_contract():
     work[9_999] = 123
     changed = np.nonzero(work != arr0)[0]
     ops_vector.adopt_list_column(lst, work, changed, (1 << 64) - 1)
-    assert list.__getitem__(lst, 17) == 17 + 5
-    assert list.__getitem__(lst, 9_999) == 123
+    assert lst.__class__ is ColumnList
+    assert lst[17] == 17 + 5 and lst[9_999] == 123 and lst[-1] == 123
+    assert list.__getitem__(lst, 17) is UNBOXED  # no boxed copy is kept
     assert lst._col_cache[1] is work, "authoritative array not adopted"
     assert lst._col_owned and lst._col_dirty == set()
     assert typ.hash_tree_root(lst) == typ.hash_tree_root(
@@ -952,3 +955,118 @@ def test_columnar_primary_engagement_2e18():
     ), {k: v for k, v in d.items() if k.startswith("epoch_vector.fallback.")}
     assert d.get("ops_vector.columns.builds", 0) == 0
     assert warm_s < 1.0, f"2^18 warm epoch took {warm_s:.2f}s"
+
+
+# ---------------------------------------------------------------------------
+# the block path boxes nothing (ssz/column_list.py): what no cell times
+# ---------------------------------------------------------------------------
+
+
+def _column_list_counters() -> dict:
+    return {
+        k: metrics.counter(f"ssz.column_list.{k}").value()
+        for k in ("stores", "left", "boxed_rows")
+    }
+
+
+def test_blocks_between_two_boundaries_box_nothing(monkeypatch):
+    """deneb at 2^12 validators, the pass at its natural threshold: a
+    boundary adopts the balances column, three blocks then read and write
+    it element by element (attestations and their proposer reward, a full
+    sync aggregate's 512 members, over forty withdrawals, a deposit that
+    appends a validator), and the next boundary adopts again. No row is
+    ever boxed, one store a boundary, and every root is the literal
+    oracle's."""
+    from chain_utils import h
+
+    from ethereum_consensus_tpu.models.phase0.containers import DepositData
+    from ethereum_consensus_tpu.ssz.column_list import ColumnList
+    from ethereum_consensus_tpu.ssz.hash import hash_pair
+
+    N = 1 << 12
+    assert epoch_vector.EPOCH_VECTOR_MIN_VALIDATORS == N
+    state, ctx = chain_utils.fast_registry_state(N, "deneb")
+    mod = chain_utils._fork_module("deneb")
+    sp, stm = mod.slot_processing, mod.state_transition
+    spe = int(ctx.SLOTS_PER_EPOCH)
+    rng = random.Random(33)
+    for i in rng.sample(range(N), 43):  # partial withdrawals, three blocks' worth
+        state.validators[i].withdrawal_credentials = (
+            b"\x01" + b"\x00" * 11 + b"\xaa" * 20
+        )
+        state.balances[i] = int(ctx.MAX_EFFECTIVE_BALANCE) + rng.randrange(1, 10**9)
+    # the one deposit the chain carries, in a deposit tree of its own
+    data = chain_utils.make_deposit_data(N, ctx)
+    deposit = chain_utils.deposits_from_datas([data], ctx)[0]
+    node = DepositData.hash_tree_root(data)
+    for sibling in deposit.proof:
+        node = hash_pair(node, bytes(sibling))
+    state.eth1_data.deposit_root = node
+    state.eth1_data.deposit_count = 1
+    state.eth1_deposit_index = 0
+
+    sp.process_slots(state, 2 * spe - 1, ctx)
+    state.previous_epoch_participation = [0b111] * N
+    state.current_epoch_participation = [0b111] * N
+    later = [0b111 if rng.random() < 0.9 else 0 for _ in range(N + 1)]
+    first, n_blocks = 2 * spe + 1, 3
+    probe, signers = state.copy(), set()
+    for slot in range(first, first + n_blocks):
+        sp.process_slots(probe, slot, ctx)
+        signers.add(h.get_beacon_proposer_index(probe, ctx))
+    for slot in range(first - 1, first + n_blocks):
+        signers.update(h.get_beacon_committee(probe, slot, 0, ctx))
+    chain_utils.realize_validator_keys(state, signers)
+    literal = state.copy()
+
+    def root(s) -> bytes:
+        return type(s).hash_tree_root(s)
+
+    # -- the columnar side builds the blocks as it imports them
+    start = _column_list_counters()
+    sp.process_slots(state, 2 * spe, ctx)
+    roots = [root(state)]
+    assert state.balances.__class__ is ColumnList
+    after_first = _column_list_counters()
+    assert after_first == {**start, "stores": start["stores"] + 1}
+    pending, blocks = [chain_utils.make_attestation(state, first - 1, 0, ctx)], []
+    for slot in range(first, first + n_blocks):
+        extras = {"deposits": [deposit]} if slot == first else {}
+        blocks.append(
+            chain_utils.produce_block_fork(
+                "deneb", state, slot, ctx, attestations=pending, apply=True,
+                **extras,
+            )
+        )
+        pending = [chain_utils.make_attestation(state, slot, 0, ctx)]
+        roots.append(root(state))
+        assert_column_consistency(state, f"block at slot {slot}")
+    swept = [len(b.message.body.execution_payload.withdrawals) for b in blocks]
+    assert swept[:2] == [16, 16] and swept[2] >= 11  # the seeded 43 and more
+    assert len(state.balances) == N + 1 and int(state.balances[N]) == int(data.amount)
+    assert state.balances.__class__ is ColumnList
+    assert _column_list_counters() == after_first  # the blocks: nothing at all
+    state.previous_epoch_participation = later
+    sp.process_slots(state, 3 * spe, ctx)
+    roots.append(root(state))
+    assert state.balances.__class__ is ColumnList
+    assert _column_list_counters() == {
+        **after_first, "stores": after_first["stores"] + 1
+    }
+    assert_column_consistency(state, "after the second boundary")
+
+    # -- the literal oracle imports the same blocks
+    monkeypatch.setenv("ECT_EPOCH_VECTOR", "off")
+    before = _column_list_counters()
+    sp.process_slots(literal, 2 * spe, ctx)
+    want = [root(literal)]
+    for block in blocks:
+        stm.state_transition(literal, block, ctx)
+        want.append(root(literal))
+    literal.previous_epoch_participation = later
+    sp.process_slots(literal, 3 * spe, ctx)
+    want.append(root(literal))
+    assert literal.balances.__class__ is CachedRootList
+    assert _column_list_counters() == before
+    assert roots == want and len(set(roots)) == len(roots)
+    assert_bit_identical(state, literal, "after two boundaries and three blocks")

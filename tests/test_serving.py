@@ -354,6 +354,53 @@ def test_snapshot_isolation_across_commit(served):
         bundle["balances"][0] = 1
 
 
+@pytest.mark.parametrize("bundle_built", ["before-the-writes", "after-the-writes"])
+def test_frozen_snapshot_survives_writes_to_column_primary_balances(bundle_built):
+    """The live state's balances are column-primary after an epoch commit
+    (ssz/column_list.py): a single write there goes straight into the
+    array. A snapshot is a copy, the copy shares the array with ownership
+    dropped on both sides, so the live side clones before its first write
+    and the frozen bundle, and the oracle's element reads on the frozen
+    state, never move."""
+    import numpy as np
+
+    from ethereum_consensus_tpu.models import ops_vector
+    from ethereum_consensus_tpu.models.phase0 import helpers as h
+    from ethereum_consensus_tpu.serving.headstore import Snapshot
+    from ethereum_consensus_tpu.ssz.column_list import ColumnList
+
+    live, ctx = fresh_genesis(64, "minimal")
+    live = live.copy()
+    old = np.array(list(live.balances), dtype=np.uint64)
+    committed = old + np.arange(64, dtype=np.uint64)
+    ops_vector.adopt_list_column(
+        live.balances, committed, committed != old, (1 << 64) - 1
+    )
+    assert live.balances.__class__ is ColumnList
+    boxed = metrics.counter("ssz.column_list.boxed_rows").value()
+    frozen = live.copy()
+    snap = Snapshot(frozen, ctx, int(frozen.slot), type(frozen).hash_tree_root(frozen))
+    want = committed.tolist()
+    if bundle_built == "before-the-writes":
+        assert snap.bundle()["balances"].tolist() == want
+    for i in (0, 5, 63):  # what a block's rewards and penalties do
+        h.increase_balance(live, i, 7)
+        h.decrease_balance(live, i + 0, 2)
+    bundle = snap.bundle()
+    assert bundle["balances"].tolist() == want
+    assert not bundle["balances"].flags.writeable
+    assert [int(snap.raw.balances[i]) for i in range(64)] == want
+    assert type(frozen).hash_tree_root(frozen) == snap.root
+    moved = list(want)
+    for i in (0, 5, 63):
+        moved[i] += 5
+    assert list(live.balances) == moved
+    assert ops_vector.columns_for(live).list_column(live, "balances").tolist() == moved
+    assert live.balances.__class__ is ColumnList
+    assert frozen.balances.__class__ is ColumnList
+    assert metrics.counter("ssz.column_list.boxed_rows").value() == boxed
+
+
 def test_rollback_never_published(served):
     """A storm's rolled-back states must never reach the store: every
     published root is a committed honest-chain position."""

@@ -223,6 +223,39 @@ def test_manifest_matches_instrumented_runtime_methods():
     }
 
 
+def test_column_list_module_is_in_scope_with_its_sanctioned_raw_calls(tmp_path):
+    """Column-primary storage (ssz/column_list.py) makes raw base-list
+    calls on purpose, and only the ones ssz/core.py's manifest names: the
+    analyzer covers the module, passes those, and flags any other."""
+    from ethereum_consensus_tpu.ssz import core as ssz_core
+
+    static = mutation.load_manifest(CORE_PATH)
+    surface = ssz_core.instrumented_surface()["column_list"]
+    assert static["column_list_module"] == surface["module"]
+    assert static["column_list_raw_calls"] == surface["raw_list_calls"]
+    real = os.path.join(
+        REPO_ROOT, "ethereum_consensus_tpu", *surface["module"].split("/")
+    )
+    assert real in speclint._default_targets(REPO_ROOT)["mutation_paths"]
+    assert mutation.analyze([real], REPO_ROOT, CORE_PATH) == []
+    with open(real) as handle:
+        source = handle.read()
+    for name in surface["raw_list_calls"]:
+        assert f"list.{name}(" in source, name  # sanctioned and really used
+    # a copy of the module that also calls a mutator the manifest does not
+    # name: flagged there, and the sanctioned calls flagged anywhere else
+    seeded = source + "\n\ndef bad_raw_extend(lst):\n    list.extend(lst, [1])\n"
+    inside = tmp_path / "pkg" / "ssz" / "column_list.py"
+    inside.parent.mkdir(parents=True)
+    inside.write_text(seeded)
+    outside = tmp_path / "pkg" / "ssz" / "elsewhere.py"
+    outside.write_text(seeded)
+    found = mutation.analyze([str(inside)], str(tmp_path), CORE_PATH)
+    assert _rules_by_symbol(found) == {("mutation/raw-list-call", "bad_raw_extend")}
+    found = mutation.analyze([str(outside)], str(tmp_path), CORE_PATH)
+    assert len(found) > 1 and {f.rule for f in found} == {"mutation/raw-list-call"}
+
+
 # ---------------------------------------------------------------------------
 # concurrency self-tests
 # ---------------------------------------------------------------------------

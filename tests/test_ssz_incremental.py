@@ -542,7 +542,13 @@ def test_column_pack_refused(width, side, fault, small_groups):
     is not read, and the root is the ints'."""
     import numpy as np
 
+    from ethereum_consensus_tpu.ssz import column_list
+
     LT, lst, ints, size = _column_list(width, side, "adopted")
+    # the column as a CACHE beside boxed content (what a bulk_store of
+    # the array leaves): a uint64 adoption is column-primary, where the
+    # column is the content and cannot go stale (tests/test_column_list.py)
+    column_list.leave(lst)
     vmax = lst._col_cache[2]
     if fault == "dirty":
         lst[5] = ints[5] = 7  # the instrumented mutator names the index
@@ -587,7 +593,10 @@ def test_column_pack_second_root_moves_no_counter(width, side, small_groups):
 def test_column_pack_then_write(width, side, small_groups):
     """(d) dirty tracking is armed by the shortcut's memo as by any full
     pack: a write marks its group and the next root is the plain list's."""
+    from ethereum_consensus_tpu.ssz import column_list
+
     LT, lst, ints, size = _column_list(width, side, "adopted")
+    column_list.leave(lst)  # the plain list and its cache, as above
     LT.hash_tree_root(lst)
     i = len(ints) - 3
     lst[i] = ints[i] = 99

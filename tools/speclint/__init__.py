@@ -117,6 +117,10 @@ def _default_targets(root: str) -> dict:
             # its providers would corrupt every later branch AND the
             # snapshot root it must verify against
             os.path.join(root, _PKG, "proofs"),
+            # column-primary list storage makes raw base-list calls ON
+            # PURPOSE, like ssz/core.py — but only the two the manifest
+            # names (COLUMN_LIST_RAW_CALLS); every other one is a finding
+            os.path.join(root, _PKG, "ssz", "column_list.py"),
         ),
         "concurrency_paths": iter_py_files(
             os.path.join(root, _PKG, "pipeline"),

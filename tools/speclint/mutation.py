@@ -15,7 +15,12 @@ analysis and stays honest if the surface grows.
   calling the *base* list method on an SSZ collection skips the
   instrumented wrapper entirely (dirty groups unmarked, caches stale).
   This is exactly what ``ssz/core.py`` does internally ON PURPOSE, which
-  is why it alone is outside this analyzer's scope.
+  is why it alone is outside this analyzer's scope. Column-primary
+  storage (``ssz/column_list.py``) is INSIDE it: the manifest names the
+  module (``COLUMN_LIST_MODULE``) and the base calls it may make
+  (``COLUMN_LIST_RAW_CALLS``: the slice store that swaps a list's slots
+  on entry and exit, the sentinel ``append``), and any other raw call
+  there is a finding like anywhere else.
 * ``mutation/setattr-bypass`` — ``object.__setattr__(container, ...)``
   skips ``Container.__setattr__``: no ``_htr_cache`` eviction, no parent
   notification.
@@ -47,14 +52,21 @@ def load_manifest(core_path: str) -> dict:
         tree = ast.parse(f.read(), filename=core_path)
     list_mutators = None
     bulk_mutators = ("bulk_store",)
+    column_module = None
+    column_raw_calls = ()
     for node in ast.walk(tree):
         if isinstance(node, ast.Assign):
             for target in node.targets:
-                if (
-                    isinstance(target, ast.Name)
-                    and target.id == "INSTRUMENTED_LIST_MUTATORS"
-                ):
+                if not isinstance(target, ast.Name):
+                    continue
+                if target.id == "INSTRUMENTED_LIST_MUTATORS":
                     list_mutators = literal_str_list(node.value)
+                elif target.id == "COLUMN_LIST_RAW_CALLS":
+                    column_raw_calls = tuple(literal_str_list(node.value) or ())
+                elif target.id == "COLUMN_LIST_MODULE" and isinstance(
+                    node.value, ast.Constant
+                ):
+                    column_module = node.value.value
     if not list_mutators:
         raise RuntimeError(
             f"{core_path}: INSTRUMENTED_LIST_MUTATORS tuple not found — the "
@@ -63,6 +75,9 @@ def load_manifest(core_path: str) -> dict:
     return {
         "list_mutators": tuple(list_mutators),
         "bulk_mutators": bulk_mutators,
+        # the one module with sanctioned raw calls, and which ones
+        "column_list_module": column_module,
+        "column_list_raw_calls": column_raw_calls,
     }
 
 
@@ -91,6 +106,12 @@ class _Visitor(ast.NodeVisitor):
         self.manifest = manifest
         self.findings: list[Finding] = []
         self.stack: list[str] = []
+        module = manifest.get("column_list_module")
+        self.sanctioned_raw = (
+            manifest.get("column_list_raw_calls", ())
+            if module and path.replace(os.sep, "/").endswith("/" + module)
+            else ()
+        )
 
     # -- scope tracking ------------------------------------------------------
     def visit_FunctionDef(self, node):
@@ -127,6 +148,7 @@ class _Visitor(ast.NodeVisitor):
                 isinstance(base, ast.Name)
                 and base.id == "list"
                 and func.attr in self.manifest["list_mutators"]
+                and func.attr not in self.sanctioned_raw
             ):
                 self._emit(
                     "mutation/raw-list-call",
