@@ -69,7 +69,13 @@ a pass did to the registry: ``epoch_vector.rows``, ``.rows_active``
 eligibility epoch was stamped), ``.registry.activated`` (rows dequeued)
 and ``.validator_writes`` (validator fields written at commit); the
 registry span carries ``queued`` and ``activated``, the commit span
-``writes``.
+``writes``. A registry that grows: ``epoch_vector.rows_appended`` (rows
+by which the registry is longer than at the last pass on this state's
+lineage), ``ops_vector.columns.extended_rows`` (rows the working columns
+were extended by, not rebuilt; span ``epoch_vector.sync.extend``) and
+``epoch_vector.fused.pad_rows`` (inert rows a fused dispatch carried:
+its shape is ``fused_dispatch_rows(n)``, not ``n``); the fused span
+carries ``rows`` and ``padded``.
 """
 
 from __future__ import annotations
@@ -92,6 +98,7 @@ __all__ = [
     "u32_planes",
     "u64_columns",
     "jitted_kernels",
+    "fused_dispatch_rows",
     "EPOCH_VECTOR_MIN_VALIDATORS",
 ]
 
@@ -232,6 +239,38 @@ def jitted_kernels() -> dict:
         }
         _JITTED_KERNELS.update(built)
     return _JITTED_KERNELS
+
+
+# The fused program is dispatched at the registry's length rounded up to
+# a whole granule of rows, so that its shape (which keys the trace, the
+# XLA compile and the persistent cache) changes once in 128 epochs of
+# MAX_DEPOSITS x SLOTS_PER_EPOCH = 512 new validators and not at every
+# epoch that held one deposit. At most 3.4 % more rows at 1.9 M; 2^20 and
+# 2^21 pad nothing. A module global, as the dirty-group geometry of
+# ssz/core.py is, so that tests can shrink it and cross an edge cheaply.
+FUSED_ROW_GRANULE = 1 << 16
+
+
+def fused_dispatch_rows(n: int) -> int:
+    """The length ``_fused_route`` dispatches the fused program at for a
+    registry of ``n`` rows: a function of ``n`` alone."""
+    return -(-n // FUSED_ROW_GRANULE) * FUSED_ROW_GRANULE
+
+
+def _padded(np, column, rows: int):
+    """``column`` at the dispatched length: itself where it has it, else
+    an owned copy with zero rows behind it. A pad row is inert by
+    construction: not eligible and not active (the two masks gate every
+    reward, penalty, score update and masked sum of the kernel), its
+    effective balance, balance, score and flags 0 (so it adds 0 to 0 and
+    the wrap census cannot see it)."""
+    n = column.shape[0]
+    if n == rows:
+        return column
+    out = np.empty(rows, dtype=column.dtype)
+    out[:n] = column
+    out[n:] = 0
+    return out
 
 
 def kernel_cache_census() -> "tuple[int, int]":
@@ -533,6 +572,7 @@ def _sync(state, context, fork):
         return None
     # the column acquisition, apart from the guards and masks below
     with trace.span("epoch_vector.sync.columns"):
+        held = ops_vector.resident_rows(state.validators)
         vc = cols.validator_columns(state)
         balances = cols.list_column(state, "balances")
         if vc is None or balances is None:
@@ -542,6 +582,10 @@ def _sync(state, context, fork):
         if balances.shape[0] != n:
             fallback("length_mismatch")
             return None
+        if held is not None and n > held:
+            # the registry is longer than at the last pass on this
+            # state's lineage: deposits came in
+            metrics.counter("epoch_vector.rows_appended").inc(n - held)
         ec = _EpochColumns()
         ec.np = np
         ec.state = state
@@ -1158,18 +1202,28 @@ def _fused_route(ec, leaking: bool) -> bool:
     try:
         import jax.numpy as jnp
 
+        padded = fused_dispatch_rows(ec.n)
         with trace.span(
-            "epoch_vector.fused", validators=ec.n, route="jit"
+            "epoch_vector.fused", validators=ec.n, route="jit",
+            rows=ec.n, padded=padded,
         ):
             # ONE upload of the packed columns for BOTH stages — the
             # per-stage h2d transfers the staged device route paid are
             # gone (the transfer ledger proves it: a single
-            # epoch_vector.fused site instead of inactivity + rewards)
+            # epoch_vector.fused site instead of inactivity + rewards).
+            # They go up at the dispatched length, inert rows behind the
+            # registry's, and the results are cut back to ``n`` below.
             arrays = _device_obs.h2d(
                 "epoch_vector.fused",
-                ec.balances, ec.eff, ec.prev_part, ec.slashed,
-                ec.active_prev, ec.eligible, ec.inact,
+                *(
+                    _padded(np, column, padded)
+                    for column in (
+                        ec.balances, ec.eff, ec.prev_part, ec.slashed,
+                        ec.active_prev, ec.eligible, ec.inact,
+                    )
+                ),
             )
+            metrics.counter("epoch_vector.fused.pad_rows").inc(padded - ec.n)
             planes, wrapped = ec.fused(
                 *arrays,
                 jnp.uint64(increment),
@@ -1196,7 +1250,7 @@ def _fused_route(ec, leaking: bool) -> bool:
                 return False
             planes = _device_obs.d2h("epoch_vector.fused", planes)
             with trace.span("epoch_vector.fused.unpack"):
-                new_scores, new_balances = u64_columns(planes)
+                new_scores, new_balances = u64_columns(planes[:, : ec.n])
     except Exception as exc:  # noqa: BLE001 — host fallback
         _fused_fallback(
             ec, "device_unusable", error=repr(exc)[:160], validators=ec.n

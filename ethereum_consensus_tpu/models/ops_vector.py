@@ -64,6 +64,7 @@ from ..utils import trace
 __all__ = [
     "RegistryColumns",
     "columns_for",
+    "resident_rows",
     "gather_rows",
     "pack_registry_cached",
     "process_attestations_batch",
@@ -152,8 +153,11 @@ def _read_validator_row(v):
 # _col_cache records, stored ON the CachedRootList itself so they travel
 # across state copies (ssz/core.py _share_col_cache — structural share,
 # copy-on-write via _col_owned): ("validators", arrays_dict) for the
-# registry, ("list", arr, vmax) for scalar lists (a fourth entry, where
-# present, is column-primary storage's growth buffer and nobody else's).
+# registry, ("list", arr, vmax) for scalar lists. A further entry, where
+# present, is the over-allocated buffer (for the registry: the dict of
+# them) the arrays are the heads of, so that growth by ``append`` is
+# amortised: ``_grown`` here, ``ColumnList.append`` for a column-primary
+# list. A writer that replaces the arrays leaves it out.
 
 # the exact classes whose scalar-list column this module serves: the
 # plain list (a cache beside the boxed content) and the column-primary
@@ -201,17 +205,89 @@ def _build_validator_cols(vals) -> "dict | None":
     return arrays
 
 
+# rows by which working columns were extended in place: the validator
+# column set once a row and each list column once a row (a column-primary
+# list extends its own column in ``append`` and counts there, under this
+# name: ssz/column_list.py)
+_EXTENDED_ROWS = metrics.counter("ops_vector.columns.extended_rows")
+
+
+def _appended_since(cd, have: int, n: int) -> bool:
+    """A list of ``n`` elements whose columns hold ``have`` rows grew by
+    ``append`` alone since the two were in step: every index from
+    ``have`` up is marked (``_mutation_elems`` names an append's element
+    and drops the tracking at any other length change)."""
+    return n > have and cd.issuperset(range(have, n))
+
+
+def _grown(np, arr, buf, owned: bool, n: int):
+    """(``arr`` at ``n`` rows, its buffer), the rows past ``arr``'s own
+    not yet written: the head of ``buf`` where that buffer is ours and
+    long enough, else of a fresh one with an eighth more rows than asked
+    for (amortised, as ``ColumnList.append`` grows) and ``arr`` copied in,
+    which is also the clone a column shared with a copy owes its first
+    write."""
+    if buf is None or not owned or buf.shape[0] < n:
+        buf = np.empty(n + max(n >> 3, 64), dtype=arr.dtype)
+        buf[: arr.shape[0]] = arr
+    return buf[:n], buf
+
+
+def _extend_validator_cols(vals, cc, have: int, n: int) -> "dict | None":
+    """The resident columns extended from ``have`` rows by the rows
+    appended to ``vals``; None where a new row holds what the column
+    contract cannot trust."""
+    np = _np()
+    bufs = cc[2] if len(cc) > 2 else {}
+    with trace.span("epoch_vector.sync.extend", rows=n - have):
+        rows = [
+            _read_validator_row(list.__getitem__(vals, i)) for i in range(have, n)
+        ]
+        if None in rows:
+            return None
+        ints, slashed, prefixes = zip(*rows)
+        grown, held = {}, {}
+        for f, a in cc[1].items():
+            grown[f], held[f] = _grown(np, a, bufs.get(f), vals._col_owned, n)
+        for f, column in zip(_VAL_INT_FIELDS, zip(*ints)):
+            grown[f][have:] = np.array(column, dtype=np.uint64)
+        grown["slashed"][have:] = slashed
+        grown["withdrawal_prefix"][have:] = prefixes
+        vals._col_cache = ("validators", grown, held)
+        vals._col_owned = True
+        vals._col_dirty.difference_update(range(have, n))
+    _EXTENDED_ROWS.inc(n - have)
+    return grown
+
+
+def resident_rows(vals) -> "int | None":
+    """How many rows the registry's resident columns hold (they travel
+    with copies, so: the registry's length at the last sync on this
+    state's lineage), or None where none are resident."""
+    cc = getattr(vals, "_col_cache", None)
+    if cc is None or cc[0] != "validators":
+        return None
+    return next(iter(cc[1].values())).shape[0]
+
+
 def _sync_validator_cols(vals) -> "dict | None":
     cc = vals._col_cache
     cd = vals._col_dirty
-    if (
-        cc is None
-        or cd is None
-        or cc[0] != "validators"
-        or next(iter(cc[1].values())).shape[0] != len(vals)
-    ):
+    if cc is None or cd is None or cc[0] != "validators":
         return _build_validator_cols(vals)
     arrays = cc[1]
+    have, n = next(iter(arrays.values())).shape[0], len(vals)
+    if have != n:
+        # deposits append: the columns follow by the new rows alone; any
+        # other history of a changed length rebuilds
+        arrays = (
+            _extend_validator_cols(vals, cc, have, n)
+            if _appended_since(cd, have, n)
+            else None
+        )
+        if arrays is None:
+            vals._col_dirty = None
+            return _build_validator_cols(vals)
     if cd:
         if not vals._col_owned:
             # shared with a copy sibling: clone before the first refresh
@@ -271,6 +347,25 @@ def _build_list_col(src, dtype, vmax):
     return arr
 
 
+def _extend_list_col(src, cc, have: int, n: int):
+    """``_extend_validator_cols`` for a scalar list's one column."""
+    np = _np()
+    vmax = cc[2]
+    with trace.span("epoch_vector.sync.extend", rows=n - have):
+        tail = list.__getitem__(src, slice(have, n))
+        if not all(type(v) is int and 0 <= v <= vmax for v in tail):
+            return None
+        arr, buf = _grown(
+            np, cc[1], cc[3] if len(cc) > 3 else None, src._col_owned, n
+        )
+        arr[have:] = np.array(tail, dtype=np.uint64)
+        src._col_cache = ("list", arr, vmax, buf)
+        src._col_owned = True
+        src._col_dirty.difference_update(range(have, n))
+    _EXTENDED_ROWS.inc(n - have)
+    return arr
+
+
 def _sync_list_col(src, dtype, vmax):
     cc = src._col_cache
     cd = src._col_dirty
@@ -279,11 +374,22 @@ def _sync_list_col(src, dtype, vmax):
         or cd is None
         or cc[0] != "list"
         or cc[2] != vmax
-        or cc[1].shape[0] != len(src)
         or cc[1].dtype != dtype
     ):
         return _build_list_col(src, dtype, vmax)
     arr = cc[1]
+    have, n = arr.shape[0], len(src)
+    if have != n:
+        # (a column-primary list grows its column in its own ``append``
+        # and never comes here with another length)
+        arr = (
+            _extend_list_col(src, cc, have, n)
+            if _appended_since(cd, have, n)
+            else None
+        )
+        if arr is None:
+            src._col_dirty = None
+            return _build_list_col(src, dtype, vmax)
     if cd:
         if not src._col_owned:
             arr = arr.copy()

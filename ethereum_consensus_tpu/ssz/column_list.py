@@ -60,6 +60,10 @@ __all__ = ["ColumnList", "UNBOXED", "adopt", "leave", "share"]
 _STORES = _metrics.counter("ssz.column_list.stores")
 _LEFT = _metrics.counter("ssz.column_list.left")
 _BOXED_ROWS = _metrics.counter("ssz.column_list.boxed_rows")
+# rows ``append`` grew a column by: the working columns' own count
+# (models/ops_vector.py, which extends every other list's column), since
+# here the content is the working column
+_EXTENDED_ROWS = _metrics.counter("ops_vector.columns.extended_rows")
 
 
 class _Unboxed:
@@ -224,6 +228,7 @@ class ColumnList(CachedRootList):
         buf[n] = value
         self._col_cache = ("list", buf[: n + 1], cc[2], buf)
         list.append(self, UNBOXED)
+        _EXTENDED_ROWS.inc()
         dg = self._dirty_groups
         if dg is not None:
             dg.add(n >> _core._DIRTY_GROUP_SHIFT)

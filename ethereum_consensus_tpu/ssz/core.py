@@ -499,9 +499,11 @@ class CachedRootList(list):
         # every sanctioned mutation channel — the instrumented list
         # mutators below, Container.__setattr__'s weak-parent notify for
         # container elements, and bulk_store's changed-indices contract.
-        # Any mutation whose touched indices can't be named (structural
-        # resize, reorder, uncertified bulk write) resets it to None, and
-        # the consumer falls back to a full column rebuild. This is the
+        # Any mutation whose touched indices can't be named (a structural
+        # resize other than ``append``, reorder, uncertified bulk write)
+        # resets it to None, and the consumer falls back to a full column
+        # rebuild; an ``append`` marks the new element's index, which lies
+        # past the consumer's arrays until it extends them. This is the
         # same single-writer discipline as _dirty_groups, at element
         # (not 4096-group) granularity, for host arrays instead of
         # merkle subtrees.
@@ -593,9 +595,15 @@ def _mutation_groups(name, args, pre_len, post_len):
 def _mutation_elems(name, args, pre_len, post_len):
     """Element indices touched by an instrumented list mutation, for the
     column-invalidation channel (``_col_dirty``), or None when the touched
-    set can't be named (resize, reorder, slice-resize) — the columnar
-    consumer then rebuilds. Stricter than ``_mutation_groups``: a column
-    array has fixed length, so ANY length change loses tracking."""
+    set can't be named (reorder, slice-resize, any resize but an
+    ``append``) — the columnar consumer then rebuilds. An ``append`` names
+    the element it added, the old length: an index at or past the
+    consumer's array, which extends its columns by the appended rows
+    (models/ops_vector.py) instead of rebuilding a registry because a
+    deposit came in. Every other length change loses tracking, which is
+    stricter than ``_mutation_groups``."""
+    if name == "append":
+        return (pre_len,)
     if post_len != pre_len:
         return None
     if name == "__setitem__":

@@ -390,6 +390,11 @@ def test_pipelined_replay_trace_has_device_lane_and_verify_route(monkeypatch):
     # the columnar pass engages at 64 validators for this run, and its
     # gate selects the fused jitted kernel
     monkeypatch.setattr(epoch_vector, "EPOCH_VECTOR_MIN_VALIDATORS", 0)
+    # jitted kernels of this test's own, so that its boundary compiles:
+    # every registry of up to 2^16 rows is dispatched in one shape
+    # (fused_dispatch_rows), which an earlier test of this process has
+    # compiled by now
+    monkeypatch.setattr(epoch_vector, "_JITTED_KERNELS", {})
     fused0 = _metric("epoch_vector.fused.jit")
     ops.install(
         sweeps_min_n=1,            # the epoch pass runs the fused kernel

@@ -308,15 +308,46 @@ def test_list_column_delta_refresh_and_bulk_store():
     assert metrics.counter("ops_vector.columns.builds").value() == builds0
 
 
-def test_structural_mutation_rebuilds():
+@pytest.mark.parametrize("mutate", [
+    lambda balances: balances.insert(0, 5),
+    lambda balances: balances.pop(),
+    lambda balances: (balances.append(5), balances.pop(0)),
+], ids=["insert", "pop", "append_then_pop"])
+def test_structural_mutation_rebuilds(mutate):
     state, _ = _warm_state()
     cols = ops_vector.columns_for(state)
     cols.list_column(state, "balances")
     builds0 = metrics.counter("ops_vector.columns.builds").value()
-    state.balances.append(5)
+    mutate(state.balances)
     col = cols.list_column(state, "balances")
-    assert col.shape[0] == len(state.balances) and int(col[-1]) == 5
+    assert col.tolist() == list(state.balances)
     assert metrics.counter("ops_vector.columns.builds").value() == builds0 + 1
+
+
+def test_an_append_extends_and_rebuilds_nothing():
+    """A deposit appends: the column follows by the new row (and a write
+    to an old row beside it), the tracking stays, nothing is rebuilt."""
+    state, _ = _warm_state()
+    cols = ops_vector.columns_for(state)
+    first = cols.list_column(state, "balances")
+    n = len(state.balances)
+    builds0 = metrics.counter("ops_vector.columns.builds").value()
+    extended0 = metrics.counter("ops_vector.columns.extended_rows").value()
+    state.balances.append(5)
+    state.balances[3] = 77
+    state.balances.append((1 << 64) - 1)
+    assert state.balances._col_dirty == {n, 3, n + 1}
+    col = cols.list_column(state, "balances")
+    assert col.tolist() == list(state.balances) and col.shape[0] == n + 2
+    assert state.balances._col_dirty == set()
+    assert metrics.counter("ops_vector.columns.builds").value() == builds0
+    assert metrics.counter("ops_vector.columns.extended_rows").value() == extended0 + 2
+    assert first.shape[0] == n  # a view handed out earlier keeps its length
+    # the next append lands in the buffer the first extension bought
+    buffer = state.balances._col_cache[3]
+    state.balances.append(9)
+    assert cols.list_column(state, "balances").tolist() == list(state.balances)
+    assert state.balances._col_cache[3] is buffer
 
 
 def test_state_copy_gets_its_own_columns():

@@ -187,13 +187,18 @@ def test_the_fused_route_shows_its_copies_and_its_wait(session):
     fused = _only(line, "ect:epoch_vector.fused")
     upload = _only(line, "ect:epoch_vector.fused.h2d")
     wait = _only(line, "ect:epoch_vector.fused.wait")
-    # scores and balances come down as one uint32[4, n]: 16 B a row (the
-    # copy is started before the wait, so its span may find it done)
+    # scores and balances come down as one uint32[4, padded]: 16 B a row
+    # of the dispatched shape, the registry's rows and the inert ones
+    # behind them (the copy is started before the wait, so its span may
+    # find it done)
     download = _only(line, "ect:epoch_vector.fused.d2h")
     unpack = _only(line, "ect:epoch_vector.fused.unpack")
     assert fused[3]["route"] == "jit"
-    assert int(upload[3]["bytes"]) > 0
-    assert int(download[3]["bytes"]) == 16 * int(fused[3]["validators"]) > 0
+    assert int(fused[3]["rows"]) == int(fused[3]["validators"]) == 96
+    assert int(fused[3]["padded"]) == 1 << 16  # one granule holds 96 rows
+    # seven columns a row: three u64, four of one byte
+    assert int(upload[3]["bytes"]) == 28 * int(fused[3]["padded"])
+    assert int(download[3]["bytes"]) == 16 * int(fused[3]["padded"])
     order = [upload, wait, download, unpack]
     assert all(fused[1] <= e[1] and e[2] <= fused[2] for e in order)
     assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
