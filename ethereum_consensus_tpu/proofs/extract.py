@@ -4,12 +4,14 @@ The fourth data plane (docs/PROOFS.md): a generalized-index walker that
 serves single-branch Merkle proofs by READING the incremental-HTR
 machinery instead of re-merkleizing. After a warm ``hash_tree_root``
 walk, the big collections of a BeaconState carry stored levels —
-``CachedRootList._pack_tree`` (packed basic / Bytes32 collections) and
-``CachedRootList._tree_memo`` (scalar-leaf container registries), each
-an ``IncrementalPaddedTree`` of 4096-chunk group mids (ssz/core.py) —
-so every sibling at or above the group layer is a 32-byte slice read,
-and the handful of sub-group siblings cost one 4096-chunk subtree
-rebuild, memoized per extraction context.
+``CachedRootList._pack_tree`` (packed basic / Bytes32 collections: an
+``IncrementalPaddedTree`` of 4096-chunk group mids, ssz/core.py) and
+``CachedRootList._tree_memo`` (scalar-leaf container registries: one
+whose level 0 is the element roots) — so every sibling at or above a
+tree's level 0 is a 32-byte slice read: all of a registry's, and a
+packed collection's down to the group layer, under which the handful of
+sub-group siblings cost one 4096-chunk subtree rebuild, memoized per
+extraction context.
 
 Layers without stored levels materialize a full ``Tree`` over their top
 chunks — the cold ``compute_merkle_proof`` walk, which doubles as the
@@ -119,10 +121,12 @@ class _SubNodes:
 
 class _StoredLevels:
     """Warm provider over a pack-tree / tree-memo: siblings at or above
-    the group layer read straight off ``IncrementalPaddedTree.levels``;
-    sub-group siblings build (and memoize) one 4096-chunk subtree per
-    touched group — for a single proof every sub-group sibling shares
-    the target leaf's group, so the whole branch costs one rebuild."""
+    the tree's level 0 read straight off ``IncrementalPaddedTree.levels``
+    (a tree-memo stores every level, ``level_offset`` 0); under a
+    pack-tree's group layer, sub-group siblings build (and memoize) one
+    4096-chunk subtree per touched group — for a single proof every
+    sub-group sibling shares the target leaf's group, so the whole
+    branch costs one rebuild."""
 
     warm = True
 
@@ -203,16 +207,17 @@ def _pack_provider(typ, values, key, esize, ctx):
 
 def _tree_provider(typ, values, tkey, ctx):
     """Stored-levels provider off ``_tree_memo`` (scalar-leaf container
-    registries: chunks are the joined element roots)."""
+    registries: the tree's level 0 is the joined element roots, so every
+    node of the layer is a slice read and no group is ever rebuilt)."""
     tm = values._tree_memo
     if tm is None:
         return None, "no_memo"
     if tm[0] != tkey:
         return None, "memo_key"
-    chunks, tree = tm[1], tm[2]
+    tree = tm[2]
     if tree is None:
         return None, "no_levels"
-    if len(chunks) != BYTES_PER_CHUNK * len(values):
+    if tree.node_count() != len(values):
         return None, "stale_buffer"
     if tree._dirty is None or tree._dirty:
         return None, "stale_tree"
@@ -222,14 +227,7 @@ def _tree_provider(typ, values, tkey, ctx):
         # groups whose elements refuse caching — either way the next
         # mutation would not be named, so the walker declines
         return None, "dirty_groups"
-    cbytes = BYTES_PER_CHUNK << tree.level_offset
-
-    def group_chunks(g, chunks=chunks, cbytes=cbytes):
-        return bytes(chunks[g * cbytes : (g + 1) * cbytes])
-
-    prov = _StoredLevels(
-        tree, group_chunks, len(chunks) // BYTES_PER_CHUNK, values, ctx
-    )
+    prov = _StoredLevels(tree, None, tree.node_count(), values, ctx)
     if prov.depth != (next_pow_of_two(_core._chunk_count_of(typ)) - 1).bit_length():
         return None, "depth_mismatch"
     return prov, None

@@ -21,6 +21,7 @@ from typing import Any
 
 from ..telemetry import memory as _memory
 from ..telemetry import metrics as _metrics
+from . import hash as _hash_mod
 from .hash import hash_level
 from .merkle import (
     BYTES_PER_CHUNK,
@@ -462,15 +463,19 @@ class CachedRootList(list):
         # through that edge; a mutation through the list itself is known
         # by group alone and sets it None until the next walk re-arms it.
         # With it the splice re-hashes the written elements of a dirty
-        # group instead of visiting all 4096 (an inactivity leak steps
-        # some 700 scattered validators down a boundary: every group of
-        # the registry is dirty, and a handful of its rows).
+        # group and their paths instead of the group's 4096 leaves (an
+        # inactivity leak steps some 700 scattered validators down a
+        # boundary: every group of the registry is dirty, and a handful
+        # of its rows).
         self._dirty_elems: "set | None" = None
-        # (key, chunks bytearray, IncrementalPaddedTree, root) for lists of
-        # scalar-leaf containers: chunks = the joined element roots, tree =
-        # the 4096-chunk group mids. Survives mutation (dirty groups name
-        # exactly what to re-merkleize); shared structurally with copies
-        # under _memos_owned copy-on-write.
+        # [key, chunks bytearray, IncrementalPaddedTree, root] for lists of
+        # scalar-leaf containers: chunks = the joined element roots, which
+        # ARE the tree's level 0 (one object under two names: every level
+        # above the element roots is stored, so a written row costs its
+        # root and its path). A list too small to track keeps (key, chunks
+        # bytes, None, root). Survives mutation (the marks name exactly
+        # what to re-hash); shared structurally with copies under
+        # _memos_owned copy-on-write.
         self._tree_memo: "list | None" = None
         # same shape for packed basic/bytes32 collections: (key, packed
         # bytearray, IncrementalPaddedTree, root)
@@ -549,8 +554,10 @@ class CachedRootList(list):
 
 # Dirty-group granularity: 4096 elements per group — one group of a
 # scalar-leaf container list spans exactly one 4096-leaf merkle subtree
-# (one chunk per element root). Module globals so the property tests can
-# shrink the geometry and exercise many groups on small collections.
+# (one chunk per element root); such a list is also marked by element
+# (_dirty_elems), and _tree_splice spends by the finer of the two. Module
+# globals so the property tests can shrink the geometry and exercise many
+# groups on small collections.
 _DIRTY_GROUP_SHIFT = 12
 # Above this many pending column-dirty element indices a full column
 # rebuild is cheaper than maintaining (and later replaying) the set.
@@ -1246,13 +1253,48 @@ def _pack_memo_gen_hit(values, key) -> bool:
     )
 
 
+# what a splice of a scalar-leaf container list spent, by route: rows whose
+# path it re-hashed off the stored levels, and 4096-row groups it walked
+# and re-merkleized whole (marks that came through the list, sticky groups)
+_SPLICE_PATH_ROWS = _metrics.counter("ssz.tree_splice.path_rows")
+_SPLICE_GROUP_WALKS = _metrics.counter("ssz.tree_splice.group_walks")
+
+
+def _written_rows_roots(elem, values, rows, tree) -> list:
+    """Set the roots of the written ``rows`` as ``tree``'s level-0 nodes;
+    returns the rows that refused caching. Rows that miss their
+    ``_htr_cache`` are rooted columnar, in one batch
+    (_bulk_scalar_leaf_roots on the subset) once there are enough of them
+    for the native hasher; a value that path will not take sends them to
+    the per-element call, which raises the structured error or refuses to
+    cache."""
+    elems = [list.__getitem__(values, i) for i in rows]
+    cold = [v for v in elems if "_htr_cache" not in v.__dict__]
+    if len(cold) >= _hash_mod.NATIVE_MIN_NODES:
+        # fewer, and every level of the batch would go to hashlib anyway
+        _bulk_scalar_leaf_roots(elem, cold)
+    htr = elem.hash_tree_root
+    refused = []
+    for i, v in zip(rows, elems):
+        r = v.__dict__.get("_htr_cache")
+        if r is None:
+            r = htr(v)
+            if "_htr_cache" not in v.__dict__:
+                refused.append(i)  # see _tree_splice
+        tree.set_node(i, r)
+    return refused
+
+
 def _tree_splice(elem, values, tkey) -> "bytes | None":
-    """Dirty-group incremental root for a list of scalar-leaf containers:
-    re-join the element roots of ONLY the dirty 4096-element groups (the
-    untouched elements in those groups serve their instance caches), re-
-    merkleize those groups, and let the stored-level tree walk the
-    log-depth paths. Returns None when the memo or tracking state can't
-    support it — the caller falls back to the discovery walk."""
+    """Incremental root for a list of scalar-leaf containers, off a tree
+    whose level 0 is the element roots. A dirty group whose marks all
+    came through its elements costs the written rows' roots and their
+    paths: every sibling is read from the stored levels. A group marked
+    by group (a store through the list, a bulk store, a sticky group) is
+    walked whole: its elements' roots re-joined off their instance caches
+    and its 4096-leaf subtree re-hashed. Returns None when the memo or
+    tracking state can't support it — the caller falls back to the
+    discovery walk."""
     tm = values._tree_memo
     dg = values._dirty_groups
     if tm is None or dg is None or tm[0] != tkey or tm[2] is None:
@@ -1262,59 +1304,55 @@ def _tree_splice(elem, values, tkey) -> "bytes | None":
     if not dg:
         return root if len(chunks) == 32 * n else None
     if not values._memos_owned:
-        chunks = bytearray(chunks)
         tree = tree.clone()
+        chunks = tree.levels[0]
         tm = [tkey, chunks, tree, root]
         values._tree_memo = tm
         values._memos_owned = True
     gs = _DIRTY_GROUP_SHIFT
     gsize = 1 << gs
-    if 32 * n < len(chunks):
-        del chunks[32 * n :]
-    htr = elem.hash_tree_root
-    sticky = set()
+    tree.truncate(n)
     # element precision, where every mark came through an element: the
     # other elements' roots are the ones the chunks already hold
     written = {}
     if values._dirty_elems is not None and len(chunks) == 32 * n:
         for i in values._dirty_elems:
             written.setdefault(i >> gs, []).append(i)
+    htr = elem.hash_tree_root
+    sticky = set()
+    path_rows = []
+    walked = 0
     for g in sorted(dg):
         start = g << gs
         if start >= n:
             continue
-        stop = min(n, start + gsize)
-        clean = True
         rows = written.get(g)
         if rows:
-            for i in rows:
-                v = list.__getitem__(values, i)
-                r = v.__dict__.get("_htr_cache")
-                if r is None:
-                    r = htr(v)
-                    if "_htr_cache" not in v.__dict__:
-                        clean = False  # as below
-                chunks[32 * i : 32 * i + 32] = r
-            seg = bytes(chunks[32 * start : 32 * stop])
-        else:
-            parts = []
-            for v in list.__getitem__(values, slice(start, stop)):
-                r = v.__dict__.get("_htr_cache")
-                if r is None:
-                    r = htr(v)
-                    if "_htr_cache" not in v.__dict__:
-                        # element refused caching (a mutable field value
-                        # can change without notifying): its group must
-                        # recompute on every walk until the value is
-                        # replaced
-                        clean = False
-                parts.append(r)
-            seg = b"".join(parts)
-            chunks[32 * start : 32 * stop] = seg
+            path_rows += rows
+            continue
+        clean = True
+        parts = []
+        for v in list.__getitem__(values, slice(start, min(n, start + gsize))):
+            r = v.__dict__.get("_htr_cache")
+            if r is None:
+                r = htr(v)
+                if "_htr_cache" not in v.__dict__:
+                    # element refused caching (a mutable field value
+                    # can change without notifying): its group must
+                    # recompute on every walk until the value is
+                    # replaced
+                    clean = False
+            parts.append(r)
+        tree.set_nodes(start, b"".join(parts))
+        walked += 1
         if not clean:
             sticky.add(g)
-        tree.set_node(g, merkleize_chunks(seg, limit=gsize))
-    tree.truncate((n + gsize - 1) >> gs)
+    if path_rows:
+        refused = _written_rows_roots(elem, values, path_rows, tree)
+        sticky.update(i >> gs for i in refused)
+        _SPLICE_PATH_ROWS.inc(len(path_rows))
+    if walked:
+        _SPLICE_GROUP_WALKS.inc(walked)
     root = tree.root()
     tm[3] = root
     values._dirty_groups = sticky
@@ -1328,7 +1366,9 @@ def _finish_container_walk(values, tkey, chunks, limit_elems, tm) -> bytes:
     """Full-walk tail for a scalar-leaf container list: serve the exact
     chunks-compare memo, group-diff against the retained chunks when a
     tree exists (the discovery path, now only reached after untracked
-    mutations), or build the dirty-group tree for future splices."""
+    mutations), or build the stored levels for future splices (the tree
+    copies the element roots into its level 0, which the memo's second
+    slot names too)."""
     gs = _DIRTY_GROUP_SHIFT
     gsize = 1 << gs
     if tm is not None and tm[1] == chunks:
@@ -1337,28 +1377,22 @@ def _finish_container_walk(values, tkey, chunks, limit_elems, tm) -> bytes:
     eligible = n_chunks > _DIRTY_TRACK_MIN_CHUNKS and limit_elems % gsize == 0
     bs = BYTES_PER_CHUNK << gs
     if tm is not None and tm[2] is not None and eligible:
-        old = tm[1]
         tree = tm[2] if values._memos_owned else tm[2].clone()
-        n_groups = (n_chunks + gsize - 1) >> gs
-        tree.truncate(n_groups)
-        for g in range(n_groups):
+        old = tree.levels[0]
+        tree.truncate(n_chunks)
+        for g in range((n_chunks + gsize - 1) >> gs):
             seg = chunks[g * bs : (g + 1) * bs]
-            if bytes(old[g * bs : (g + 1) * bs]) != seg:
-                tree.set_node(g, merkleize_chunks(seg, limit=gsize))
-        root = tree.root()
-        values._tree_memo = [tkey, bytearray(chunks), tree, root]
+            if old[g * bs : (g + 1) * bs] != seg:
+                tree.set_nodes(g << gs, seg)
+    elif eligible:
+        tree = IncrementalPaddedTree(chunks, limit_elems)
+    else:
+        root = merkleize_chunks(chunks, limit=limit_elems)
+        values._tree_memo = [tkey, chunks, None, root]
         values._memos_owned = True
         return root
-    if eligible:
-        tree = IncrementalPaddedTree(
-            _group_mids(chunks), limit_elems >> gs, level_offset=gs
-        )
-        root = tree.root()
-        values._tree_memo = [tkey, bytearray(chunks), tree, root]
-        values._memos_owned = True
-        return root
-    root = merkleize_chunks(chunks, limit=limit_elems)
-    values._tree_memo = [tkey, chunks, None, root]
+    root = tree.root()
+    values._tree_memo = [tkey, tree.levels[0], tree, root]
     values._memos_owned = True
     return root
 
@@ -1676,9 +1710,9 @@ def _merkleize_homogeneous(elem: SSZType, values: list, limit_elems: int) -> byt
     tkey = ("tree", elem, limit_elems)
     tm = None
     if freshable:
-        # dirty-group splice: the mutators and the element setattr chain
-        # have named exactly which 4096-leaf groups changed — re-merkleize
-        # those plus the log-depth path, no registry walk
+        # incremental splice: the element setattr chain has named the
+        # rows that changed and the mutators the 4096-leaf groups — re-hash
+        # those rows' paths (those groups), no registry walk
         hit = _tree_splice(elem, values, tkey)
         if hit is not None:
             return hit

@@ -301,13 +301,15 @@ class IncrementalPaddedTree:
     node the root of a depth-``level_offset`` subtree, zero-padded to a
     virtual width of ``limit`` nodes.
 
-    This is the TOP HALF of the two-level incremental hash_tree_root
-    scheme (ssz/core.py): level-0 nodes are 4096-leaf group roots, and a
-    single-group edit costs exactly the log-depth path to the root —
-    ``set_node`` marks, ``root()`` recomputes only marked paths. Levels
-    store the populated region only; sparse padding uses the zero-subtree
-    table, so a List[..., 2**40] bound adds ~28 cheap path hashes, never
-    width.
+    The substrate of the incremental hash_tree_root scheme (ssz/core.py).
+    A packed collection keeps 4096-chunk group roots at level 0
+    (``level_offset`` 12); a list of scalar-leaf containers keeps the
+    element roots themselves (``level_offset`` 0), so one written row
+    costs its path and nothing beside it. ``set_node``/``set_nodes``
+    mark, ``root()`` recomputes only marked paths, one ``hash_level``
+    call a level. Levels store the populated region only; sparse padding
+    uses the zero-subtree table, so a List[..., 2**40] bound adds ~28
+    cheap path hashes, never width.
     """
 
     __slots__ = ("depth", "level_offset", "levels", "_dirty", "_root")
@@ -317,7 +319,9 @@ class IncrementalPaddedTree:
         self.depth = (width - 1).bit_length()
         self.level_offset = level_offset
         self.levels: list[bytearray] = [bytearray(nodes)]
-        self._dirty: set[int] | None = None  # None => full (re)build pending
+        # level-0 marks since the last root(): ints and ranges, in any
+        # order and with repeats. None => full (re)build pending
+        self._dirty: "list | None" = None
         self._root: bytes | None = None
 
     def clone(self) -> "IncrementalPaddedTree":
@@ -325,7 +329,7 @@ class IncrementalPaddedTree:
         new.depth = self.depth
         new.level_offset = self.level_offset
         new.levels = [bytearray(level) for level in self.levels]
-        new._dirty = set(self._dirty) if self._dirty is not None else None
+        new._dirty = list(self._dirty) if self._dirty is not None else None
         new._root = self._root
         return new
 
@@ -343,22 +347,52 @@ class IncrementalPaddedTree:
         else:
             raise IndexError(f"node {index} beyond populated width {n}")
         if self._dirty is not None:
-            self._dirty.add(index)
+            self._dirty.append(index)
+
+    def set_nodes(self, start: int, nodes: bytes) -> None:
+        """Replace a run of level-0 nodes from ``start`` on; the run may
+        reach past ``node_count()`` (and start at it), never leave a gap."""
+        level0 = self.levels[0]
+        if 32 * start > len(level0):
+            raise IndexError(
+                f"node {start} beyond populated width {len(level0) // 32}"
+            )
+        level0[32 * start : 32 * start + len(nodes)] = nodes
+        if self._dirty is not None:
+            self._dirty.append(range(start, start + len(nodes) // 32))
 
     def truncate(self, count: int) -> None:
-        """Drop level-0 nodes beyond ``count`` (shrink is rare enough that
-        it schedules a full level rebuild rather than path surgery)."""
-        level0 = self.levels[0]
-        if len(level0) // 32 > count:
-            del level0[32 * count :]
+        """Drop level-0 nodes beyond ``count``: every level is cut to the
+        nodes that still cover something, and the last survivor's path is
+        marked (its siblings turned into padding)."""
+        if len(self.levels[0]) // 32 <= count:
+            return
+        if self._dirty is None or count == 0:
+            del self.levels[0][32 * count :]
             self._dirty = None
+            return
+        keep = count
+        for level in self.levels:
+            del level[32 * keep :]
+            keep = (keep + 1) // 2
+        marks = []
+        for m in self._dirty:
+            if m.__class__ is range:
+                m = range(m.start, min(m.stop, count))
+                if not m:
+                    continue
+            elif m >= count:
+                continue
+            marks.append(m)
+        marks.append(count - 1)
+        self._dirty = marks
 
     def root(self) -> bytes:
         if self._dirty is None:
             self._rebuild()
         elif self._dirty:
             self._update_paths()
-        self._dirty = set()
+        self._dirty = []
         return self._root  # type: ignore[return-value]
 
     def _rebuild(self) -> None:
@@ -375,22 +409,80 @@ class IncrementalPaddedTree:
         )
 
     def _update_paths(self) -> None:
-        indices = self._dirty
+        """Re-hash the marked nodes' paths a level at a time: each level's
+        dirty parents are gathered into one buffer and hashed by one
+        ``hash_level`` call, every sibling read from the stored levels.
+        Where a level has fewer dirty parents than the native hasher
+        takes (the upper levels always; a block's few written rows all the
+        way) they are hashed pair by pair, without the gather."""
+        import numpy as np
+
+        marks = self._dirty
+        few = _hash_mod.NATIVE_MIN_NODES
+        idx = None
+        runs = [m for m in marks if m.__class__ is range]
+        if runs or len(marks) >= few:
+            parts = [np.arange(r.start, r.stop, dtype=np.int64) for r in runs]
+            if len(runs) < len(marks):
+                parts.append(
+                    np.array(
+                        [m for m in marks if m.__class__ is not range],
+                        dtype=np.int64,
+                    )
+                )
+            idx = parts[0] if len(parts) == 1 else np.concatenate(parts)
+        below = marks  # the level's dirty nodes, while idx is None
         for d in range(self.depth):
+            pad = zero_hash(self.level_offset + d)
             cur = self.levels[d]
-            n = len(cur) // 32
             nxt = self.levels[d + 1]
-            parents = {i >> 1 for i in indices}
-            for j in sorted(parents):
+            if idx is not None:
+                idx = np.unique(idx >> 1)
+                if idx.shape[0] >= few:
+                    _scatter_nodes(nxt, idx, _hash_parents(cur, idx, pad))
+                    continue
+                parents, idx = idx.tolist(), None
+            else:
+                parents = sorted({i >> 1 for i in below})
+            n = len(cur) // 32
+            for j in parents:
                 left = bytes(cur[64 * j : 64 * j + 32])
                 if 2 * j + 1 < n:
                     right = bytes(cur[64 * j + 32 : 64 * j + 64])
                 else:
-                    right = zero_hash(self.level_offset + d)
-                parent = hash_pair(left, right)
-                if 32 * j == len(nxt):
-                    nxt += parent
-                else:
-                    nxt[32 * j : 32 * (j + 1)] = parent
-            indices = parents
+                    right = pad
+                nxt[32 * j : 32 * j + 32] = hash_pair(left, right)
+            below = parents
         self._root = bytes(self.levels[-1][:32])
+
+
+def _hash_parents(level: bytearray, parents, pad: bytes) -> bytes:
+    """The digests of ``level``'s node pairs ``(2j, 2j + 1)`` for the
+    sorted parent indices ``parents``, in one ``hash_level`` call; a right
+    sibling past the populated width (the last parent's alone can be) is
+    ``pad``."""
+    import numpy as np
+
+    n = len(level) // 32
+    nodes = np.frombuffer(level, dtype=np.uint8).reshape(n, 32)
+    pairs = np.empty((parents.shape[0], 2, 32), dtype=np.uint8)
+    left = parents << 1
+    pairs[:, 0] = nodes[left]
+    if int(left[-1]) + 1 < n:
+        pairs[:, 1] = nodes[left + 1]
+    else:
+        pairs[:-1, 1] = nodes[left[:-1] + 1]
+        pairs[-1, 1] = np.frombuffer(pad, dtype=np.uint8)
+    return hash_level(pairs.tobytes())
+
+
+def _scatter_nodes(level: bytearray, indices, nodes: bytes) -> None:
+    """Store ``nodes`` at the sorted ``indices`` of ``level``, growing it
+    to hold the last (a grown level's new nodes are all among them)."""
+    import numpy as np
+
+    need = 32 * (int(indices[-1]) + 1)
+    if need > len(level):
+        level.extend(bytes(need - len(level)))
+    view = np.frombuffer(level, dtype=np.uint8).reshape(-1, 32)
+    view[indices] = np.frombuffer(nodes, dtype=np.uint8).reshape(-1, 32)

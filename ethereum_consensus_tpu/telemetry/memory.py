@@ -499,8 +499,12 @@ def _ssz_census() -> dict:
             add("ssz.pack_tree", pt[2], _tree_bytes(pt[2]))
         tm = getattr(lst, "_tree_memo", None)
         if isinstance(tm, (list, tuple)) and len(tm) >= 3:
-            add("ssz.tree_memo", tm[1])
-            add("ssz.tree_memo", tm[2], _tree_bytes(tm[2]))
+            if tm[2] is None:
+                add("ssz.tree_memo", tm[1])
+            else:
+                # the element roots are the tree's level 0: counted there,
+                # with every stored level above them
+                add("ssz.tree_memo", tm[2], _tree_bytes(tm[2]))
         pm = getattr(lst, "_pack_memo", None)
         if isinstance(pm, tuple):
             for part in pm[1:]:

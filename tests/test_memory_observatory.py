@@ -90,6 +90,36 @@ def test_census_bitpack_owner_exact(observatory):
     assert row["entries"] == 1
 
 
+def test_census_tree_memo_counts_every_stored_level_once(observatory):
+    """A container list's memo names its element roots twice (the memo's
+    chunks ARE the tree's level 0): the census counts each stored level
+    once, and a copy that shares the memo adds nothing."""
+    class Row(ssz_core.Container):
+        a: ssz_core.uint64
+        b: ssz_core.uint64
+
+    n = 4096 + 40
+    LT = ssz_core.List[Row, 1 << 20]
+    rows = ssz_core.CachedRootList(Row(a=i, b=i) for i in range(n))
+    LT.hash_tree_root(rows)
+    tm = rows._tree_memo
+    assert tm[1] is tm[2].levels[0] and len(tm[1]) == 32 * n
+    want, width = 0, n
+    for _ in range(21):  # levels 0..20 of a 2^20 limit, populated region
+        want += 32 * width
+        width = (width + 1) // 2
+    row = observatory.census()["ssz.tree_memo"]
+    assert row == {"bytes": want, "entries": 1}
+    twin = ssz_core._copy_value(LT, rows)
+    assert twin._tree_memo is tm
+    assert observatory.census()["ssz.tree_memo"] == row
+    twin[7].a = 9  # the first splice clones: now two trees
+    LT.hash_tree_root(twin)
+    assert observatory.census()["ssz.tree_memo"] == {
+        "bytes": 2 * want, "entries": 2,
+    }
+
+
 def test_census_snapshot_owner_exact(observatory):
     """A HeadStore snapshot's frozen column bundle censuses at exactly
     the sum of its (deduped) array nbytes."""

@@ -25,6 +25,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from ethereum_consensus_tpu.ssz import core as ssz_core
 from ethereum_consensus_tpu.ssz import hash as ssz_hash
+from ethereum_consensus_tpu.telemetry import metrics
 from ethereum_consensus_tpu.ssz.core import (
     ByteVector,
     CachedRootList,
@@ -85,8 +86,8 @@ def _naive_u64_list_root(values, limit: int) -> bytes:
 
 def test_digest_count_single_container_edit():
     """One field write on one element of an 8192-element scalar-leaf
-    container list re-merkleizes ≤ one 4096-leaf group + the log-depth
-    path — never the whole collection (the registry-walk bound)."""
+    container list costs the element's own root and its path through the
+    stored levels: never its 4096-leaf group, let alone the collection."""
     LT = List[Val, 1 << 40]
     values = CachedRootList(
         Val(a=i, b=i.to_bytes(4, "little") * 8) for i in range(8192)
@@ -100,12 +101,17 @@ def test_digest_count_single_container_edit():
     assert ssz_hash.digest_count() - before <= 2  # length mix-in only
 
     before = ssz_hash.digest_count()
+    base = metrics.snapshot()
     values[5000].a = 10**15
     root = LT.hash_tree_root(values)
     delta = ssz_hash.digest_count() - before
-    # one 4096-leaf group (4095) + tree path (28 for limit 2^40) + the
-    # element's own root + the length mix-in
-    assert delta <= 4096 + 40, f"single edit cost {delta} digests"
+    # the element's own root (1 for Val; 8 for a Validator) + its path
+    # (12 levels to its group's root, 28 more for limit 2^40) + the
+    # length mix-in, and a small slack
+    assert delta <= 1 + 12 + 28 + 1 + 4, f"single edit cost {delta} digests"
+    moved = metrics.delta(base)
+    assert moved.get("ssz.tree_splice.path_rows") == 1
+    assert not moved.get("ssz.tree_splice.group_walks")
 
     # bit-identity of the spliced root vs a cold rebuild
     cold = CachedRootList(Val(a=v.a, b=v.b) for v in values)
@@ -165,10 +171,11 @@ def _cold_root(LT, values) -> bytes:
 
 def test_splice_rehashes_the_written_rows_not_their_groups():
     """A few scattered field writes dirty every group they fall in; the
-    splice re-hashes those rows and takes every other element's root from
-    the chunks it holds. Shown by an element it must not look at: a root
-    cache planted wrong, without notice, on an unwritten row of a dirty
-    group would come out in a whole-group walk."""
+    splice re-hashes those rows and their paths and reads every sibling
+    from the levels it holds: four paths, not four groups. Shown also by
+    an element it must not look at: a root cache planted wrong, without
+    notice, on an unwritten row of a dirty group would come out in a
+    whole-group walk."""
     LT, values = _armed_registry(3 * 4096 + 100)
     written = [7, 4096 + 9, 2 * 4096 + 11, 3 * 4096 + 50]
     for i in written:
@@ -178,8 +185,13 @@ def test_splice_rehashes_the_written_rows_not_their_groups():
     true_root = values[8].__dict__["_htr_cache"]
     values[8].__dict__["_htr_cache"] = b"\x11" * 32  # never read
     before = ssz_hash.digest_count()
+    base = metrics.snapshot()
     root = LT.hash_tree_root(values)
-    assert ssz_hash.digest_count() - before <= 4 * 4096 + 64
+    # four roots, four paths of 40 that meet under the list's root
+    assert ssz_hash.digest_count() - before <= 4 * (1 + 12) + 2 * 4 + 28 + 1 + 4
+    moved = metrics.delta(base)
+    assert moved.get("ssz.tree_splice.path_rows") == 4
+    assert not moved.get("ssz.tree_splice.group_walks")
     values[8].__dict__["_htr_cache"] = true_root
     assert root == _cold_root(LT, values)
     assert values._dirty_groups == set() and values._dirty_elems == set()
@@ -204,7 +216,12 @@ def test_a_mutation_through_the_list_falls_back_to_group_precision(through_the_l
         bulk_store(values, new, [4096 + 1])
     assert values._dirty_elems is None and values._dirty_groups
     values[20].a = 456  # and one after: marked by group
+    base = metrics.snapshot()
     assert LT.hash_tree_root(values) == _cold_root(LT, values)
+    moved = metrics.delta(base)
+    # the written rows' group and the stored element's, each walked whole
+    assert moved.get("ssz.tree_splice.group_walks") >= 2
+    assert not moved.get("ssz.tree_splice.path_rows")
     assert values._dirty_elems == set()
     values[4096 + 1].a = 78  # the stored element is wired like the others
     assert values._dirty_elems == {4096 + 1}
@@ -226,6 +243,187 @@ def test_copies_carry_their_own_written_rows():
     for side in (reg, twin):
         cold = Reg(vals=[Val(a=v.a, b=v.b) for v in side.vals])
         assert Reg.hash_tree_root(side) == Reg.hash_tree_root(cold)
+
+
+def _write(values, rows, salt=0):
+    for i in rows:
+        values[i].a = 10**12 + 7 * i + salt
+
+
+def _scattered_in_every_group(LT, values):
+    _write(values, range(11, len(values), 1000))
+    return {"path_rows": len(range(11, len(values), 1000)), "group_walks": 0}
+
+
+def _dense_in_one_group(LT, values):
+    _write(values, range(4096 + 100, 4096 + 620))
+    return {"path_rows": 520, "group_walks": 0}
+
+
+def _in_a_partial_last_group(LT, values):
+    n = len(values)
+    assert n % 4096
+    _write(values, (n - 1, n - 2, n - 37, (n >> 12 << 12)))
+    return {"path_rows": 4, "group_walks": 0}
+
+
+def _append_pop_truncate_between_roots(LT, values):
+    for k in range(3):
+        values.append(Val(a=k, b=bytes([k]) * 32))
+    _write(values, (5, 9000))
+    assert LT.hash_tree_root(values) == _cold_root(LT, values)
+    values.pop()
+    values.pop()
+    _write(values, (6,))
+    assert LT.hash_tree_root(values) == _cold_root(LT, values)
+    _write(values, (7, len(values) - 1))
+    assert LT.hash_tree_root(values) == _cold_root(LT, values)
+    del values[2 * 4096 + 17 :]  # tracking lost: the discovery walk
+    assert LT.hash_tree_root(values) == _cold_root(LT, values)
+    for k in range(4096):  # over a group's edge, and over a level's width
+        values.append(Val(a=k, b=bytes([k % 251]) * 32))
+    _write(values, (8,))
+    return {}
+
+
+def _a_group_mark_among_element_marks(LT, values):
+    _write(values, (3, 4096 + 3))
+    values[2 * 4096 + 1 : 2 * 4096 + 3] = [
+        Val(a=1, b=b"\x01" * 32), Val(a=2, b=b"\x02" * 32)
+    ]
+    _write(values, (2 * 4096 + 2, 3 * 4096 + 1), salt=1)
+    # known by group from the slice store on: groups 0..3, each whole
+    return {"path_rows": 0, "group_walks": 4}
+
+
+def _an_element_that_refuses_caching(LT, values):
+    values[4096 + 5].b = bytearray(b"\x05" * 32)  # can change without notice
+    _write(values, (9,))
+    assert LT.hash_tree_root(values) == _cold_root(LT, values)
+    assert values._dirty_groups == {1} and values._dirty_elems is None
+    values[4096 + 5].b[0] = 0x77  # and does
+    _write(values, (2 * 4096 + 1,))
+    # the sticky group is walked whole at every root, the other by group
+    return {"path_rows": 0, "group_walks": 2}
+
+
+@pytest.mark.parametrize(
+    "scenario",
+    [
+        _scattered_in_every_group,
+        _dense_in_one_group,
+        _in_a_partial_last_group,
+        _append_pop_truncate_between_roots,
+        _a_group_mark_among_element_marks,
+        _an_element_that_refuses_caching,
+    ],
+    ids=lambda f: f.__name__.strip("_"),
+)
+def test_spliced_root_is_the_cold_root(scenario):
+    """Whatever marked the list, by element or by group, the spliced root
+    is the cold ``hash_tree_root`` of a fresh list of the same values,
+    and the route taken is the one the marks name."""
+    LT, values = _armed_registry(3 * 4096 + 100)
+    expected = scenario(LT, values)
+    base = metrics.snapshot()
+    root = LT.hash_tree_root(values)
+    moved = metrics.delta(base)
+    assert root == _cold_root(LT, values)
+    for name, count in expected.items():
+        assert moved.get("ssz.tree_splice." + name, 0) == count, (name, moved)
+    assert LT.hash_tree_root(values) == root  # and it stands
+
+
+def test_copy_siblings_written_in_turn_do_not_move_each_other():
+    """Two copies share the stored levels until one writes; each write
+    clones, so neither root moves with the other's rows."""
+    class Reg(Container):
+        vals: List[Val, 1 << 40]
+
+    reg = Reg(vals=[Val(a=i, b=i.to_bytes(4, "little") * 8) for i in range(8200)])
+    Reg.hash_tree_root(reg)
+    twin = reg.copy()
+    assert twin.vals._tree_memo is reg.vals._tree_memo
+
+    def cold(side):
+        return Reg.hash_tree_root(Reg(vals=[Val(a=v.a, b=v.b) for v in side.vals]))
+
+    untouched = Reg.hash_tree_root(reg)
+    _write(twin.vals, (5, 5000))
+    twin_root = Reg.hash_tree_root(twin)
+    assert twin_root == cold(twin) != untouched
+    assert twin.vals._tree_memo[2] is not reg.vals._tree_memo[2]
+    assert Reg.hash_tree_root(reg) == untouched == cold(reg)
+    _write(reg.vals, (5, 8100), salt=3)
+    reg_root = Reg.hash_tree_root(reg)
+    assert reg_root == cold(reg) and reg_root not in (untouched, twin_root)
+    assert Reg.hash_tree_root(twin) == twin_root == cold(twin)
+    third = twin.copy()  # a copy of a written copy, written in its turn
+    _write(third.vals, (8199,), salt=5)
+    twin.vals.append(Val(a=1, b=b"\x09" * 32))
+    assert Reg.hash_tree_root(third) == cold(third)
+    assert Reg.hash_tree_root(twin) == cold(twin)
+    assert Reg.hash_tree_root(reg) == reg_root
+
+
+@pytest.mark.parametrize("offset", [0, 3])
+@pytest.mark.parametrize("limit", [64, 1 << 20])
+def test_stored_levels_follow_any_run_of_marks(limit, offset):
+    """``IncrementalPaddedTree``: single marks, runs, growth a node and a
+    run at a time, truncation to any width and to nothing, each root the
+    plain merkleization of the nodes it holds (batched levels above the
+    native hasher's width, pair by pair under it)."""
+    from ethereum_consensus_tpu.ssz.merkle import (
+        IncrementalPaddedTree,
+        merkleize_chunks,
+    )
+
+    rng = random.Random(99 + limit + offset)
+    nodes = [rng.randbytes(32) for _ in range(37)]
+    tree = IncrementalPaddedTree(b"".join(nodes), limit, level_offset=offset)
+
+    def check():
+        want = merkleize_chunks(b"".join(nodes), limit=limit, level_offset=offset)
+        assert tree.root() == want
+        assert tree.node_count() == len(nodes)
+        keep = len(nodes)
+        for level in tree.levels:  # the populated region, and no more
+            assert len(level) == 32 * keep
+            keep = (keep + 1) // 2
+
+    check()
+    for step in range(60):
+        op = rng.randrange(6)
+        n = len(nodes)
+        if op == 0 and n:  # a few single marks
+            for i in rng.sample(range(n), min(n, rng.choice((1, 3, 20)))):
+                nodes[i] = rng.randbytes(32)
+                tree.set_node(i, nodes[i])
+        elif op == 1 and n < limit:  # grow by one
+            nodes.append(rng.randbytes(32))
+            tree.set_node(n, nodes[-1])
+        elif op == 2 and n:  # a run inside, or over the end
+            start = rng.randrange(n + 1)
+            run = [rng.randbytes(32) for _ in range(rng.choice((1, 2, 9, 24)))]
+            run = run[: limit - start]
+            nodes[start : start + len(run)] = run
+            tree.set_nodes(start, b"".join(run))
+        elif op == 3 and n:  # cut, with marks pending on both sides of it
+            i = rng.randrange(n)
+            nodes[i] = rng.randbytes(32)
+            tree.set_node(i, nodes[i])
+            keep = rng.randrange(n + 1)
+            del nodes[keep:]
+            tree.truncate(keep)
+        elif op == 4 and n:  # a clone goes its own way
+            twin = tree.clone()
+            twin.set_node(0, b"\xee" * 32)
+            twin.root()
+        if step % 3 == 0:
+            check()
+    check()
+    with pytest.raises(IndexError):
+        tree.set_nodes(len(nodes) + 1, b"\x00" * 32)
 
 
 # ---------------------------------------------------------------------------
