@@ -11,6 +11,7 @@ from __future__ import annotations
 from enum import Enum
 
 from ..error import Error, InvalidStateRoot, StateTransitionError, checked_add
+from ..ssz.core import scope_next_root
 from ..utils import trace
 from .phase0.containers import BeaconBlockHeader
 from .phase0.helpers import verify_block_signature
@@ -58,6 +59,7 @@ def process_slots_generic(state, slot: int, context, process_epoch) -> None:
             if (state.slot + 1) % context.SLOTS_PER_EPOCH == 0:
                 with trace.span("transition.process_epoch", slot=int(state.slot)):
                     process_epoch(state, context)
+                scope_next_root(state, "transition.epoch_root")
             state.slot = checked_add(state.slot, 1)
 
 

@@ -21,6 +21,7 @@ from typing import Any
 
 from ..telemetry import memory as _memory
 from ..telemetry import metrics as _metrics
+from ..utils import trace as _trace
 from . import hash as _hash_mod
 from .hash import hash_level
 from .merkle import (
@@ -979,10 +980,22 @@ def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
         esize = BYTES_PER_CHUNK
     else:
         return None
+    if not dg:
+        return pt[3] if len(pt[1]) == len(values) * esize else None
+    with _trace.span("ssz.packed_splice", groups=len(dg)):
+        return _splice_dirty_groups(values, key, esize, pt, dg)
+
+
+def _memo_clone_bytes(tree, raw=b"") -> int:
+    """What a copy's first splice clones: the stored levels (and the
+    retained raw buffer of a packed list)."""
+    return len(raw) + sum(len(level) for level in tree.levels)
+
+
+def _splice_dirty_groups(values, key, esize: int, pt, dg) -> "bytes | None":
+    """_packed_splice's work once a group is dirty."""
     n = len(values)
     raw, tree, root = pt[1], pt[2], pt[3]
-    if not dg:
-        return root if len(raw) == n * esize else None
     gs = _DIRTY_GROUP_SHIFT
     gsize = 1 << gs
     # write-direction shortcut (_clean_wire_column): dirty groups
@@ -993,8 +1006,6 @@ def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
     # serialize every dirty range BEFORE touching the memo, with the same
     # strictness as serialize(): a non-conforming value sends the whole
     # walk to the fallback path and its structured errors
-    _obs = _memory.OBSERVATORY
-    _t0 = _time.perf_counter() if _obs.active else 0.0
     segs = []
     try:
         for g in sorted(dg):
@@ -1023,9 +1034,12 @@ def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
             segs.append((start, stop, seg))
     except (OverflowError, TypeError, ValueError):
         return None
+    nbytes = sum(len(seg) for _start, _stop, seg in segs)
+    _trace.note(bytes=nbytes)
     if not values._memos_owned:
-        raw = bytearray(raw)
-        tree = tree.clone()
+        with _trace.span("ssz.memo_clone", bytes=_memo_clone_bytes(tree, raw)):
+            raw = bytearray(raw)
+            tree = tree.clone()
         pt = [key, raw, tree, root]
         values._pack_tree = pt
         values._memos_owned = True
@@ -1052,15 +1066,11 @@ def _packed_splice(elem, values, key, limit_chunks: int) -> "bytes | None":
     root = tree.root()
     pt[3] = root
     values._dirty_groups = set()
+    _obs = _memory.OBSERVATORY
     if _obs.active:
         # bandwidth: exactly the bytes re-serialized into the retained
-        # raw buffer (the dirty groups), timed over the whole splice
-        _obs.record_copy(
-            "ssz.packed_splice",
-            sum(len(seg) for _start, _stop, seg in segs),
-            _t0,
-            _time.perf_counter(),
-        )
+        # raw buffer (the dirty groups); the seconds are the span's
+        _obs.record_copy("ssz.packed_splice", nbytes)
     return root
 
 
@@ -1299,12 +1309,19 @@ def _tree_splice(elem, values, tkey) -> "bytes | None":
     dg = values._dirty_groups
     if tm is None or dg is None or tm[0] != tkey or tm[2] is None:
         return None
+    if not dg:
+        return tm[3] if len(tm[1]) == 32 * len(values) else None
+    with _trace.span("ssz.tree_splice"):
+        return _splice_dirty_rows(elem, values, tkey, tm, dg)
+
+
+def _splice_dirty_rows(elem, values, tkey, tm, dg) -> bytes:
+    """_tree_splice's work once a group is dirty."""
     chunks, tree, root = tm[1], tm[2], tm[3]
     n = len(values)
-    if not dg:
-        return root if len(chunks) == 32 * n else None
     if not values._memos_owned:
-        tree = tree.clone()
+        with _trace.span("ssz.memo_clone", bytes=_memo_clone_bytes(tree)):
+            tree = tree.clone()
         chunks = tree.levels[0]
         tm = [tkey, chunks, tree, root]
         values._tree_memo = tm
@@ -1353,6 +1370,7 @@ def _tree_splice(elem, values, tkey) -> "bytes | None":
         _SPLICE_PATH_ROWS.inc(len(path_rows))
     if walked:
         _SPLICE_GROUP_WALKS.inc(walked)
+    _trace.note(path_rows=len(path_rows), group_walks=walked)
     root = tree.root()
     tm[3] = root
     values._dirty_groups = sticky
@@ -1579,6 +1597,111 @@ def _bulk_store_impl(values, new_values, changed_indices=None) -> None:
                 cd.update(idxs)
 
 
+def _full_pack_basic(elem, values, key, limit: int, col_arr) -> bytes:
+    """A packed basic-type collection merkleized from its whole content:
+    off a clean wire-width column where the list holds one, else off its
+    ints (or, for anything the vectorized pack refuses, serialize())."""
+    all_int = (
+        col_arr is not None
+        or getattr(values, "_uniform_kind", None) == ("int",)
+    )
+    if not all_int and values and set(map(type, values)) == {int}:
+        all_int = True  # C-speed scan; keeps serialize()'s
+        # bool/float rejections out of the numpy path
+    if all_int and isinstance(values, CachedRootList):
+        values._uniform_kind = ("int",)  # mutators maintain it
+    if (
+        isinstance(elem, _UintType)
+        and elem.byte_length in (1, 2, 4, 8)
+        and all_int
+    ):
+        # vectorized uint packing (u64 balances/inactivity lists and
+        # the u8 participation flags dominate — the per-element
+        # serialize of a 131k-flag list was the hot line of altair+
+        # block walks). Convert through u64 FIRST and range-check the
+        # width explicitly: a direct sub-word asarray silently WRAPS
+        # out-of-range ints on numpy<2 (the same hazard the columnar
+        # bulk path guards with its shift check), whereas u64
+        # conversion raises OverflowError for >=2^64 on every numpy
+        # and the shift catches everything else; the little-endian
+        # astype matches serialize().
+        size = elem.byte_length
+        if col_arr is not None:
+            raw = col_arr.astype("<u%d" % size, copy=False).tobytes()
+            _PACK_FROM_COLUMN.inc()
+            _PACK_FROM_COLUMN_BYTES.inc(len(raw))
+        else:
+            try:
+                import numpy as _np
+
+                col = _np.asarray(values, dtype="<u8")
+                if size < 8 and bool((col >> (8 * size)).any()):
+                    raise OverflowError  # out of range for the width
+                raw = col.astype("<u%d" % size).tobytes()
+            except (OverflowError, TypeError, ValueError):
+                raw = b"".join(elem.serialize(v) for v in values)
+        _obs = _memory.OBSERVATORY
+        if _obs.active:
+            # bandwidth: the full wire-width column materialization
+            # (a whole-collection re-pack — the cost _packed_splice
+            # exists to avoid; seeing this site grow per walk IS the
+            # signal a memo stopped engaging); the seconds are the
+            # ssz.full_pack span's
+            _obs.record_copy("ssz.column_serialize", len(raw))
+    else:
+        raw = b"".join(elem.serialize(v) for v in values)
+    return _merkleize_packed_memo(values, key, pack_bytes(raw), limit, raw=raw)
+
+
+def _full_pack_b32(values, b32_key, limit_elems: int) -> "bytes | None":
+    """A collection of 32-byte vectors merkleized from its whole content,
+    or None where an element does not conform (the caller's per-element
+    path then raises the structured error)."""
+    # a 32-byte vector's root IS its bytes — and the validation runs
+    # at C speed (join rejects non-bytes with TypeError; the len-set
+    # check rejects any element that isn't exactly 32 bytes), because
+    # a per-element Python genexpr over block_roots/state_roots/
+    # randao_mixes (tens of thousands of elements on a mainnet
+    # state) was the single hottest line of block processing.
+    # Anything non-conforming falls to the per-element path and its
+    # structured errors.
+    # both scans run at C speed and are BOTH required: the len-set
+    # rejects any element that isn't exactly 32 long (a 31+33 pair
+    # would fool a total-length check alone), while the joined byte
+    # length rejects sized buffer objects whose len() isn't their
+    # byte size (array.array('I', …)/memoryview of wider items would
+    # fool the len-set alone)
+    if getattr(values, "_uniform_kind", None) == ("bytes", BYTES_PER_CHUNK):
+        sizes_ok = True  # full scan done once; mutators maintain it
+    else:
+        try:
+            sizes_ok = not values or set(map(len, values)) == {BYTES_PER_CHUNK}
+        except TypeError:  # un-sized element (e.g. int)
+            sizes_ok = False
+    if sizes_ok:
+        try:
+            chunks = b"".join(values)
+        except TypeError:  # sized but not bytes-like (e.g. str)
+            chunks = None
+        if chunks is not None and len(chunks) == BYTES_PER_CHUNK * len(
+            values
+        ):
+            if (
+                values
+                and isinstance(values, CachedRootList)
+                and values._uniform_kind is None
+                and all(type(v) is bytes for v in values)
+            ):
+                # the flag asserts type-is-bytes too (a bytearray
+                # joins fine but can mutate in place), so it is only
+                # set after one full type scan; mutators keep it
+                values._uniform_kind = ("bytes", BYTES_PER_CHUNK)
+            return _merkleize_packed_memo(
+                values, b32_key, chunks, limit_elems, raw=chunks
+            )
+    return None
+
+
 def _merkleize_homogeneous(elem: SSZType, values: list, limit_elems: int) -> bytes:
     if _is_basic(elem):
         limit = (
@@ -1599,74 +1722,15 @@ def _merkleize_homogeneous(elem: SSZType, values: list, limit_elems: int) -> byt
         col_arr = None
         if isinstance(values, CachedRootList) and isinstance(elem, _UintType):
             col_arr = _clean_wire_column(values, elem.byte_length)
-        all_int = (
-            col_arr is not None
-            or getattr(values, "_uniform_kind", None) == ("int",)
-        )
-        if not all_int and values and set(map(type, values)) == {int}:
-            all_int = True  # C-speed scan; keeps serialize()'s
-            # bool/float rejections out of the numpy path
-        if all_int and isinstance(values, CachedRootList):
-            values._uniform_kind = ("int",)  # mutators maintain it
-        if (
-            isinstance(elem, _UintType)
-            and elem.byte_length in (1, 2, 4, 8)
-            and all_int
+        with _trace.span(
+            "ssz.full_pack",
+            chunks=(len(values) * elem.fixed_size() + BYTES_PER_CHUNK - 1)
+            // BYTES_PER_CHUNK,
+            from_column=col_arr is not None,
         ):
-            # vectorized uint packing (u64 balances/inactivity lists and
-            # the u8 participation flags dominate — the per-element
-            # serialize of a 131k-flag list was the hot line of altair+
-            # block walks). Convert through u64 FIRST and range-check the
-            # width explicitly: a direct sub-word asarray silently WRAPS
-            # out-of-range ints on numpy<2 (the same hazard the columnar
-            # bulk path guards with its shift check), whereas u64
-            # conversion raises OverflowError for >=2^64 on every numpy
-            # and the shift catches everything else; the little-endian
-            # astype matches serialize().
-            _obs = _memory.OBSERVATORY
-            _t0 = _time.perf_counter() if _obs.active else 0.0
-            size = elem.byte_length
-            if col_arr is not None:
-                raw = col_arr.astype("<u%d" % size, copy=False).tobytes()
-                _PACK_FROM_COLUMN.inc()
-                _PACK_FROM_COLUMN_BYTES.inc(len(raw))
-            else:
-                try:
-                    import numpy as _np
-
-                    col = _np.asarray(values, dtype="<u8")
-                    if size < 8 and bool((col >> (8 * size)).any()):
-                        raise OverflowError  # out of range for the width
-                    raw = col.astype("<u%d" % size).tobytes()
-                except (OverflowError, TypeError, ValueError):
-                    raw = b"".join(elem.serialize(v) for v in values)
-            if _obs.active:
-                # bandwidth: the full wire-width column materialization
-                # (a whole-collection re-pack — the cost _packed_splice
-                # exists to avoid; seeing this site grow per walk IS the
-                # signal a memo stopped engaging)
-                _obs.record_copy(
-                    "ssz.column_serialize", len(raw), _t0,
-                    _time.perf_counter(),
-                )
-        else:
-            raw = b"".join(elem.serialize(v) for v in values)
-        return _merkleize_packed_memo(values, key, pack_bytes(raw), limit, raw=raw)
+            return _full_pack_basic(elem, values, key, limit, col_arr)
     if isinstance(elem, ByteVector) and elem.length == BYTES_PER_CHUNK:
-        # a 32-byte vector's root IS its bytes — and the validation runs
-        # at C speed (join rejects non-bytes with TypeError; the len-set
-        # check rejects any element that isn't exactly 32 bytes), because
-        # a per-element Python genexpr over block_roots/state_roots/
-        # randao_mixes (tens of thousands of elements on a mainnet
-        # state) was the single hottest line of block processing.
-        # Anything non-conforming falls to the per-element path and its
-        # structured errors.
-        # both scans run at C speed and are BOTH required: the len-set
-        # rejects any element that isn't exactly 32 long (a 31+33 pair
-        # would fool a total-length check alone), while the joined byte
-        # length rejects sized buffer objects whose len() isn't their
-        # byte size (array.array('I', …)/memoryview of wider items would
-        # fool the len-set alone)
+        # a 32-byte vector's root IS its bytes (see _full_pack_b32)
         b32_key = ("b32", elem, limit_elems)
         if _pack_memo_gen_hit(values, b32_key):
             return values._pack_memo[2]
@@ -1674,34 +1738,10 @@ def _merkleize_homogeneous(elem: SSZType, values: list, limit_elems: int) -> byt
             hit = _packed_splice(elem, values, b32_key, limit_elems)
             if hit is not None:
                 return hit
-        if getattr(values, "_uniform_kind", None) == ("bytes", BYTES_PER_CHUNK):
-            sizes_ok = True  # full scan done once; mutators maintain it
-        else:
-            try:
-                sizes_ok = not values or set(map(len, values)) == {BYTES_PER_CHUNK}
-            except TypeError:  # un-sized element (e.g. int)
-                sizes_ok = False
-        if sizes_ok:
-            try:
-                chunks = b"".join(values)
-            except TypeError:  # sized but not bytes-like (e.g. str)
-                chunks = None
-            if chunks is not None and len(chunks) == BYTES_PER_CHUNK * len(
-                values
-            ):
-                if (
-                    values
-                    and isinstance(values, CachedRootList)
-                    and values._uniform_kind is None
-                    and all(type(v) is bytes for v in values)
-                ):
-                    # the flag asserts type-is-bytes too (a bytearray
-                    # joins fine but can mutate in place), so it is only
-                    # set after one full type scan; mutators keep it
-                    values._uniform_kind = ("bytes", BYTES_PER_CHUNK)
-                return _merkleize_packed_memo(
-                    values, b32_key, chunks, limit_elems, raw=chunks
-                )
+        with _trace.span("ssz.full_pack", chunks=len(values), from_column=False):
+            root = _full_pack_b32(values, b32_key, limit_elems)
+        if root is not None:
+            return root
     freshable = (
         isinstance(values, CachedRootList)
         and isinstance(elem, type)
@@ -2248,6 +2288,29 @@ def _try_cache_nested_root(cls, value, root: bytes) -> None:
     d["_htr_cache"] = root
 
 
+# The instance key of a pending root scope (``scope_next_root``): like
+# ``_htr_cache`` it is no field, so it is never serialized or compared.
+_ROOT_SCOPE = "_root_scope"
+
+
+def scope_next_root(value: "Container", name: str) -> None:
+    """Make the next ``hash_tree_root`` of ``value`` (a container with a
+    list or container field: the check is skipped on scalar-leaf ones) a
+    counter scope ``name`` (utils/trace.scope), and count the SHA-256
+    compressions it does as ``<name>.digests``. The mark is taken by that
+    root and is not carried by ``copy()``."""
+    value.__dict__[_ROOT_SCOPE] = name
+
+
+def _scoped_root(cls, value: "Container") -> bytes:
+    name = value.__dict__.pop(_ROOT_SCOPE)
+    before = _hash_mod.digest_count()
+    with _trace.scope(name):
+        root = cls.hash_tree_root(value)
+    _metrics.counter(name + ".digests").inc(_hash_mod.digest_count() - before)
+    return root
+
+
 class Container(metaclass=_ContainerMeta):
     """SSZ container. Declare fields as class annotations whose *values* are
     SSZType descriptors::
@@ -2416,6 +2479,8 @@ class Container(metaclass=_ContainerMeta):
         # the self-weakref points at the ORIGINAL; children registered
         # under it would notify the wrong object
         nd.pop("_ssz_self_ref", None)
+        # a pending root scope belongs to the pass that marked this value
+        nd.pop(_ROOT_SCOPE, None)
         if not cls.__ssz_scalar_leaf__:
             # a nested-cached root is only sound with child->parent links
             # installed, and the copied children aren't wired to the copy;
@@ -2537,6 +2602,8 @@ class Container(metaclass=_ContainerMeta):
         cached = value.__dict__.get("_htr_cache")
         if cached is not None:
             return cached
+        if not cls.__ssz_scalar_leaf__ and _ROOT_SCOPE in value.__dict__:
+            return _scoped_root(cls, value)
         chunks = b"".join(
             typ.hash_tree_root(getattr(value, key))
             for key, typ in cls.__ssz_fields__.items()

@@ -69,10 +69,29 @@ span to three integer counters of the metrics registry:
 (``ns`` minus what child spans on the same thread covered: the
 per-thread stack that parents spans also carries each open span's
 children total). They do not move while no sink is on.
+
+**Counter scopes.** A name declared a scope (``declare_scope``, which
+``utils/trace.scope`` calls) qualifies the totals of every span that
+ends inside it on the same thread: besides ``span.<name>.*`` such a span
+adds to ``span.<scope>/<name>.{n,ns,self_ns}``. The per-thread stack
+carries the innermost scope the way it carries ``child_ns``, so a scope
+is no second recorder, only a second name for totals that exist anyway.
+
+**Garbage collection.** ``gc.callbacks`` holds ``GcWatch.on_gc`` from
+import on: it always counts ``gc.collections.gen{0,1,2}`` and
+``gc.pause_ns``, and while a timing sink is on it makes each collection
+a ``gc.collect`` span (fields ``generation``, ``collected``) on the
+thread that ran it, so a trace shows a collection where it fell. A
+collection can start inside any allocation, this module's own locked
+sections included, so the hook takes no lock a caller may hold: it
+touches the thread's stack, counters made before it runs, and leaves
+what needs a lock (the ring, a qualified total named for the first
+time) to the next span that ends.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -98,6 +117,7 @@ __all__ = [
     "stop_recording",
     "recording",
     "write_chrome_trace",
+    "GC_WATCH",
 ]
 
 DEFAULT_CAPACITY = 1 << 16
@@ -165,6 +185,7 @@ class SpanRecord:
         "child_ns",
         "ring",
         "annotation",
+        "scope",
     )
 
     def __init__(self, span_id: int, parent_id: int, name: str, lane: int,
@@ -192,6 +213,9 @@ class SpanRecord:
         # the profiler's open annotation of this span, while a session is
         # live: where ``note`` sends the fields the body learns late
         self.annotation = None
+        # the scope whose qualified totals this span's children add to:
+        # its own name if it is a declared scope, else its parent's
+        self.scope = None
 
     @property
     def duration_s(self) -> float:
@@ -231,6 +255,8 @@ class SpanRecorder:
         self._wall0 = 0.0             # wall-clock at start (metadata only)
         self._slow: list = []         # worst-N completed traces, ascending
         self._totals: dict = {}       # span name -> its three counters
+        self._scoped: dict = {}       # (scope, span name) -> three counters
+        self._scopes: set = set()     # names declared scopes
         self.dropped = 0              # ring evictions (spans + events)
         self.enabled = False
 
@@ -313,8 +339,22 @@ class SpanRecorder:
         )
         rec.flow_src = flow_src
         rec.ring = self.enabled
+        if name in self._scopes:
+            rec.scope = name
+        elif parent is not None:
+            rec.scope = parent.scope
         stack.append(rec)
         return rec
+
+    def declare_scope(self, name: str) -> None:
+        """Make ``name`` a counter scope: every span that ends inside a
+        span of that name, on its thread, also adds to
+        ``span.<name>/<its name>.*``."""
+        with self._lock:
+            self._scopes.add(name)
+
+    def is_scope(self, name: str) -> bool:
+        return name in self._scopes
 
     def note(self, fields: dict) -> None:
         """Add ``fields`` to the innermost span open on this thread: the
@@ -331,6 +371,8 @@ class SpanRecorder:
     def end(self, rec: SpanRecord, error: "str | None" = None) -> None:
         rec.t1 = time.perf_counter()
         rec.error = error
+        if GC_WATCH.pending:
+            self._settle_collections()
         stack = self._stack()
         # the facade pairs begin/end via try/finally, so rec is the top;
         # remove by identity anyway in case a caller misnests
@@ -342,12 +384,15 @@ class SpanRecorder:
             except ValueError:
                 pass
         ns = max(0, round((rec.t1 - rec.t0) * 1e9))
+        own_ns = max(0, ns - rec.child_ns)
+        scope = None
         if stack:  # what is now on top encloses this span
-            stack[-1].child_ns += ns
-        count, total, own = self._span_totals(rec.name)
-        count.inc()
-        total.inc(ns)
-        own.inc(max(0, ns - rec.child_ns))
+            top = stack[-1]
+            top.child_ns += ns
+            scope = top.scope
+        _add_totals(self._span_totals(rec.name), ns, own_ns)
+        if scope is not None:
+            _add_totals(self._scoped_totals(scope, rec.name), ns, own_ns)
         if rec.ring:
             self._append_span(rec)
 
@@ -356,13 +401,95 @@ class SpanRecorder:
         name."""
         totals = self._totals.get(name)
         if totals is None:
-            totals = tuple(
-                _metrics.counter(f"span.{name}.{what}")
-                for what in ("n", "ns", "self_ns")
-            )
+            totals = _make_totals(name)
             with self._lock:
                 self._totals[name] = totals
         return totals
+
+    def _scoped_totals(self, scope: str, name: str) -> tuple:
+        """The ``span.<scope>/<name>.{n,ns,self_ns}`` counters."""
+        key = (scope, name)
+        totals = self._scoped.get(key)
+        if totals is None:
+            totals = _make_totals(f"{scope}/{name}")
+            with self._lock:
+                self._scoped[key] = totals
+        return totals
+
+    # -- garbage collection (GcWatch calls these inside its hook) -----------
+    def gc_begin(self, generation: int, annotate) -> "SpanRecord | None":
+        """Open a ``gc.collect`` span on this thread's stack without a
+        lock: None on a thread that never opened a span (its lane would
+        need the lock)."""
+        lane = self._lanes.get(threading.get_ident())
+        if lane is None:
+            return None
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        rec = SpanRecord(
+            span_id=next(self._ids),
+            parent_id=parent.span_id if parent is not None else 0,
+            name=GC_SPAN,
+            lane=lane,
+            t0=time.perf_counter(),
+            fields={"generation": generation},
+            trace_id=parent.trace_id if parent is not None else 0,
+        )
+        rec.ring = self.enabled
+        if parent is not None:
+            rec.scope = parent.scope
+        if annotate is not None:
+            rec.annotation = annotate(PROFILER_PREFIX + GC_SPAN,
+                                      generation=generation)
+            rec.annotation.__enter__()
+        stack.append(rec)
+        return rec
+
+    def gc_end(self, rec: SpanRecord, collected: int) -> None:
+        """Close the span ``gc_begin`` opened: the totals made before the
+        hook ran move now; the ring's record and a qualified total named
+        for the first time wait in ``GC_WATCH.pending`` for the next span
+        that ends (``_settle_collections``)."""
+        rec.t1 = time.perf_counter()
+        rec.fields["collected"] = collected
+        if rec.annotation is not None:
+            rec.annotation.set_metadata(collected=collected)
+            rec.annotation.__exit__(None, None, None)
+        stack = self._stack()
+        if stack and stack[-1] is rec:
+            stack.pop()
+        else:  # pragma: no cover - a finalizer left a span open
+            try:
+                stack.remove(rec)
+            except ValueError:
+                pass
+        ns = max(0, round((rec.t1 - rec.t0) * 1e9))
+        own_ns = max(0, ns - rec.child_ns)
+        scope = None
+        if stack:
+            top = stack[-1]
+            top.child_ns += ns
+            scope = top.scope
+        _add_totals(GC_WATCH.totals, ns, own_ns)
+        if scope is not None:
+            totals = self._scoped.get((scope, GC_SPAN))
+            if totals is not None:
+                _add_totals(totals, ns, own_ns)
+                scope = None
+        if scope is not None or rec.ring:
+            GC_WATCH.pending.append((rec, scope, ns, own_ns))
+
+    def _settle_collections(self) -> None:
+        pending = GC_WATCH.pending
+        while pending:
+            try:
+                rec, scope, ns, own_ns = pending.popleft()
+            except IndexError:  # pragma: no cover - another thread took it
+                return
+            if scope is not None:
+                _add_totals(self._scoped_totals(scope, GC_SPAN), ns, own_ns)
+            if rec.ring:
+                self._append_span(rec)
 
     def _append_span(self, rec: SpanRecord) -> None:
         dropped = False
@@ -692,7 +819,63 @@ class SpanRecorder:
         }
 
 
+def _make_totals(name: str) -> tuple:
+    return tuple(
+        _metrics.counter(f"span.{name}.{what}")
+        for what in ("n", "ns", "self_ns")
+    )
+
+
+def _add_totals(totals: tuple, ns: int, own_ns: int) -> None:
+    count, total, own = totals
+    count.inc()
+    total.inc(ns)
+    own.inc(own_ns)
+
+
+GC_SPAN = "gc.collect"
+
+
+class GcWatch:
+    """The ``gc.callbacks`` hook. Collections never overlap (the
+    interpreter runs one at a time and none inside a callback), so the
+    open collection's start and span live here without a lock."""
+
+    def __init__(self):
+        self.generations = tuple(
+            _metrics.counter(f"gc.collections.gen{g}") for g in range(3)
+        )
+        self.pause_ns = _metrics.counter("gc.pause_ns")
+        # made before the hook can run: the hook never creates a counter
+        self.totals = _make_totals(GC_SPAN)
+        # (record, scope, ns, self ns) the hook could not finish without
+        # a lock
+        self.pending: deque = deque()
+        self.t0 = 0
+        self.rec = None
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self.t0 = time.perf_counter_ns()
+            annotate = profiler_annotation()
+            if annotate is not None or RECORDER.enabled:
+                self.rec = RECORDER.gc_begin(info["generation"], annotate)
+            return
+        self.pause_ns.inc(time.perf_counter_ns() - self.t0)
+        self.generations[info["generation"]].inc()
+        rec = self.rec
+        if rec is not None:
+            self.rec = None
+            RECORDER.gc_end(rec, info["collected"])
+
+    def install(self) -> None:
+        if self.on_gc not in gc.callbacks:
+            gc.callbacks.append(self.on_gc)
+
+
 RECORDER = SpanRecorder()
+GC_WATCH = GcWatch()
+GC_WATCH.install()
 
 
 def is_recording() -> bool:

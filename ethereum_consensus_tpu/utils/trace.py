@@ -20,6 +20,9 @@ core library emits nothing. Here the same facade fans out to three sinks:
 
 While the recorder or the profiler sink is on, the end of a span also
 adds to the ``span.<name>.{n,ns,self_ns}`` counters (telemetry/spans.py).
+A ``scope`` is such a span whose name also qualifies those counters for
+every span that ends inside it on its thread:
+``span.<scope>/<name>.{n,ns,self_ns}``.
 
 When no sink is active (the default), ``span`` takes a fast path
 that does no formatting, no recording, and no timestamp bookkeeping
@@ -47,6 +50,7 @@ from ..telemetry import spans as _spans
 __all__ = [
     "logger",
     "span",
+    "scope",
     "note",
     "event",
     "basic_setup",
@@ -137,6 +141,17 @@ def span(name: str, **fields):
             _RECORDER.end(rec, error=error)
         if mem is not None:
             _MEMORY.phase_end(name, mem)
+
+
+def scope(name: str, **fields):
+    """A ``span`` that is also a counter scope: while a timing sink is
+    on, every span that ends inside it on this thread adds to
+    ``span.<name>/<its name>.{n,ns,self_ns}`` as well as to its own
+    totals (telemetry/spans.py). With no sink on it costs what a span
+    costs."""
+    if not _RECORDER.is_scope(name):
+        _RECORDER.declare_scope(name)
+    return span(name, **fields)
 
 
 def note(**fields) -> None:

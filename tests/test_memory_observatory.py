@@ -206,30 +206,68 @@ def test_state_copy_bandwidth_counts_pointer_bytes(observatory):
     assert after["count"] > 0
 
 
-def test_bandwidth_renders_on_memory_trace_lane(observatory):
-    """Timed copy sites render as complete events on the `memory`
-    virtual lane of the Chrome trace (the device-lane idiom)."""
+def _packed_list(n: int):
+    """A u64 list past the tracking threshold whose pack tree is built, so
+    its next root after a write is a splice."""
+    lst = ssz_core.CachedRootList([0] * n)
+    ssz_core.List(ssz_core.uint64, 1 << 20).hash_tree_root(lst)
+    return lst
+
+
+def _bulk_store_site(n: int):
+    lst = ssz_core.CachedRootList([0] * n)
+    ssz_core.bulk_store(lst, np.ones(n, dtype=np.uint64), np.arange(n))
+
+
+def _packed_splice_site(n: int):
+    lst = _packed_list(n)
+    ssz_core.bulk_store(lst, [1] * n, range(n))  # every group dirty
+    ssz_core.List(ssz_core.uint64, 1 << 20).hash_tree_root(lst)
+
+
+def _column_serialize_site(n: int):
+    ssz_core.List(ssz_core.uint64, 1 << 20).hash_tree_root(
+        ssz_core.CachedRootList([1] * n)
+    )
+
+
+@pytest.mark.parametrize("site, make, span", [
+    ("ssz.bulk_store", _bulk_store_site, None),
+    ("ssz.packed_splice", _packed_splice_site, "ssz.packed_splice"),
+    ("ssz.column_serialize", _column_serialize_site, "ssz.full_pack"),
+])
+def test_bandwidth_renders_on_memory_trace_lane(observatory, site, make, span):
+    """A timed copy site renders as complete events on the `memory`
+    virtual lane of the Chrome trace (the device-lane idiom). The two ssz
+    sites inside a root keep their bytes and take no time of their own:
+    their seconds are the facade span around the same work."""
     from ethereum_consensus_tpu.telemetry import spans as tel_spans
 
-    n = 1 << 12
+    n = 1 << 15  # 8,192 chunks: over the tracking threshold
+    before = observatory.copy_summary()["sites"].get(site, {"bytes": 0})
     with tel_spans.recording():
-        lst = ssz_core.CachedRootList([0] * n)
-        ssz_core.bulk_store(
-            lst, np.ones(n, dtype=np.uint64), np.arange(n)
-        )
+        make(n)
         doc = tel_spans.RECORDER.chrome_trace()
+    after = observatory.copy_summary()["sites"][site]
+    assert after["bytes"] - before["bytes"] == n * 8
     lanes = {
         e["args"]["name"]: e["tid"]
         for e in doc["traceEvents"]
         if e.get("name") == "thread_name"
     }
-    assert "memory" in lanes
     copies = [
         e for e in doc["traceEvents"]
-        if e.get("name") == "memory.copy" and e["tid"] == lanes["memory"]
+        if e.get("name") == "memory.copy"
+        and e["tid"] == lanes.get("memory")
+        and e["args"]["site"] == site
     ]
-    assert copies and copies[0]["args"]["site"] == "ssz.bulk_store"
-    assert copies[0]["args"]["bytes"] == n * 8
+    if span is None:
+        assert "memory" in lanes
+        assert copies and copies[0]["args"]["bytes"] == n * 8
+    else:
+        assert copies == []
+        (event,) = [e for e in doc["traceEvents"] if e.get("name") == span]
+        assert event["dur"] > 0
 
 
 # ---------------------------------------------------------------------------

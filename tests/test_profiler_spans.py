@@ -58,10 +58,18 @@ def session(tmp_path_factory):
 
     from ethereum_consensus_tpu import _device_flags
 
+    from ethereum_consensus_tpu.ssz import core as ssz_core
+
     patch = pytest.MonkeyPatch()
     patch.setattr(epoch_vector, "EPOCH_VECTOR_MIN_VALIDATORS", 0)
     patch.setattr(_device_flags, "SWEEPS_MIN_N", 1)
+    # the dirty-group geometry shrunk (conftest's small_groups), so that
+    # the balances of 96 rows are spliced by groups as a registry's are
+    patch.setattr(ssz_core, "_DIRTY_GROUP_SHIFT", 2)
+    patch.setattr(ssz_core, "_DIRTY_TRACK_MIN_CHUNKS", 1 << 2)
     state, ctx = chain_utils.fresh_genesis_fork("deneb", 96, "minimal")
+    # a graph of its own: memos built under the shrunk geometry stay here
+    state = type(state).deserialize(type(state).serialize(state))
     sp = importlib.import_module(
         "ethereum_consensus_tpu.models.deneb.slot_processing"
     )
@@ -77,6 +85,8 @@ def session(tmp_path_factory):
         if i >= 93:
             validator.activation_eligibility_epoch = far
     chain_utils._strip_spec_caches(state)
+    # the root the first boundary's pass marked is taken before the session
+    type(state).hash_tree_root(state)
 
     def on_a_second_thread():
         with trace.span("test.worker", lane="second"):
@@ -94,6 +104,8 @@ def session(tmp_path_factory):
         live = spans.profiler_annotation()
         with jax.profiler.TraceAnnotation("bench:process_slots"):
             sp.process_slots(state, 2 * spe, ctx)
+        with jax.profiler.TraceAnnotation("bench:root"):
+            type(state).hash_tree_root(state)
         worker = threading.Thread(target=on_a_second_thread)
         worker.start()
         worker.join()
@@ -202,6 +214,28 @@ def test_the_fused_route_shows_its_copies_and_its_wait(session):
     order = [upload, wait, download, unpack]
     assert all(fused[1] <= e[1] and e[2] <= fused[2] for e in order)
     assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
+
+
+def test_the_epoch_root_scope_holds_the_splice_on_the_callers_line(session):
+    """The root after the pass is ``ect:transition.epoch_root`` inside the
+    harness's ``bench:root``, and the balances' splice is inside it, on
+    the same line, with what it did as stats."""
+    line = _line_with(session, "ect:transition.epoch_root")
+    outer = _only(line, "bench:root")
+    scope = _only(line, "ect:transition.epoch_root")
+    assert outer[1] <= scope[1] and scope[2] <= outer[2]
+    # the slot roots of process_slots splice too, outside the scope
+    splices = [
+        e for e in line if e[0] == "ect:ssz.packed_splice"
+        and scope[1] <= e[1] and e[2] <= scope[2]
+    ]
+    assert splices
+    assert all(int(e[3]["groups"]) >= 1 and int(e[3]["bytes"]) > 0
+               for e in splices)
+    moved = session["moved"]
+    assert moved["span.transition.epoch_root.n"] == 1
+    assert (moved["span.transition.epoch_root/ssz.packed_splice.n"]
+            == len(splices))
 
 
 def test_a_second_threads_span_is_on_that_threads_line(session):
@@ -313,6 +347,85 @@ def test_an_exception_ends_the_span_and_counts_it():
             == moved["span.nest.outer.ns"] - moved["span.nest.raises.ns"])
     assert "ValueError" in records["nest.raises"].error
     assert "ValueError" in records["nest.outer"].error
+
+
+def _scoped_nest():
+    with trace.scope("nest.scope"):
+        with trace.span("nest.a"):
+            with trace.span("nest.b"):
+                time.sleep(0.001)
+        with trace.span("nest.b"):
+            pass
+    with trace.span("nest.b"):  # outside: no scoped total
+        pass
+
+
+def test_a_scope_qualifies_what_ends_inside_it():
+    before = _span_counters()
+    with spans.recording():
+        _scoped_nest()
+    moved = _moved(before)
+    assert moved["span.nest.scope.n"] == 1
+    assert moved["span.nest.b.n"] == 3
+    assert moved["span.nest.scope/nest.b.n"] == 2
+    assert moved["span.nest.scope/nest.a.n"] == 1
+    for what in ("ns", "self_ns"):
+        assert (moved[f"span.nest.scope/nest.a.{what}"]
+                == moved[f"span.nest.a.{what}"])
+    # the scope's own time is what its direct children left: nest.a and
+    # the second nest.b (the first is nest.a's only child)
+    direct = moved["span.nest.scope/nest.a.ns"] + (
+        moved["span.nest.scope/nest.b.ns"]
+        - (moved["span.nest.a.ns"] - moved["span.nest.a.self_ns"])
+    )
+    assert moved["span.nest.scope.self_ns"] + direct == moved["span.nest.scope.ns"]
+
+
+def test_a_scope_is_per_thread():
+    """A span another thread ends while the scope is open is not inside
+    it: the stack that carries the scope is the thread's own."""
+    before = _span_counters()
+
+    def worker():
+        with trace.span("nest.worker"):
+            pass
+
+    with spans.recording():
+        with trace.scope("nest.scope"):
+            thread = threading.Thread(target=worker)
+            thread.start()
+            thread.join()
+    moved = _moved(before)
+    assert moved["span.nest.worker.n"] == 1
+    assert not [name for name in moved if name.startswith("span.nest.scope/")]
+
+
+def test_a_collection_is_counted_always_and_a_span_under_a_sink():
+    import gc
+
+    assert not spans.RECORDER.enabled
+    generations = [f"gc.collections.gen{g}" for g in range(3)]
+    snapshot = metrics.snapshot()
+    before = {name: snapshot[name] for name in generations + ["gc.pause_ns"]}
+    spans_before = _span_counters()
+    gc.collect(1)
+    snapshot = metrics.snapshot()
+    assert snapshot["gc.collections.gen1"] == before["gc.collections.gen1"] + 1
+    assert snapshot["gc.pause_ns"] > before["gc.pause_ns"]
+    assert _moved(spans_before) == {}  # no sink: no span
+    with spans.recording() as recorder:
+        with trace.scope("nest.scope"):
+            gc.collect(2)
+        with trace.span("nest.after"):
+            pass
+        collections = [r for r in recorder.records() if r.name == "gc.collect"]
+    moved = _moved(spans_before)
+    assert metrics.snapshot()["gc.collections.gen2"] >= before["gc.collections.gen2"] + 1
+    assert [r.fields["generation"] for r in collections] == [2]
+    assert collections[0].fields["collected"] >= 0
+    assert moved["span.gc.collect.n"] == moved["span.nest.scope/gc.collect.n"] == 1
+    assert (moved["span.nest.scope.self_ns"] + moved["span.gc.collect.ns"]
+            == moved["span.nest.scope.ns"])
 
 
 def test_no_sink_no_totals():

@@ -864,3 +864,197 @@ def test_state_roots_match_cold_recompute(fork, small_groups):
                 f"{fork}: divergence at step {step}"
             )
     assert state_type.hash_tree_root(state) == cold_root()
+
+
+# ---------------------------------------------------------------------------
+# the post-epoch root's counter scope (utils/trace.scope) and the ssz spans
+# ---------------------------------------------------------------------------
+
+EPOCH_ROOT = "transition.epoch_root"
+# what the scope's own time leaves to its direct children: the three ssz
+# spans that never nest in each other, and a collection that falls there
+SCOPE_CHILDREN = ("ssz.packed_splice", "ssz.tree_splice", "ssz.full_pack",
+                  "gc.collect")
+
+
+def _counters() -> dict:
+    return {k: v for k, v in metrics.snapshot().items() if isinstance(v, int)}
+
+
+def _moved(before: dict) -> dict:
+    return {
+        name: value - before.get(name, 0)
+        for name, value in _counters().items()
+        if value != before.get(name, 0)
+    }
+
+
+def _scope_moves(moved: dict) -> dict:
+    return {
+        name: value for name, value in moved.items()
+        if name.startswith(("span." + EPOCH_ROOT, EPOCH_ROOT))
+    }
+
+
+@pytest.fixture(scope="module")
+def driver_chain():
+    """The 1m cell's driver cut to 2^16 rows (above
+    ``_DIRTY_TRACK_MIN_CHUNKS``: the balances are 16 chunk-groups), under
+    the span recorder: a crossing, then the untimed advance (31 empty
+    slots, the participation refill, a root), the next crossing and its
+    root, and that root again. Returns what each step moved and the
+    crossing's root beside the literal oracle's (``ECT_EPOCH_VECTOR=off``
+    on a copy)."""
+    import gc
+    import json
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmark import worlds
+    from ethereum_consensus_tpu.models.deneb import slot_processing
+    from ethereum_consensus_tpu.telemetry import spans
+
+    root_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root_dir, "benchmark/configs/mainnet-deneb-1m.json")) as f:
+        config = json.load(f)
+    config["validators"] = 1 << 16
+    world = worlds.build(
+        config,
+        {"kind": "epoch_edge", "epoch": 1, "miss_share": [0.01, 0.03],
+         "chain_epochs": 2},
+        3700000017,
+    )
+    state = world.pre.copy()
+    htr = type(state).hash_tree_root
+    target = world.target_slot
+    out = {}
+    with spans.recording():
+        slot_processing.process_slots(state, target, world.context)
+        htr(state)
+        # the driver's advance: 31 empty slots, the refill, a root
+        before = _counters()
+        slot_processing.process_slots(state, target + 31, world.context)
+        state.current_epoch_participation = world.refills[0].tolist()
+        htr(state)
+        out["advance"] = _moved(before)
+        literal = state.copy()
+        before = _counters()
+        slot_processing.process_slots(state, target + 32, world.context)
+        out["marked"] = ssz_core._ROOT_SCOPE in state.__dict__
+        out["copy_marked"] = ssz_core._ROOT_SCOPE in state.copy().__dict__
+        gc.disable()  # a collection inside a splice would sit a level down
+        try:
+            out["root"] = htr(state)
+        finally:
+            gc.enable()
+        out["crossing"] = _moved(before)
+        before = _counters()
+        assert htr(state) == out["root"]
+        out["again"] = _moved(before)
+    os.environ["ECT_EPOCH_VECTOR"] = "off"
+    try:
+        slot_processing.process_slots(literal, target + 32, world.context)
+    finally:
+        os.environ.pop("ECT_EPOCH_VECTOR", None)
+    out["literal"] = type(literal).hash_tree_root(literal)
+    return out
+
+
+def test_the_untimed_advance_opens_no_epoch_root_scope(driver_chain):
+    """31 slot roots and the refill's root hash, and none is the root after
+    an epoch pass: no scoped total and no scoped digest moves."""
+    moved = driver_chain["advance"]
+    assert moved["ssz.digests"] > 0
+    assert moved.get("span.transition.state_htr.n") == 31
+    assert _scope_moves(moved) == {}
+
+
+def test_a_crossing_opens_the_scope_once_with_the_oracles_root(driver_chain):
+    moved = driver_chain["crossing"]
+    assert driver_chain["marked"] and not driver_chain["copy_marked"]
+    assert moved["span.transition.epoch_root.n"] == 1
+    assert driver_chain["root"] == driver_chain["literal"]
+    # every compression of the crossing's root is the scope's; the slot
+    # root before the pass is not
+    assert 0 < moved[EPOCH_ROOT + ".digests"] < moved["ssz.digests"]
+    assert moved[f"span.{EPOCH_ROOT}/ssz.packed_splice.n"] >= 1
+    assert moved[f"span.{EPOCH_ROOT}/ssz.full_pack.n"] >= 1
+
+
+@pytest.mark.parametrize("what", ["n", "ns", "self_ns"])
+def test_a_scoped_total_is_the_spans_own_total_inside_the_scope(
+    driver_chain, what
+):
+    """Every ``ssz.*`` span of the crossing ended inside the root's scope:
+    its scoped totals are its totals."""
+    moved = driver_chain["crossing"]
+    for part in ("ssz.packed_splice", "ssz.full_pack"):
+        assert moved[f"span.{EPOCH_ROOT}/{part}.{what}"] == moved[
+            f"span.{part}.{what}"
+        ], part
+
+
+def test_the_parts_and_the_rest_are_the_scope(driver_chain):
+    moved = driver_chain["crossing"]
+    parts = sum(
+        moved.get(f"span.{EPOCH_ROOT}/{child}.ns", 0)
+        for child in SCOPE_CHILDREN
+    )
+    rest = moved[f"span.{EPOCH_ROOT}.self_ns"]
+    assert parts > 0 and rest > 0
+    assert parts + rest == moved[f"span.{EPOCH_ROOT}.ns"]
+
+
+def test_a_second_root_of_an_unchanged_state_opens_no_ssz_span(driver_chain):
+    moved = driver_chain["again"]
+    assert not [name for name in moved if name.startswith("span.ssz.")]
+    assert _scope_moves(moved) == {}
+
+
+def test_the_block_path_opens_the_scope_inside_its_state_root_check():
+    """A node: the block at an epoch's first slot is imported, and the
+    root its import checks is the root after the pass."""
+    import chain_utils
+    from ethereum_consensus_tpu.models.deneb.state_transition import (
+        Validation,
+        state_transition_block_in_slot,
+    )
+    from ethereum_consensus_tpu.telemetry import spans
+
+    state, ctx = chain_utils.fresh_genesis_deneb(16, "minimal")
+    state = state.copy()
+    spe = int(ctx.SLOTS_PER_EPOCH)
+    block = chain_utils.produce_block_deneb(state, spe, ctx)
+    assert ssz_core._ROOT_SCOPE in state.__dict__  # the advance's pass
+    with spans.recording() as recorder:
+        state_transition_block_in_slot(state, block, Validation.ENABLED, ctx)
+        records = recorder.records()
+    by_id = {r.span_id: r for r in records}
+    (scope,) = [r for r in records if r.name == EPOCH_ROOT]
+    parent = by_id[scope.parent_id]
+    assert parent.name == "transition.state_htr"
+    assert int(parent.fields["slot"]) == spe
+    assert ssz_core._ROOT_SCOPE not in state.__dict__
+
+
+def test_a_copy_does_not_carry_the_mark():
+    """The mark belongs to the value the pass ran on: its copy roots
+    without a scope, the original opens it at its own next root."""
+    import chain_utils
+    from ethereum_consensus_tpu.models.deneb import slot_processing
+    from ethereum_consensus_tpu.telemetry import spans
+
+    state, ctx = chain_utils.fresh_genesis_deneb(16, "minimal")
+    state = state.copy()
+    slot_processing.process_slots(state, int(ctx.SLOTS_PER_EPOCH), ctx)
+    copy = state.copy()
+    assert ssz_core._ROOT_SCOPE not in copy.__dict__
+    assert copy == state  # not a field: never compared
+    htr = type(state).hash_tree_root
+    with spans.recording():
+        before = _counters()
+        copy_root = htr(copy)
+        after_copy = _moved(before)
+        assert htr(state) == copy_root
+        after_state = _moved(before)
+    assert _scope_moves(after_copy) == {}
+    assert after_state["span.transition.epoch_root.n"] == 1

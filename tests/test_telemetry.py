@@ -95,11 +95,17 @@ def test_recording_off_records_nothing():
 
 
 def test_ring_buffer_bounds_memory():
-    with spans.recording(capacity=16):
-        for i in range(64):
-            with trace.span("spin", i=i):
-                pass
-        records = spans.RECORDER.records()
+    try:
+        with spans.recording(capacity=16):
+            for i in range(64):
+                with trace.span("spin", i=i):
+                    pass
+            records = spans.RECORDER.records()
+    finally:
+        # a capacity outlives its recording: give the worker's later
+        # tests the default ring back
+        spans.start_recording(spans.DEFAULT_CAPACITY)
+        spans.stop_recording()
     assert len(records) == 16
     # newest survive, oldest dropped
     assert max(r.fields["i"] for r in records) == 63
@@ -294,20 +300,23 @@ def _span_totals():
             if k.startswith("span.")}
 
 
-def test_disabled_span_microcost():
-    """Absolute sanity bound on one disabled span (not a benchmark — a
-    regression tripwire: the disabled path must stay allocation-light),
-    and no ``span.*`` total moves while no sink is on."""
+@pytest.mark.parametrize("opener", ["span", "scope"])
+def test_disabled_span_microcost(opener):
+    """Absolute sanity bound on one disabled span, and on a counter scope,
+    which is a span (not a benchmark — a regression tripwire: the
+    disabled path must stay allocation-light), and no ``span.*`` total
+    moves while no sink is on."""
     assert not spans.RECORDER.enabled
     assert spans.profiler_annotation() is None
+    open_span = getattr(trace, opener)
     totals0 = _span_totals()
     n = 20_000
     t0 = time.perf_counter()
     for _ in range(n):
-        with trace.span("micro.guard", slot=1):
+        with open_span("micro.guard", slot=1):
             pass
     per_span = (time.perf_counter() - t0) / n
-    assert per_span < 50e-6, f"{per_span * 1e6:.1f}µs per disabled span"
+    assert per_span < 50e-6, f"{per_span * 1e6:.1f}µs per disabled {opener}"
     assert totals0 == _span_totals()
 
 
