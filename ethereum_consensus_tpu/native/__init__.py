@@ -28,6 +28,7 @@ __all__ = [
     "build_failures",
     "hash_level_native",
     "merkle_root_native",
+    "merkle_groups_native",
     "install",
 ]
 
@@ -136,7 +137,7 @@ def load():
         "sha256_merkle",
         artifact_tag([_SOURCE], sha_ni),
         _SOURCE,
-        ["-DEC_USE_SHA_NI"] if sha_ni else [],
+        ["-pthread", *(["-DEC_USE_SHA_NI"] if sha_ni else [])],
         timeout=120,
     )
     if lib_path is None:
@@ -154,6 +155,12 @@ def load():
         ctypes.c_char_p, ctypes.c_char_p,
     ]
     lib.ec_merkle_root.restype = None
+    lib.ec_merkle_groups.argtypes = [
+        ctypes.c_void_p, ctypes.c_size_t, ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_size_t, ctypes.c_uint32, ctypes.c_char_p, ctypes.c_char_p,
+        ctypes.c_uint32,
+    ]
+    lib.ec_merkle_groups.restype = ctypes.c_uint32
     lib.ec_version.restype = ctypes.c_uint64
     _LIB = lib
     return lib
@@ -188,6 +195,25 @@ def merkle_root_native(chunks: bytes, depth: int, zero_hashes: bytes) -> bytes:
     out = ctypes.create_string_buffer(32)
     lib.ec_merkle_root(chunks, len(chunks) // 32, depth, zero_hashes, out)
     return out.raw
+
+
+def merkle_groups_native(
+    raw, group_ids, depth: int, zero_hashes: bytes, n_threads: int
+) -> "tuple[bytes, int] | None":
+    """Roots of the 2^depth-chunk groups ``group_ids`` of the bytearray
+    ``raw`` (read in place), 32 bytes each in the order given, on up to
+    ``n_threads`` host threads; with the threads that ran. None when the
+    native side wrote nothing."""
+    lib = load()
+    n = len(group_ids)
+    ids = (ctypes.c_uint64 * n)(*group_ids)
+    out = ctypes.create_string_buffer(32 * n)
+    buf = (ctypes.c_char * len(raw)).from_buffer(raw)
+    used = lib.ec_merkle_groups(
+        buf, len(raw), ids, n, depth, zero_hashes, out, n_threads
+    )
+    del buf  # the bytearray may be resized again once its export is gone
+    return (out.raw, used) if used else None
 
 
 def install() -> bool:

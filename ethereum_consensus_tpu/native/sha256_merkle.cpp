@@ -12,10 +12,18 @@
 //   void ec_hash_level(const uint8_t* in, uint8_t* out, size_t n_pairs);
 //   void ec_merkle_root(const uint8_t* chunks, size_t count, uint32_t depth,
 //                       const uint8_t* zero_hashes, uint8_t* out32);
+//   uint32_t ec_merkle_groups(const uint8_t* raw, size_t raw_len,
+//                             const uint64_t* group_ids, size_t n_groups,
+//                             uint32_t group_depth,
+//                             const uint8_t* zero_hashes, uint8_t* out,
+//                             uint32_t n_threads);
 //   uint64_t ec_version(void);
 
+#include <algorithm>
+#include <atomic>
 #include <cstdint>
 #include <cstring>
+#include <thread>
 #include <vector>
 
 #if defined(EC_USE_SHA_NI) && defined(__SHA__) && defined(__x86_64__)
@@ -516,6 +524,93 @@ void ec_merkle_root(const uint8_t* chunks, size_t count, uint32_t depth,
     nodes.swap(next);
   }
   std::memcpy(out32, nodes.data(), 32);
+}
+
+}  // extern "C"
+
+namespace {
+
+// Root of chunk-group `g` of `raw`: 2^depth chunks of 32 bytes, the bytes
+// past `raw_len` zero; what pack_bytes + merkleize_chunks(limit=2^depth)
+// give for the group's bytes. The first level reads `raw` in place;
+// `scratch` holds 32 << depth bytes, two halves the levels alternate in.
+void root_group(const uint8_t* raw, size_t raw_len, uint64_t g,
+                uint32_t depth, const uint8_t* zero_hashes, uint8_t* scratch,
+                uint8_t* out32) {
+  const size_t gbytes = size_t(32) << depth;
+  const size_t start = size_t(g) * gbytes;
+  const size_t len = start < raw_len ? std::min(gbytes, raw_len - start) : 0;
+  if (len == 0) {
+    std::memcpy(out32, zero_hashes + 32 * size_t(depth), 32);
+    return;
+  }
+  uint8_t* cur = scratch;
+  uint8_t* nxt = scratch + gbytes / 2;
+  size_t n = len / 64;
+  ec_hash_level(raw + start, cur, n);
+  if (len % 64) {
+    // the last pair: a partial chunk's padding and an odd chunk's
+    // sibling (zero_hashes[0]) are both zero bytes
+    uint8_t tail[64] = {0};
+    std::memcpy(tail, raw + start + 64 * n, len % 64);
+    ec_hash_level(tail, cur + 32 * n, 1);
+    ++n;
+  }
+  for (uint32_t level = 1; level < depth; ++level) {
+    if (n % 2 == 1) {
+      std::memcpy(cur + 32 * n, zero_hashes + 32 * size_t(level), 32);
+      ++n;
+    }
+    ec_hash_level(cur, nxt, n / 2);
+    n /= 2;
+    std::swap(cur, nxt);
+  }
+  std::memcpy(out32, cur, 32);
+}
+
+}  // namespace
+
+extern "C" {
+
+// Roots of the chunk-groups `group_ids` of `raw` (each 2^group_depth
+// chunks; `zero_hashes` = group_depth+1 zero-subtree roots), 32 bytes each
+// into `out` in the order given. Up to `n_threads` threads, spawned here
+// and joined before return, take groups off one shared index; the
+// calling thread is one of them. Returns the threads that ran, 0 when
+// nothing was written (group_depth 0, or no scratch could be allocated).
+uint32_t ec_merkle_groups(const uint8_t* raw, size_t raw_len,
+                          const uint64_t* group_ids, size_t n_groups,
+                          uint32_t group_depth, const uint8_t* zero_hashes,
+                          uint8_t* out, uint32_t n_threads) {
+  if (n_groups == 0) return 1;
+  if (group_depth == 0) return 0;  // a one-chunk group is its own root
+  if (n_threads > n_groups) n_threads = uint32_t(n_groups);
+  if (n_threads < 1) n_threads = 1;
+  const size_t gbytes = size_t(32) << group_depth;
+  std::vector<uint8_t> scratch;
+  try {
+    scratch.resize(gbytes * n_threads);
+  } catch (...) {
+    return 0;
+  }
+  std::atomic<size_t> next{0};
+  auto work = [&](size_t t) {
+    uint8_t* buf = scratch.data() + gbytes * t;
+    for (size_t i; (i = next.fetch_add(1)) < n_groups;) {
+      root_group(raw, raw_len, group_ids[i], group_depth, zero_hashes, buf,
+                 out + 32 * i);
+    }
+  };
+  std::vector<std::thread> pool;
+  try {
+    pool.reserve(n_threads - 1);
+    for (uint32_t t = 1; t < n_threads; ++t) pool.emplace_back(work, t);
+  } catch (...) {
+    // fewer threads than asked: the ones running take the rest
+  }
+  work(0);
+  for (auto& th : pool) th.join();
+  return uint32_t(pool.size() + 1);
 }
 
 uint64_t ec_version(void) { return 1; }

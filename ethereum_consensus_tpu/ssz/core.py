@@ -27,6 +27,7 @@ from .hash import hash_level
 from .merkle import (
     BYTES_PER_CHUNK,
     IncrementalPaddedTree,
+    merkleize_chunk_groups,
     merkleize_chunks,
     mix_in_length,
     next_pow_of_two,
@@ -1056,13 +1057,23 @@ def _splice_dirty_groups(values, key, esize: int, pt, dg) -> "bytes | None":
     total_chunks = (len(raw) + BYTES_PER_CHUNK - 1) // BYTES_PER_CHUNK
     n_cgs = (total_chunks + (1 << gs) - 1) >> gs
     tree.truncate(n_cgs)
-    for cg in sorted({g >> pcl for g in dg}):
-        if cg >= n_cgs:
-            continue
-        seg = bytes(raw[cg * cbytes : (cg + 1) * cbytes])
-        if not seg:
-            continue
-        tree.set_node(cg, merkleize_chunks(pack_bytes(seg), limit=1 << gs))
+    cgs = [cg for cg in sorted({g >> pcl for g in dg}) if cg < n_cgs]
+    # every dirty chunk-group in one native call over the host's cores,
+    # reading raw in place; one by one where that is unavailable
+    batch = merkleize_chunk_groups(raw, cgs, gs)
+    if batch is not None:
+        roots, threads = batch
+        _trace.note(threads=threads)
+    else:
+        roots = [
+            merkleize_chunks(
+                pack_bytes(bytes(raw[cg * cbytes : (cg + 1) * cbytes])),
+                limit=1 << gs,
+            )
+            for cg in cgs
+        ]
+    for cg, group_root in zip(cgs, roots):
+        tree.set_node(cg, group_root)
     root = tree.root()
     pt[3] = root
     values._dirty_groups = set()

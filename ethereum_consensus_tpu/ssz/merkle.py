@@ -9,6 +9,10 @@ spec-tests/runners/light_client.rs:10-13).
 
 from __future__ import annotations
 
+import functools
+import os
+
+from ..telemetry import metrics as _metrics
 from . import hash as _hash_mod
 from .hash import hash_bytes, hash_level, hash_pair
 
@@ -18,6 +22,7 @@ __all__ = [
     "zero_hash",
     "merkleize",
     "merkleize_chunks",
+    "merkleize_chunk_groups",
     "mix_in_length",
     "mix_in_selector",
     "pack_bytes",
@@ -127,12 +132,7 @@ def merkleize_chunks(
         root = _MESH_MERKLEIZER(chunks, limit)
         if root is not None:
             # exact level-sum work accounting, as _native_tree_root does
-            n = count
-            total = 0
-            for _ in range(depth):
-                n = (n + 1) // 2
-                total += n
-            _hash_mod.add_digests(total)
+            _hash_mod.add_digests(_level_sum(count, depth))
             return root
 
     # medium-to-large flat trees: one native call walks every level
@@ -167,19 +167,77 @@ def _native_tree_root(chunks: bytes, depth: int) -> "bytes | None":
         return None
     if not native.available():
         return None
+    _hash_mod.add_digests(_level_sum(len(chunks) // BYTES_PER_CHUNK, depth))
+    return native.merkle_root_native(chunks, depth, _zero_hashes_joined(depth))
+
+
+def _zero_hashes_joined(depth: int) -> bytes:
     zh = _ZH_JOINED.get(depth)
     if zh is None:
         zh = b"".join(zero_hash(level) for level in range(depth + 1))
         _ZH_JOINED[depth] = zh
-    # exact level-sum digest count (zero-pad siblings come from the
-    # precomputed table, so each level costs ceil(n/2) compressions)
-    n = len(chunks) // BYTES_PER_CHUNK
+    return zh
+
+
+def _level_sum(count: int, depth: int) -> int:
+    """Compressions of a depth-``depth`` tree over ``count`` leaves whose
+    zero-pad siblings come from the table: ceil(n/2) a level."""
     total = 0
     for _ in range(depth):
-        n = (n + 1) // 2
-        total += n
-    _hash_mod.add_digests(total)
-    return native.merkle_root_native(chunks, depth, zh)
+        count = (count + 1) // 2
+        total += count
+    return total
+
+
+# groups of a merkleize_chunk_groups batch, by whether it ran on more
+# than one host thread (ssz/core.py _splice_dirty_groups is the caller)
+_GROUPS_THREADED = _metrics.counter("ssz.group_roots.threaded")
+_GROUPS_INLINE = _metrics.counter("ssz.group_roots.inline")
+
+
+@functools.lru_cache(maxsize=1)
+def _usable_cores() -> int:
+    return len(os.sched_getaffinity(0))
+
+
+def merkleize_chunk_groups(
+    raw, group_ids: "list[int]", depth: int
+) -> "tuple[list[bytes], int] | None":
+    """Roots of the chunk-groups ``group_ids`` of ``raw`` (2**depth chunks
+    each, zero past the end of ``raw``), each what
+    ``merkleize_chunks(pack_bytes(group bytes), limit=2**depth)`` gives,
+    in one native call over min(groups, usable cores) host threads; with
+    the threads that ran. None when the native backend is unavailable or
+    the mesh hook could take a group: the caller roots them one by one."""
+    if _MESH_MERKLEIZER is not None and _MESH_MIN_CHUNKS <= 1 << depth:
+        return None
+    try:
+        from .. import native
+    except Exception:  # noqa: BLE001 — no toolchain: python loop
+        return None
+    if not native.available():
+        return None
+    got = native.merkle_groups_native(
+        raw,
+        group_ids,
+        depth,
+        _zero_hashes_joined(depth),
+        min(len(group_ids), _usable_cores()),
+    )
+    if got is None:
+        return None
+    out, threads = got
+    # the level-sum of each group's populated chunks, as the loop counts
+    gchunks = 1 << depth
+    chunks = (len(raw) + BYTES_PER_CHUNK - 1) // BYTES_PER_CHUNK
+    _hash_mod.add_digests(
+        sum(
+            _level_sum(min(max(chunks - g * gchunks, 0), gchunks), depth)
+            for g in group_ids
+        )
+    )
+    (_GROUPS_THREADED if threads > 1 else _GROUPS_INLINE).inc(len(group_ids))
+    return [out[i : i + 32] for i in range(0, len(out), 32)], threads
 
 
 def merkleize(chunks: list[bytes], limit: int | None = None) -> bytes:
