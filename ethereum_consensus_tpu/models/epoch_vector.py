@@ -706,6 +706,15 @@ def _sync(state, context, fork):
     ec.prefix = ec.b_prefix
     ec.balances = ec.b_balances
     ec.inact = ec.b_inact
+    # slashed, exited, not yet withdrawable: flag penalties and score
+    # updates for rows outside every active mask
+    eligible_inactive = int(np.count_nonzero(ec.eligible)) - int(
+        np.count_nonzero(ec.active_prev)
+    )
+    if eligible_inactive:
+        metrics.counter("epoch_vector.rows_eligible_inactive").inc(
+            eligible_inactive
+        )
     ec._total_active = None
     ec._active_cur_count = None
     ec.credential_switches = []
@@ -1479,8 +1488,10 @@ def _slashings(ec) -> None:
     mask = ec.slashed & (ec.wdr == np.uint64(target))
     hits = np.nonzero(mask)[0]
     increment = ec.increment
+    trace.note(hits=int(hits.size))
     if hits.size:
         _own(ec, "balances")
+        metrics.counter("epoch_vector.slashings.penalised").inc(int(hits.size))
     for i in hits.tolist():
         # exact big-int math per hit (the eff//inc * adjusted product
         # exceeds u64 at mainnet totals); hits are the few slashed
