@@ -75,7 +75,10 @@ lineage), ``ops_vector.columns.extended_rows`` (rows the working columns
 were extended by, not rebuilt; span ``epoch_vector.sync.extend``) and
 ``epoch_vector.fused.pad_rows`` (inert rows a fused dispatch carried:
 its shape is ``fused_dispatch_rows(n)``, not ``n``); the fused span
-carries ``rows`` and ``padded``.
+carries ``rows`` and ``padded``. The sweep before the pass (span
+``epoch_vector.sync.scan``): ``epoch_vector.scan.threads`` (the host
+threads it ran on) and ``epoch_vector.scan.fallback`` (passes that took
+the numpy sequence, reason on a one-shot event).
 """
 
 from __future__ import annotations
@@ -549,6 +552,8 @@ class _EpochColumns:
         "balances", "inact",
         # lazy scalars
         "_total_active", "_active_cur_count",
+        # the pre-pass sweep's maxima, counts and masked eff sums (_scan)
+        "scan",
         # masks at the pre-pass registry (activity is stable within the
         # epoch window — every spec write targets future epochs)
         "active_prev", "active_cur", "eligible",
@@ -560,6 +565,99 @@ class _EpochColumns:
         # selected it (None = host/mesh routes decide)
         "fused",
     )
+
+
+# a sweep thread is worth its start and join from this many rows up: on the
+# v5e's 13-core host each thread past the first costs about 0.25 ms, and a
+# thread sweeps 2^18 rows in about 1.6 ms (PERF.md section 6, the thread curve)
+SCAN_MIN_ROWS_PER_THREAD = 1 << 18
+
+_SCAN_FALLBACK_SEEN: set = set()
+
+
+def _scan_fallback(reason: str) -> None:
+    """A pass whose sweep took the numpy sequence: counted, and a trace
+    event once per reason per process."""
+    metrics.counter("epoch_vector.scan.fallback").inc()
+    if reason not in _SCAN_FALLBACK_SEEN:
+        with _FALLBACK_LOCK:
+            if reason not in _SCAN_FALLBACK_SEEN:
+                _SCAN_FALLBACK_SEEN.add(reason)
+                trace.event("epoch_vector.scan.fallback", reason=reason)
+
+
+def _scan_columns(ec) -> tuple:
+    return (
+        ec.b_balances, ec.b_eff, ec.b_act, ec.b_exit, ec.b_wdr, ec.slashed,
+        ec.prev_part, ec.cur_part, ec.b_inact,
+    )
+
+
+def _scan(ec) -> dict:
+    """Set ``ec.active_prev``, ``ec.active_cur`` and ``ec.eligible`` and
+    return the scalars of ``native.epoch_scan.SCAN_FIELDS``: one native
+    sweep over the columns on min(usable cores, rows / 2^18) host threads,
+    or, where it cannot run, the numpy sequence it replaces (counted)."""
+    from ..native import epoch_scan, usable_cores
+
+    if not epoch_scan.available():
+        _scan_fallback("native_unavailable")
+        return _scan_numpy(ec)
+    threads = max(1, min(usable_cores(), ec.n // SCAN_MIN_ROWS_PER_THREAD))
+    swept = epoch_scan.epoch_scan(
+        ec.np, *_scan_columns(ec), ec.prev, ec.cur,
+        _TIMELY_TARGET_FLAG_INDEX, threads,
+    )
+    if swept is None:
+        _scan_fallback("column_layout")
+        return _scan_numpy(ec)
+    masks, scan, ran = swept
+    ec.active_prev, ec.active_cur, ec.eligible = masks
+    metrics.counter("epoch_vector.scan.threads").inc(ran)
+    return scan
+
+
+def _scan_numpy(ec) -> dict:
+    """``_scan``'s numpy sequence: the fallback, and the oracle the
+    native sweep is tested against."""
+    np = ec.np
+    far = np.uint64(FAR_FUTURE_EPOCH)
+    real_exits = ec.b_exit[ec.b_exit != far]
+    prev64 = np.uint64(ec.prev)
+    cur64 = np.uint64(ec.cur)
+    ec.active_prev = (ec.b_act <= prev64) & (prev64 < ec.b_exit)
+    ec.active_cur = (ec.b_act <= cur64) & (cur64 < ec.b_exit)
+    ec.eligible = ec.active_prev | (
+        ec.slashed & (prev64 + np.uint64(1) < ec.b_wdr)
+    )
+    scan = {
+        "balance_max": int(ec.b_balances.max(initial=0)),
+        "eff_max": int(ec.b_eff.max(initial=0)),
+        "exit_max": int(real_exits.max()) if real_exits.size else 0,
+        "inact_max": 0,
+        "n_active_prev": int(np.count_nonzero(ec.active_prev)),
+        "n_active_cur": int(np.count_nonzero(ec.active_cur)),
+        "n_eligible": int(np.count_nonzero(ec.eligible)),
+        "active_cur_eff": int(ec.b_eff[ec.active_cur].sum()),
+        "prev_target_eff": 0,
+        "cur_target_eff": 0,
+    }
+    if ec.b_inact is not None:
+        unslashed = ~ec.slashed
+        prev_mask = (
+            ec.active_prev
+            & unslashed
+            & _flag_mask(ec, ec.prev_part, _TIMELY_TARGET_FLAG_INDEX)
+        )
+        cur_mask = (
+            ec.active_cur
+            & unslashed
+            & _flag_mask(ec, ec.cur_part, _TIMELY_TARGET_FLAG_INDEX)
+        )
+        scan["inact_max"] = int(ec.b_inact.max(initial=0))
+        scan["prev_target_eff"] = int(ec.b_eff[prev_mask].sum())
+        scan["cur_target_eff"] = int(ec.b_eff[cur_mask].sum())
+    return scan
 
 
 def _sync(state, context, fork):
@@ -629,19 +727,26 @@ def _sync(state, context, fork):
         else:
             ec.prev_part = ec.cur_part = ec.b_inact = None
 
+    # every registry-wide reduction the pass takes before its kernels, in
+    # one sweep over the columns: the masks at the PRE-PASS registry
+    # (every spec mutation of the activity schedule targets a future
+    # epoch, the get_active_validator_indices contract, so they stay
+    # exact for the whole pass), the guards' maxima and the masked sums
+    with trace.span("epoch_vector.sync.scan"):
+        scan = _scan(ec)
+    ec.scan = scan
+
     # --- u64 lane guards: everything the pass adds/multiplies must stay
     # below 2^63 so no kernel op can wrap; a state outside the lane
     # (adversarial near-2^64 values) declines BEFORE any mutation and
     # the literal loops keep their exact big-int/structured-error paths
-    if int(ec.b_balances.max(initial=0)) >= _LANE_MAX:
+    if scan["balance_max"] >= _LANE_MAX:
         fallback("u64_guard")
         return None
-    if int(ec.b_eff.max(initial=0)) >= _LANE_MAX:
+    if scan["eff_max"] >= _LANE_MAX:
         fallback("u64_guard")
         return None
-    far = np.uint64(FAR_FUTURE_EPOCH)
-    real_exits = ec.b_exit[ec.b_exit != far]
-    if real_exits.size and int(real_exits.max()) >= _LANE_MAX:
+    if scan["exit_max"] >= _LANE_MAX:
         fallback("u64_guard")
         return None
     if cur >= _LANE_MAX - (2 + int(context.MAX_SEED_LOOKAHEAD)):
@@ -649,26 +754,14 @@ def _sync(state, context, fork):
         return None
     if ec.b_inact is not None:
         bias = int(context.inactivity_score_bias)
-        if int(ec.b_inact.max(initial=0)) >= _U64_MAX - bias:
+        if scan["inact_max"] >= _U64_MAX - bias:
             fallback("u64_guard")
             return None
     # masked eff sums must be exact in u64: cap n * max(eff) below 2^64
-    eff_max = int(ec.b_eff.max(initial=0))
+    eff_max = scan["eff_max"]
     if n and eff_max * n >= 1 << 64:
         fallback("u64_guard")
         return None
-
-    # activity masks at the PRE-PASS registry: every spec mutation of
-    # the activity schedule targets a future epoch (the
-    # get_active_validator_indices contract), so these stay exact for
-    # the whole pass
-    prev64 = np.uint64(ec.prev)
-    cur64 = np.uint64(cur)
-    ec.active_prev = (ec.b_act <= prev64) & (prev64 < ec.b_exit)
-    ec.active_cur = (ec.b_act <= cur64) & (cur64 < ec.b_exit)
-    ec.eligible = ec.active_prev | (
-        ec.slashed & (prev64 + np.uint64(1) < ec.b_wdr)
-    )
 
     if ec.cfg["family"] == "altair" and cur != GENESIS_EPOCH:
         # rewards-kernel product guard, BEFORE any mutation: the largest
@@ -677,9 +770,7 @@ def _sync(state, context, fork):
         # states clear this by ~10 bits; a decline costs nothing.
         from .phase0.helpers import integer_squareroot
 
-        total_active = max(
-            ec.increment, int(ec.b_eff[ec.active_cur].sum())
-        )
+        total_active = max(ec.increment, scan["active_cur_eff"])
         brpi = (
             ec.increment
             * int(context.BASE_REWARD_FACTOR)
@@ -708,9 +799,7 @@ def _sync(state, context, fork):
     ec.inact = ec.b_inact
     # slashed, exited, not yet withdrawable: flag penalties and score
     # updates for rows outside every active mask
-    eligible_inactive = int(np.count_nonzero(ec.eligible)) - int(
-        np.count_nonzero(ec.active_prev)
-    )
+    eligible_inactive = scan["n_eligible"] - scan["n_active_prev"]
     if eligible_inactive:
         metrics.counter("epoch_vector.rows_eligible_inactive").inc(
             eligible_inactive
@@ -738,7 +827,13 @@ def _total_active(ec) -> int:
     exactly ``get_total_active_balance``'s value; seeded into the
     state's memo so every scalar helper call mid-pass hits it."""
     if ec._total_active is None:
-        total = max(ec.increment, int(ec.eff[ec.active_cur].sum()))
+        # the sweep's sum while no stage has written an effective balance
+        active_eff = (
+            ec.scan["active_cur_eff"]
+            if ec.eff is ec.b_eff
+            else int(ec.eff[ec.active_cur].sum())
+        )
+        total = max(ec.increment, active_eff)
         ec._total_active = total
         ec.state.__dict__["_total_active_balance_cache"] = (
             (ec.cur, ec.n),
@@ -749,7 +844,7 @@ def _total_active(ec) -> int:
 
 def _active_cur_count(ec) -> int:
     if ec._active_cur_count is None:
-        ec._active_cur_count = int(ec.active_cur.sum())
+        ec._active_cur_count = ec.scan["n_active_cur"]
     return ec._active_cur_count
 
 
@@ -801,20 +896,11 @@ def _justification_altair(ec) -> None:
         return
     from .phase0.epoch_processing import weigh_justification_and_finalization
 
-    unslashed = ~ec.slashed
-    prev_mask = (
-        ec.active_prev
-        & unslashed
-        & _flag_mask(ec, ec.prev_part, _TIMELY_TARGET_FLAG_INDEX)
-    )
-    cur_mask = (
-        ec.active_cur
-        & unslashed
-        & _flag_mask(ec, ec.cur_part, _TIMELY_TARGET_FLAG_INDEX)
-    )
+    # the pass's first stage: the effective balances are still the ones
+    # the sweep summed over the unslashed target flags
     total_active = _total_active(ec)
-    previous_target = max(ec.increment, int(ec.eff[prev_mask].sum()))
-    current_target = max(ec.increment, int(ec.eff[cur_mask].sum()))
+    previous_target = max(ec.increment, ec.scan["prev_target_eff"])
+    current_target = max(ec.increment, ec.scan["cur_target_eff"])
     weigh_justification_and_finalization(
         ec.state, total_active, previous_target, current_target, ec.context
     )
@@ -1149,10 +1235,14 @@ def _fused_route(ec, leaking: bool) -> bool:
     recovery = int(context.inactivity_score_recovery_rate)
     # the staged host path clamps pathological eff*score products through
     # exact Python ints — a kernel cannot; post-update scores are bounded
-    # by pre-update max + bias, so this guard covers the fused product
-    if ec.n and int(ec.eff.max(initial=0)) * (
-        int(ec.inact.max(initial=0)) + bias
-    ) >= 1 << 64:
+    # by pre-update max + bias, so this guard covers the fused product;
+    # the columns are the sweep's until a stage rebinds them
+    if ec.eff is ec.b_eff and ec.inact is ec.b_inact:
+        eff_max, inact_max = ec.scan["eff_max"], ec.scan["inact_max"]
+    else:
+        eff_max = int(ec.eff.max(initial=0))
+        inact_max = int(ec.inact.max(initial=0))
+    if ec.n and eff_max * (inact_max + bias) >= 1 << 64:
         _fused_fallback(ec, "u64_product", validators=ec.n)
         return False
     total_active = _total_active(ec)

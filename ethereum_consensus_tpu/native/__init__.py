@@ -29,6 +29,7 @@ __all__ = [
     "hash_level_native",
     "merkle_root_native",
     "merkle_groups_native",
+    "usable_cores",
     "install",
 ]
 
@@ -69,6 +70,13 @@ def _host_tag() -> bytes:
     return "\0".join(
         (platform.machine(), flags or platform.processor(), compiler)
     ).encode()
+
+
+@functools.lru_cache(maxsize=1)
+def usable_cores() -> int:
+    """The host cores this process may run on: what a native batch
+    spreads its threads over."""
+    return len(os.sched_getaffinity(0))
 
 
 def artifact_tag(sources, extra: str = "") -> str:

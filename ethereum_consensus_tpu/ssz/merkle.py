@@ -9,9 +9,7 @@ spec-tests/runners/light_client.rs:10-13).
 
 from __future__ import annotations
 
-import functools
-import os
-
+from ..native import usable_cores as _usable_cores
 from ..telemetry import metrics as _metrics
 from . import hash as _hash_mod
 from .hash import hash_bytes, hash_level, hash_pair
@@ -193,11 +191,6 @@ def _level_sum(count: int, depth: int) -> int:
 # than one host thread (ssz/core.py _splice_dirty_groups is the caller)
 _GROUPS_THREADED = _metrics.counter("ssz.group_roots.threaded")
 _GROUPS_INLINE = _metrics.counter("ssz.group_roots.inline")
-
-
-@functools.lru_cache(maxsize=1)
-def _usable_cores() -> int:
-    return len(os.sched_getaffinity(0))
 
 
 def merkleize_chunk_groups(

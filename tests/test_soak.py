@@ -27,6 +27,20 @@ from ethereum_consensus_tpu.soak import (  # noqa: E402
 )
 
 
+@pytest.fixture(autouse=True)
+def _unlatched_health():
+    """``/healthz`` reads process-wide latches (the ``pipeline.degraded``
+    and ``pipeline.broken`` gauges, the flight recorder's last broken
+    window) that a fault test of another file leaves set when it ran
+    earlier in the same worker; each soak starts from a pipeline that
+    nothing has broken yet, so its gate reads what the soak itself did."""
+    from ethereum_consensus_tpu.telemetry import flight, metrics
+
+    metrics.gauge("pipeline.degraded").set(0)
+    metrics.gauge("pipeline.broken").set(0)
+    flight.RECORDER.clear()
+
+
 def _smoke_config(**overrides):
     base = dict(
         cycles=3,
