@@ -78,7 +78,12 @@ its shape is ``fused_dispatch_rows(n)``, not ``n``); the fused span
 carries ``rows`` and ``padded``. The sweep before the pass (span
 ``epoch_vector.sync.scan``): ``epoch_vector.scan.threads`` (the host
 threads it ran on) and ``epoch_vector.scan.fallback`` (passes that took
-the numpy sequence, reason on a one-shot event).
+the numpy sequence, reason on a one-shot event). The period
+boundaries: span ``epoch_vector.sync_committee`` (children ``.active``,
+``.sample``, ``.aggregate``) and counter
+``epoch_vector.sync_committee.rotations``; span
+``epoch_vector.historical_summary`` and counter
+``epoch_vector.historical_summaries``.
 """
 
 from __future__ import annotations
@@ -1799,6 +1804,38 @@ def _count_pass(ec) -> None:
         metrics.counter("epoch_vector.rows_active").inc(ec._active_cur_count)
 
 
+def _sync_committee_updates(ec) -> None:
+    """``process_sync_committee_updates`` at a period boundary, step for
+    step, each of its three parts under a span of its own: the next
+    epoch's active-index tuple (from the committed columns, so the
+    sampler never walks the registry), the sampler itself (the literal
+    helper), and the parse and aggregate of the sampled keys."""
+    from ..crypto import bls
+    from .altair.containers import build
+    from .altair.helpers import get_next_sync_committee_indices
+
+    state, context, np = ec.state, ec.context, ec.np
+    next_epoch = ec.cur + 1
+    with trace.span("epoch_vector.sync_committee"):
+        with trace.span("epoch_vector.sync_committee.active"):
+            mask = (ec.act <= np.uint64(next_epoch)) & (
+                np.uint64(next_epoch) < ec.exit
+            )
+            _seed_active_indices(ec, next_epoch, mask)
+        with trace.span("epoch_vector.sync_committee.sample"):
+            indices = get_next_sync_committee_indices(state, context)
+        with trace.span("epoch_vector.sync_committee.aggregate"):
+            public_keys = [bytes(state.validators[i].public_key) for i in indices]
+            aggregate = bls.eth_aggregate_public_keys(
+                [bls.PublicKey.from_bytes(key) for key in public_keys]
+            )
+        state.current_sync_committee = state.next_sync_committee
+        state.next_sync_committee = build(context.preset).SyncCommittee(
+            public_keys=public_keys, aggregate_public_key=aggregate.to_bytes()
+        )
+    metrics.counter("epoch_vector.sync_committee.rotations").inc()
+
+
 class _PassComplete(Exception):
     """Internal control flow: a stage finished the pass itself (the
     literal overflow mirrors, which must raise the structured error
@@ -1913,12 +1950,16 @@ def process_epoch_columnar(state, context, fork: str) -> bool:
             )
 
             process_historical_roots_update(state, context)
-        else:
+        elif (ec.cur + 1) % (
+            int(context.SLOTS_PER_HISTORICAL_ROOT) // int(context.SLOTS_PER_EPOCH)
+        ) == 0:
             from .capella.epoch_processing import (
                 process_historical_summaries_update,
             )
 
-            process_historical_summaries_update(state, context)
+            with trace.span("epoch_vector.historical_summary"):
+                process_historical_summaries_update(state, context)
+            metrics.counter("epoch_vector.historical_summaries").inc()
         with trace.span("epoch_vector.rotation"):
             if cfg["family"] == "phase0":
                 from .committees import drop_masks_memo
@@ -1937,21 +1978,9 @@ def process_epoch_columnar(state, context, fork: str) -> bool:
                 ops_vector.install_zero_column(
                     state.current_epoch_participation, n, 0xFF
                 )
-        if cfg["family"] == "altair":
-            next_epoch = ec.cur + 1
-            if next_epoch % int(context.EPOCHS_PER_SYNC_COMMITTEE_PERIOD) == 0:
-                # the sampling sweep reads the committed registry; seed
-                # its active-index tuple from the committed columns so
-                # the rare boundary stays walk-free too
-                np = ec.np
-                mask = (ec.act <= np.uint64(next_epoch)) & (
-                    np.uint64(next_epoch) < ec.exit
-                )
-                _seed_active_indices(ec, next_epoch, mask)
-                from .altair.epoch_processing import (
-                    process_sync_committee_updates,
-                )
-
-                process_sync_committee_updates(state, context)
+        if cfg["family"] == "altair" and (ec.cur + 1) % int(
+            context.EPOCHS_PER_SYNC_COMMITTEE_PERIOD
+        ) == 0:
+            _sync_committee_updates(ec)
     _count_pass(ec)
     return True
