@@ -81,13 +81,17 @@ threads it ran on) and ``epoch_vector.scan.fallback`` (passes that took
 the numpy sequence, reason on a one-shot event). The period
 boundaries: span ``epoch_vector.sync_committee`` (children ``.active``,
 ``.sample``, ``.aggregate``) and counter
-``epoch_vector.sync_committee.rotations``; span
+``epoch_vector.sync_committee.rotations``; the sampler's counters
+``epoch_vector.sync_committee.batched`` (rotations it drew natively),
+``.candidates`` (candidates drawn) and ``.fallback`` (rotations the
+literal helper drew, reason on an event); span
 ``epoch_vector.historical_summary`` and counter
 ``epoch_vector.historical_summaries``.
 """
 
 from __future__ import annotations
 
+import hashlib
 import threading
 
 from .. import _device_flags, _env
@@ -861,12 +865,12 @@ def _churn_limit(ec) -> int:
     )
 
 
-def _seed_active_indices(ec, epoch: int, mask) -> tuple:
+def _seed_active_indices(ec, epoch: int, mask, rows=None) -> tuple:
     """Materialize (once) the active-index tuple for ``epoch`` from the
     columns and install it in the state's ``_active_idx_cache`` with the
     helper's exact rebind discipline — the committee machinery (phase0
     pendings, sync-committee sampling) then never pays the per-validator
-    sweep."""
+    sweep. ``rows``: ``mask``'s row numbers, where the caller has them."""
     state = ec.state
     key = (epoch, ec.n)
     cache = state.__dict__.get("_active_idx_cache")
@@ -877,7 +881,9 @@ def _seed_active_indices(ec, epoch: int, mask) -> tuple:
         items = list(cache.items())
     else:
         items = []
-    out = tuple(ec.np.nonzero(mask)[0].tolist())
+    if rows is None:
+        rows = ec.np.nonzero(mask)[0]
+    out = tuple(rows.tolist())
     if len(items) >= 4:
         items = items[1:]
     state.__dict__["_active_idx_cache"] = dict(items + [(key, out)])
@@ -1804,15 +1810,77 @@ def _count_pass(ec) -> None:
         metrics.counter("epoch_vector.rows_active").inc(ec._active_cur_count)
 
 
+def _sampler_fallback(reason: str) -> None:
+    """A rotation whose committee the literal helper drew: counted, with
+    its reason on a trace event (a rotation is rare: no one-shot guard)."""
+    metrics.counter("epoch_vector.sync_committee.fallback").inc()
+    trace.event("epoch_vector.sync_committee.fallback", reason=reason)
+
+
+def _sample_sync_committee(ec, active, seed: bytes) -> "list[int] | None":
+    """``get_next_sync_committee_indices`` step for step, with candidates
+    drawn ``SYNC_COMMITTEE_SIZE`` at a time: a block's shuffled positions
+    in one native swap-or-not pass over all of them
+    (``native.shuffle_positions``), its random bytes from one digest per 32
+    candidates, its acceptance tested in the literal order against the
+    ``eff`` column, which ``_commit`` has written to the validators (so it
+    is the post-hysteresis balance the literal helper reads); the next
+    block only while the committee is short. ``active`` is the next
+    epoch's active rows. None, counted, where the native library is not
+    loaded or nobody is active: the caller then asks the literal helper."""
+    from .. import native
+
+    if not native.available():
+        _sampler_fallback("native_unavailable")
+        return None
+    if len(active) == 0:
+        _sampler_fallback("no_active")
+        return None
+    np, context = ec.np, ec.context
+    size = int(context.SYNC_COMMITTEE_SIZE)
+    count = len(active)
+    max_balance = np.uint64(int(context.MAX_EFFECTIVE_BALANCE))
+    indices: list = []
+    start = 0
+    while len(indices) < size:
+        shuffled, _ = native.shuffle_positions(
+            seed, count, int(context.SHUFFLE_ROUND_COUNT),
+            np.arange(start, start + size, dtype=np.uint64) % np.uint64(count),
+        )
+        candidates = active[shuffled]
+        first, last = start // 32, (start + size - 1) // 32
+        random_bytes = np.frombuffer(
+            b"".join(
+                hashlib.sha256(seed + k.to_bytes(8, "little")).digest()
+                for k in range(first, last + 1)
+            ),
+            dtype=np.uint8,
+        )[start % 32:start % 32 + size]
+        # effective * 255 >= MAX_EFFECTIVE_BALANCE * random_byte, as the
+        # least balance that passes, so that no u64 product can wrap
+        least = (
+            max_balance * random_bytes.astype(np.uint64) + np.uint64(254)
+        ) // np.uint64(255)
+        accepted = candidates[ec.eff[candidates] >= least]
+        indices.extend(accepted[:size - len(indices)].tolist())
+        start += size
+    metrics.counter("epoch_vector.sync_committee.batched").inc()
+    metrics.counter("epoch_vector.sync_committee.candidates").inc(start)
+    trace.note(candidates=start, blocks=start // size)
+    return indices
+
+
 def _sync_committee_updates(ec) -> None:
     """``process_sync_committee_updates`` at a period boundary, step for
     step, each of its three parts under a span of its own: the next
     epoch's active-index tuple (from the committed columns, so the
-    sampler never walks the registry), the sampler itself (the literal
-    helper), and the parse and aggregate of the sampled keys."""
+    sampler never walks the registry), the sampler
+    (``_sample_sync_committee``, the literal helper where it declines),
+    and the parse and aggregate of the sampled keys."""
     from ..crypto import bls
+    from ..domains import DomainType
     from .altair.containers import build
-    from .altair.helpers import get_next_sync_committee_indices
+    from .altair.helpers import get_next_sync_committee_indices, get_seed
 
     state, context, np = ec.state, ec.context, ec.np
     next_epoch = ec.cur + 1
@@ -1821,9 +1889,13 @@ def _sync_committee_updates(ec) -> None:
             mask = (ec.act <= np.uint64(next_epoch)) & (
                 np.uint64(next_epoch) < ec.exit
             )
-            _seed_active_indices(ec, next_epoch, mask)
+            active = np.nonzero(mask)[0]
+            _seed_active_indices(ec, next_epoch, mask, active)
         with trace.span("epoch_vector.sync_committee.sample"):
-            indices = get_next_sync_committee_indices(state, context)
+            seed = get_seed(state, next_epoch, DomainType.SYNC_COMMITTEE, context)
+            indices = _sample_sync_committee(ec, active, seed)
+            if indices is None:
+                indices = get_next_sync_committee_indices(state, context)
         with trace.span("epoch_vector.sync_committee.aggregate"):
             public_keys = [bytes(state.validators[i].public_key) for i in indices]
             aggregate = bls.eth_aggregate_public_keys(

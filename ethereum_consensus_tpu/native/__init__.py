@@ -5,6 +5,8 @@ c-kzg, sha2 — SURVEY.md L0); this package is the equivalent native layer
 here: a from-scratch C++ SHA-256 merkle library compiled on first use with
 the system toolchain and loaded via ctypes (no pybind11 in this image).
 Falls back cleanly to the pure-Python path when no compiler is available.
+The same library holds the swap-or-not shuffle's per-index pass
+(``shuffle_positions``), which reuses its SHA-256 lane routines.
 
 ``install()`` registers the native hasher with ssz.hash so every
 hash_tree_root below the device threshold runs native.
@@ -29,6 +31,7 @@ __all__ = [
     "hash_level_native",
     "merkle_root_native",
     "merkle_groups_native",
+    "shuffle_positions",
     "usable_cores",
     "install",
 ]
@@ -169,6 +172,11 @@ def load():
         ctypes.c_uint32,
     ]
     lib.ec_merkle_groups.restype = ctypes.c_uint32
+    lib.ec_shuffle_positions.argtypes = [
+        ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint32, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_size_t,
+    ]
+    lib.ec_shuffle_positions.restype = ctypes.c_uint32
     lib.ec_version.restype = ctypes.c_uint64
     _LIB = lib
     return lib
@@ -222,6 +230,35 @@ def merkle_groups_native(
     )
     del buf  # the bytearray may be resized again once its export is gone
     return (out.raw, used) if used else None
+
+
+def shuffle_positions(seed: bytes, count: int, rounds: int, positions):
+    """``compute_shuffled_index(i, count, seed)`` for every ``i`` of the
+    1-D ``uint64`` numpy array ``positions``, in one native call that runs
+    the rounds over all of them at once. Returns the shuffled positions as
+    a new array and the widest lane routine the library hashed with (8 or
+    1). ``ValueError`` for a seed not of 32 bytes, an array not so laid out,
+    or what ``ec_shuffle_positions`` refuses: ``count`` outside 1..2^40,
+    more than 256 rounds, a position not below ``count``."""
+    lib = _require_lib()
+    if len(seed) != 32:
+        raise ValueError("shuffle: the seed is 32 bytes")
+    if not (
+        positions.dtype == "uint64"
+        and positions.ndim == 1
+        and positions.flags.c_contiguous
+    ):
+        raise ValueError("shuffle: positions must be a contiguous uint64 array")
+    out = positions.copy()
+    lanes = lib.ec_shuffle_positions(
+        seed, count, rounds, positions.ctypes.data, out.ctypes.data,
+        positions.size,
+    )
+    if not lanes:
+        raise ValueError(
+            "shuffle: 1 <= count <= 2^40, at most 256 rounds, positions below count"
+        )
+    return out, int(lanes)
 
 
 def install() -> bool:

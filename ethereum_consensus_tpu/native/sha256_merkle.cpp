@@ -17,6 +17,9 @@
 //                             uint32_t group_depth,
 //                             const uint8_t* zero_hashes, uint8_t* out,
 //                             uint32_t n_threads);
+//   uint32_t ec_shuffle_positions(const uint8_t* seed32, uint64_t count,
+//                                 uint32_t rounds, const uint64_t* in,
+//                                 uint64_t* out, size_t n);
 //   uint64_t ec_version(void);
 
 #include <algorithm>
@@ -128,6 +131,19 @@ inline void sha256_64(const uint8_t* in, uint8_t* out) {
   compress(state, w);
   compress(state, PAD_BLOCK);
   for (int i = 0; i < 8; ++i) store_be32(out + 4 * i, state[i]);
+}
+
+// The one-block lane routines of the swap-or-not shuffle: each lane's
+// message is a single block, already padded, given as 16 words with word t
+// of lane j at w[t * stride + j]; the digest's word i of lane j goes to
+// out[i * stride + j]. This is the scalar one.
+inline void sha256_block(const uint32_t* w_in, size_t stride, uint32_t* out) {
+  uint32_t w[16];
+  for (int t = 0; t < 16; ++t) w[t] = w_in[t * stride];
+  uint32_t state[8];
+  std::memcpy(state, H0, sizeof(H0));
+  compress(state, w);
+  for (int i = 0; i < 8; ++i) out[i * stride] = state[i];
 }
 
 #ifdef EC_SHA_NI_ACTIVE
@@ -342,6 +358,46 @@ inline void sha256_64_x8(const uint8_t* in, uint8_t* out) {
     for (int i = 0; i < 8; ++i) {
       store_be32(out + 32 * lane + 4 * i, lanes[i][lane]);
     }
+  }
+}
+
+// eight one-block lanes (layout: sha256_block): the data block of
+// sha256_64_x8 alone
+inline void sha256_block_x8(const uint32_t* w_in, size_t stride,
+                            uint32_t* out) {
+  __m256i a = _mm256_set1_epi32(int(H0[0]));
+  __m256i b = _mm256_set1_epi32(int(H0[1]));
+  __m256i c = _mm256_set1_epi32(int(H0[2]));
+  __m256i d = _mm256_set1_epi32(int(H0[3]));
+  __m256i e = _mm256_set1_epi32(int(H0[4]));
+  __m256i f = _mm256_set1_epi32(int(H0[5]));
+  __m256i g = _mm256_set1_epi32(int(H0[6]));
+  __m256i h = _mm256_set1_epi32(int(H0[7]));
+  __m256i w[16];
+  for (int t = 0; t < 16; ++t) {
+    w[t] = _mm256_loadu_si256(
+        reinterpret_cast<const __m256i*>(w_in + t * stride));
+  }
+  for (int t = 0; t < 64; ++t) {
+    if (t >= 16) {
+      __m256i w15 = w[(t - 15) & 15], w2 = w[(t - 2) & 15];
+      __m256i s0 = _mm256_xor_si256(
+          _mm256_xor_si256(rotr8(w15, 7), rotr8(w15, 18)),
+          _mm256_srli_epi32(w15, 3));
+      __m256i s1 = _mm256_xor_si256(
+          _mm256_xor_si256(rotr8(w2, 17), rotr8(w2, 19)),
+          _mm256_srli_epi32(w2, 10));
+      w[t & 15] = _mm256_add_epi32(
+          _mm256_add_epi32(w[t & 15], s0),
+          _mm256_add_epi32(w[(t - 7) & 15], s1));
+    }
+    EC_ROUND8(w[t & 15]);
+  }
+  const __m256i state[8] = {a, b, c, d, e, f, g, h};
+  for (int i = 0; i < 8; ++i) {
+    _mm256_storeu_si256(
+        reinterpret_cast<__m256i*>(out + i * stride),
+        _mm256_add_epi32(state[i], _mm256_set1_epi32(int(H0[i]))));
   }
 }
 
@@ -611,6 +667,85 @@ uint32_t ec_merkle_groups(const uint8_t* raw, size_t raw_len,
   work(0);
   for (auto& th : pool) th.join();
   return uint32_t(pool.size() + 1);
+}
+
+// The swap-or-not shuffle of the consensus specification, per index and
+// round-major: out[j] = compute_shuffled_index(in[j], count, seed) for each
+// of the n lanes. Each round's pivot is hashed once. The lanes then go
+// through every round a tile at a time, so the scratch is the tile's, on
+// the stack: a round hashes each lane's source block (seed + round +
+// uint32_le(position >> 8), one padded block) eight lanes at once where the
+// build has AVX2, the scalar routine taking the tail. Runs on the calling
+// thread. Returns the widest lane routine it has (8, or 1), and 0, having
+// written nothing, for count outside 1..2^40 (a source block's number is
+// 4 bytes), more than 256 rounds (the round is 1 byte), or an input not
+// below count.
+uint32_t ec_shuffle_positions(const uint8_t* seed32, uint64_t count,
+                              uint32_t rounds, const uint64_t* in,
+                              uint64_t* out, size_t n) {
+  if (count == 0 || count > (uint64_t(1) << 40) || rounds > 256) return 0;
+  for (size_t j = 0; j < n; ++j) {
+    if (in[j] >= count) return 0;
+  }
+  uint32_t seed[8];
+  for (int t = 0; t < 8; ++t) seed[t] = load_be32(seed32 + 4 * t);
+  // the pivot: the first 8 bytes, little-endian, of SHA-256(seed + round)
+  uint64_t pivots[256];
+  for (uint32_t r = 0; r < rounds; ++r) {
+    uint32_t pw[16] = {0};
+    std::memcpy(pw, seed, sizeof(seed));
+    pw[8] = (r << 24) | 0x800000u;
+    pw[15] = 33 * 8;
+    uint32_t ps[8];
+    std::memcpy(ps, H0, sizeof(H0));
+    compress(ps, pw);
+    pivots[r] = (uint64_t(__builtin_bswap32(ps[0])) |
+                 uint64_t(__builtin_bswap32(ps[1])) << 32) %
+                count;
+  }
+  constexpr size_t kTile = 256;
+  uint32_t words[16 * kTile], digest[8 * kTile];
+  uint64_t flip[kTile];
+  std::copy(in, in + n, out);
+  for (size_t base = 0; base < n; base += kTile) {
+    const size_t m = std::min(kTile, n - base);
+    uint64_t* lane = out + base;
+    // the 37-byte message's words but its round and position: the seed,
+    // the padding's zeros and its bit length
+    for (int t = 0; t < 8; ++t) {
+      std::fill(words + t * m, words + (t + 1) * m, seed[t]);
+    }
+    std::fill(words + 10 * m, words + 15 * m, 0u);
+    std::fill(words + 15 * m, words + 16 * m, uint32_t(37 * 8));
+    for (uint32_t r = 0; r < rounds; ++r) {
+      for (size_t j = 0; j < m; ++j) {
+        const uint64_t index = lane[j];
+        flip[j] = (pivots[r] + count - index) % count;
+        const uint64_t q = std::max(index, flip[j]) >> 8;
+        words[8 * m + j] = (r << 24) | uint32_t(q & 0xff) << 16 |
+                           uint32_t((q >> 8) & 0xff) << 8 |
+                           uint32_t((q >> 16) & 0xff);
+        words[9 * m + j] = uint32_t((q >> 24) & 0xff) << 24 | 0x800000u;
+      }
+      size_t g = 0;
+#ifdef EC_AVX2_ACTIVE
+      for (; g + 8 <= m; g += 8) sha256_block_x8(words + g, m, digest + g);
+#endif
+      for (; g < m; ++g) sha256_block(words + g, m, digest + g);
+      for (size_t j = 0; j < m; ++j) {
+        const uint64_t position = std::max(lane[j], flip[j]);
+        const uint32_t byte_at = uint32_t(position & 255) >> 3;
+        const uint32_t word = digest[(byte_at >> 2) * m + j];
+        const uint32_t byte = (word >> (24 - 8 * (byte_at & 3))) & 0xff;
+        if ((byte >> (position & 7)) & 1) lane[j] = flip[j];
+      }
+    }
+  }
+#ifdef EC_AVX2_ACTIVE
+  return 8;
+#else
+  return 1;
+#endif
 }
 
 uint64_t ec_version(void) { return 1; }
