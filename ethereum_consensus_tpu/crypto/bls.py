@@ -160,10 +160,12 @@ class SecretKey:
 
 
 # process-wide decompressed-pubkey cache (FIFO eviction): compressed48 →
-# affine raw96 of a VALID key. Entries enter ONLY from from_bytes'
-# subgroup-checked, identity-rejecting decompression, so a hit proves
-# validity; raw_uncompressed (which skips the subgroup check and accepts
-# identity aggregates) reads but never writes it. ~15MB at capacity.
+# affine raw96 of a VALID key. Entries enter ONLY from the
+# subgroup-checked, identity-rejecting decompressions of from_bytes and
+# warm_pubkey_cache, so a hit proves validity (eth_aggregate_public_keys
+# sums hits without checking them again); raw_uncompressed (which skips
+# the subgroup check and accepts identity aggregates) reads but never
+# writes it. ~15MB at capacity.
 _RAW_PK_CACHE: "dict[bytes, bytes]" = {}
 _RAW_PK_CACHE_MAX = 1 << 16
 # inserts/evictions serialize: the chain pipeline fills this cache from
@@ -185,6 +187,8 @@ _WARM_CALLS = _metrics.counter("bls.warm_raw_keys.calls")
 _WARM_KEYS = _metrics.counter("bls.warm_raw_keys.keys")
 _ROUTE_DEVICE = _metrics.counter("bls.pairing_route.device")
 _ROUTE_HOST = _metrics.counter("bls.pairing_route.host")
+_AGG_FROM_CACHE = _metrics.counter("bls.aggregate_pubkeys.from_cache")
+_AGG_DECOMPRESSED = _metrics.counter("bls.aggregate_pubkeys.decompressed")
 
 # which route proved the most recent batched verification on THIS thread
 # ("device" / "host" / None before any batch) — the flight recorder's
@@ -683,9 +687,19 @@ def eth_aggregate_public_keys(public_keys: list[PublicKey]) -> PublicKey:
     if not public_keys:
         raise InvalidPublicKeyError("cannot aggregate zero public keys")
     if _native():
-        rc, out = native_bls.aggregate_public_keys(
-            [pk.to_bytes() for pk in public_keys]
-        )
+        # a _RAW_PK_CACHE entry passed KeyValidate when it entered, so
+        # when every key hits, its affine point is summed as it is (no
+        # sqrt, no subgroup check). pk._raw alone proves nothing: the
+        # deferred registry parse fills it without the subgroup check.
+        keys = [pk.to_bytes() for pk in public_keys]
+        raws = [_RAW_PK_CACHE.get(key) for key in keys]
+        if None not in raws:
+            rc, out = native_bls.aggregate_public_keys_raw(raws)
+            if rc == 0:
+                _AGG_FROM_CACHE.inc()
+                return PublicKey._from_valid_bytes(out)
+        _AGG_DECOMPRESSED.inc()
+        rc, out = native_bls.aggregate_public_keys(keys)
         if rc == 0:
             return PublicKey._from_valid_bytes(out)
         raise InvalidPublicKeyError(native_bls.decode_error_message(rc))

@@ -37,6 +37,7 @@ __all__ = [
     "aggregate_verify",
     "aggregate_signatures",
     "aggregate_public_keys",
+    "aggregate_public_keys_raw",
     "batch_verify",
     "g1_msm",
     "g2_msm",
@@ -95,6 +96,7 @@ def _declare(lib) -> None:
         "ec_bls_aggregate_verify": ([p8, sz, p8, _u32p, p8, sz, p8, i32], i32),
         "ec_bls_aggregate_sigs": ([p8, sz, p8], i32),
         "ec_bls_aggregate_pubkeys": ([p8, sz, p8], i32),
+        "ec_bls_aggregate_pubkeys_raw": ([p8, sz, p8], i32),
         "ec_bls_batch_verify": ([sz, _u32p, p8, p8, _u32p, p8, p8, sz, p8], i32),
         "ec_bls_batch_verify_raw": ([sz, _u32p, p8, p8, _u32p, p8, p8, sz, p8], i32),
         "ec_miller_loop_raw": ([p8, p8, p8], i32),
@@ -285,6 +287,18 @@ def aggregate_signatures(sigs: list[bytes]) -> tuple[int, bytes]:
 def aggregate_public_keys(pks: list[bytes]) -> tuple[int, bytes]:
     out = _c.create_string_buffer(48)
     rc = _lib().ec_bls_aggregate_pubkeys(b"".join(bytes(p) for p in pks), len(pks), out)
+    return rc, out.raw
+
+
+def aggregate_public_keys_raw(raws: list[bytes]) -> tuple[int, bytes]:
+    """eth_aggregate_pubkeys from cached raw affine pubkeys (96 bytes
+    each, subgroup-checked at parse): (rc, compressed48); -5 for a key
+    off the curve or the identity."""
+    cat = b"".join(raws)
+    if len(cat) != 96 * len(raws):
+        raise ValueError("each raw affine pubkey must be 96 bytes")
+    out = _c.create_string_buffer(48)
+    rc = _lib().ec_bls_aggregate_pubkeys_raw(cat, len(raws), out)
     return rc, out.raw
 
 

@@ -5117,6 +5117,20 @@ int ec_bls_fast_aggregate_verify_raw(const u8* pks_raw, size_t n,
   return pairing_product_is_one(ps, qs, 2) ? 1 : 0;
 }
 
+// eth_aggregate_pubkeys from PRE-DECOMPRESSED raw affine pubkeys (the
+// PublicKey cache): no per-key sqrt or subgroup check, which every key
+// passed when it entered the cache; on-curve is re-checked and an
+// identity key refused (-5, as the verify path). The compression is
+// ec_bls_aggregate_pubkeys', the identity sum included.
+int ec_bls_aggregate_pubkeys_raw(const u8* pks_raw, size_t n, u8* out48) {
+  ensure_init();
+  if (n == 0) return -1;
+  G1 acc;
+  if (!g1_sum_raw(acc, pks_raw, n)) return -5;
+  g1_compress(out48, acc);
+  return 0;
+}
+
 int ec_bls_aggregate_verify(const u8* pks, size_t n, const u8* msgs,
                             const u32* msg_lens, const u8* dst, size_t dst_len,
                             const u8* sig96, int assume_valid) {

@@ -441,3 +441,146 @@ def test_msm_same_point_annihilating_digits():
             else:
                 acc, acc_inf = nb.g1_add_raw(acc, acc_inf, m, mi)
         assert got_inf == acc_inf and (got_inf or got == acc), n
+
+
+# -- eth_aggregate_public_keys from the decompressed-pubkey cache ---------------
+
+_AGG_COUNTERS = ("from_cache", "decompressed")
+# (0, 2) is on the curve and outside the order-r subgroup
+_OUTSIDE_THE_SUBGROUP = bytes([0x80]) + bytes(47)
+
+
+def _agg_counts():
+    from ethereum_consensus_tpu.telemetry import metrics
+
+    return {
+        name: metrics.counter(f"bls.aggregate_pubkeys.{name}").value()
+        for name in _AGG_COUNTERS
+    }
+
+
+def _agg_moved(before):
+    after = _agg_counts()
+    return {name: after[name] - before[name] for name in _AGG_COUNTERS}
+
+
+@pytest.fixture(scope="module")
+def distinct_keys():
+    return [bls.SecretKey(90_001 + i).public_key().to_bytes() for i in range(512)]
+
+
+def _negated(key: bytes) -> bytes:
+    # the sign flag picks the other root y: the compressed encoding of -P
+    return bytes([key[0] ^ 0x20]) + key[1:]
+
+
+def _aggregate_case(kind: str, n: int, base: list) -> list:
+    if kind == "distinct":
+        return base[:n]
+    if kind == "repeated":
+        # the serial chain's second add and lane 1's first eight-wide add
+        # both meet their own partial sum: the doubling case
+        return [base[0]] + [base[i % 8] for i in range(n - 1)]
+    if kind == "negated_neighbour":
+        # k, -k side by side: a partial sum (and, at even n, the whole
+        # sum) is the identity
+        keys = []
+        for i in range(n):
+            keys.append(_negated(keys[-1]) if i % 2 else base[i])
+        return keys
+    if kind == "negated_lane":
+        # every second block of eight negates the block before it: each
+        # eight-wide lane's running sum returns to the identity
+        return [
+            _negated(base[i - 8]) if (i // 8) % 2 else base[i] for i in range(n)
+        ]
+    raise AssertionError(kind)
+
+
+@pytest.mark.parametrize("n", [1, 7, 31, 32, 33, 512])
+@pytest.mark.parametrize(
+    "kind", ["distinct", "repeated", "negated_neighbour", "negated_lane"]
+)
+def test_cached_aggregate_equals_the_compressed_call(kind, n, distinct_keys):
+    force_backend("native")
+    keys = _aggregate_case(kind, n, distinct_keys)
+    public_keys = [bls.PublicKey.from_bytes(key) for key in keys]
+    assert all(key in bls._RAW_PK_CACHE for key in keys)
+    rc, want = native_bls.aggregate_public_keys(keys)
+    assert rc == 0
+    before = _agg_counts()
+    got = bls.eth_aggregate_public_keys(public_keys).to_bytes()
+    assert got == want
+    assert _agg_moved(before) == {"from_cache": 1, "decompressed": 0}
+    if kind == "negated_neighbour" and n % 2 == 0:
+        assert got == bytes([0xC0]) + bytes(47)  # the identity
+
+
+def _deferred_keys(keys: list, filler: str) -> list:
+    """Keys parsed as the registry parses them, their affine form filled
+    without the subgroup check, none of them in the validated cache."""
+    for key in keys:
+        bls._RAW_PK_CACHE.pop(key, None)
+    public_keys = [bls.PublicKey.from_validated_bytes(key) for key in keys]
+    if filler == "warm_raw_keys":
+        bls.warm_raw_keys(public_keys)
+    else:
+        for pk in public_keys:
+            pk.raw_uncompressed()
+    assert all(pk._raw is not None for pk in public_keys)
+    assert not any(key in bls._RAW_PK_CACHE for key in keys)
+    return public_keys
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        "raw_uncompressed",
+        "warm_raw_keys",
+        "outside_the_subgroup.raw_uncompressed",
+        "outside_the_subgroup.warm_raw_keys",
+        "evicted",
+        "empty",
+    ],
+)
+def test_aggregate_falls_back_to_the_compressed_call(case, distinct_keys):
+    force_backend("native")
+    keys = [bls.SecretKey(70_001 + i).public_key().to_bytes() for i in range(9)]
+    before = _agg_counts()
+    if case == "empty":
+        with pytest.raises(InvalidPublicKeyError, match="zero public keys"):
+            bls.eth_aggregate_public_keys([])
+        assert _agg_moved(before) == {"from_cache": 0, "decompressed": 0}
+        return
+    if case.startswith("outside_the_subgroup"):
+        keys[3] = _OUTSIDE_THE_SUBGROUP
+        public_keys = _deferred_keys(keys, case.split(".")[1])
+        with pytest.raises(InvalidPublicKeyError) as raised:
+            bls.eth_aggregate_public_keys(public_keys)
+        assert str(raised.value) == native_bls.decode_error_message(-6)
+        assert _agg_moved(before) == {"from_cache": 0, "decompressed": 1}
+        return
+    if case == "evicted":
+        public_keys = [bls.PublicKey.from_bytes(key) for key in keys]
+        bls._RAW_PK_CACHE.pop(keys[5])
+        assert public_keys[5]._raw is not None
+    else:
+        # the first key validated and cached, the other eight deferred
+        # (warm_raw_keys fills nothing below eight keys)
+        public_keys = [bls.PublicKey.from_bytes(keys[0])]
+        public_keys += _deferred_keys(keys[1:], case)
+    rc, want = native_bls.aggregate_public_keys(keys)
+    assert rc == 0
+    assert bls.eth_aggregate_public_keys(public_keys).to_bytes() == want
+    assert _agg_moved(before) == {"from_cache": 0, "decompressed": 1}
+
+
+def test_raw_aggregate_refuses_the_identity_and_off_curve_points(distinct_keys):
+    raws = [native_bls.g1_decompress(k, check_subgroup=True)[1] for k in distinct_keys[:40]]
+    for n in (3, 40):  # the serial chain and the eight-wide sum
+        assert native_bls.aggregate_public_keys_raw(raws[:n])[0] == 0
+        for bad in (bytes(96), raws[1][:95] + bytes([raws[1][95] ^ 1])):
+            assert native_bls.aggregate_public_keys_raw(raws[: n - 1] + [bad])[0] == -5
+    assert native_bls.aggregate_public_keys_raw([])[0] == -1
+    with pytest.raises(ValueError):
+        native_bls.aggregate_public_keys_raw(raws[:2] + [raws[2][:48]])

@@ -217,6 +217,21 @@ def test_the_crossing_equals_the_period_reference(seed, fused_route):
     assert int(state.finalized_checkpoint.epoch) == ENTERED - 2
 
 
+def test_the_rotation_sums_the_cached_keys(fused_route):
+    """The sampled keys are parsed (and so cached, subgroup-checked) before
+    the aggregate: it sums their cached affine points in one native call
+    and decompresses nothing again, and the root is the reference's."""
+    world = period_world(41)
+    state = world.pre.copy()
+    names = ("from_cache", "decompressed")
+    before = [metrics.counter(f"bls.aggregate_pubkeys.{n}").value() for n in names]
+    assert cross(state, world) == reference_root(41)
+    after = [metrics.counter(f"bls.aggregate_pubkeys.{n}").value() for n in names]
+    assert [a - b for a, b in zip(after, before)] == [1, 0]
+    public, aggregate = committee(state.next_sync_committee)
+    assert aggregate == g1.eth_aggregate_pubkeys(public)
+
+
 PLANTS = faults_period.FAULTS + [faults_period.CONTROL]
 
 
