@@ -2,7 +2,7 @@
 
 PY ?= python
 
-.PHONY: help test test-all speclint speclint-json speclint-sarif speclint-changed speclint-all forkdiff bench chip-smoke bench-smoke bench-diff bench-trend chaos mesh-smoke mem-smoke pool-smoke proofs-smoke soak-smoke trace-smoke pipeline-selfcheck trace metrics profile serve serve-data server-smoke serving-smoke
+.PHONY: help test test-all speclint speclint-json speclint-sarif speclint-changed speclint-all forkdiff chip-smoke bench-smoke chaos mesh-smoke mem-smoke pool-smoke proofs-smoke soak-smoke trace-smoke pipeline-selfcheck trace metrics profile serve serve-data server-smoke serving-smoke
 
 PROFILE_DIR ?= profile_artifacts
 
@@ -35,9 +35,6 @@ speclint-all:  ## include allowlisted findings in the listing
 forkdiff:  ## regenerate docs/FORKDIFF.md from the fork-diff machinery
 	$(PY) -m tools.speclint --write-forkdiff
 
-bench:  ## full benchmark battery (bench.py; needs a TPU — exits 3 without one, sizes never depend on the backend)
-	$(PY) bench.py
-
 chip-smoke:  ## the main path end to end on ONE TPU chip at the 2^20-validator deneb deployment (chip_smoke.py; on a four-chip host `$(PY) chip_smoke.py --chips 4` runs the mesh path instead); fails without a TPU
 	$(PY) chip_smoke.py
 
@@ -65,12 +62,6 @@ soak-smoke:  ## short deterministic production soak: storm + faults + readers + 
 
 trace-smoke:  ## causal trace plane: one end-to-end linked trace on a 2-lane pipelined replay, zero dropped spans
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_trace_plane.py -q -m trace_smoke
-
-bench-diff:  ## per-phase diff of two bench evidence files: make bench-diff A=old.json B=new.json
-	$(PY) bench_compare.py $(A) $(B)
-
-bench-trend:  ## per-phase seconds across every BENCH_r*.json as a markdown table
-	$(PY) bench_compare.py --trend $(sort $(wildcard BENCH_r*.json))
 
 pipeline-selfcheck:  ## pipeline smoke: seq-vs-pipelined bit identity
 	JAX_PLATFORMS=cpu $(PY) -m ethereum_consensus_tpu.pipeline --selfcheck

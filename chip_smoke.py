@@ -44,13 +44,14 @@ import importlib
 import json
 import os
 import sys
-import threading
 import time
 from contextlib import contextmanager
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tests"))  # the chain generator
+
+from benchmark import meters  # noqa: E402
 
 FORK = "deneb"
 VALIDATORS = 1 << 20
@@ -82,56 +83,18 @@ def require(condition, message: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# readings: compiles (jax.monitoring), transfers and routes (the device
-# observatory), declines (the metrics registry)
+# readings: the benchmark's meters (benchmark/meters.py) — compiles,
+# transfers and routes, declines
 # ---------------------------------------------------------------------------
 
 
-class CompileMeter:
-    """Counts what jax compiles, from jax's own monitoring events: every
-    executable it builds or loads (``requests``, with the seconds spent),
-    and of those how many the persistent cache did not hold (``misses`` —
-    real compiles) or did (``hits``)."""
-
-    _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-    _MISS = "/jax/compilation_cache/cache_misses"
-    _HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        import jax.monitoring
-
-        self._lock = threading.Lock()  # the verifier thread compiles too
-        self._totals = {
-            "compile_s": 0.0, "compiles": 0, "cache_misses": 0,
-            "cache_hits": 0,
-        }
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **_) -> None:
-        if event == self._BACKEND_COMPILE:
-            with self._lock:
-                self._totals["compile_s"] += duration
-                self._totals["compiles"] += 1
-
-    def _event(self, event: str, **_) -> None:
-        key = {self._MISS: "cache_misses", self._HIT: "cache_hits"}.get(event)
-        if key:
-            with self._lock:
-                self._totals[key] += 1
-
-    def read(self) -> dict:
-        with self._lock:
-            return dict(self._totals)
+_METER: "meters.CompileMeter | None" = None
 
 
-_METER: "CompileMeter | None" = None
-
-
-def _meter() -> CompileMeter:
+def _meter() -> meters.CompileMeter:
     global _METER
     if _METER is None:
-        _METER = CompileMeter()
+        _METER = meters.CompileMeter()
     return _METER
 
 
@@ -148,47 +111,22 @@ def native_state() -> dict:
 
 
 def _readings() -> dict:
-    from ethereum_consensus_tpu.telemetry import device as tel_device
-
-    obs = tel_device.OBSERVATORY
-    transfers = obs.transfer_summary()["totals"]
-    return {
-        **_meter().read(),
-        "h2d_bytes": transfers["h2d_bytes"],
-        "d2h_bytes": transfers["d2h_bytes"],
-        "routes": obs.route_tallies(),
-    }
+    return {**_meter().read(), **meters.observatory()}
 
 
 def _delta(before: dict, after: dict) -> dict:
-    out = {}
-    for key, now in after.items():
-        if key == "routes":
-            routes = {}
-            for kind, choices in now.items():
-                moved = {
-                    choice: count - before["routes"].get(kind, {}).get(choice, 0)
-                    for choice, count in choices.items()
-                }
-                moved = {c: n for c, n in moved.items() if n}
-                if moved:
-                    routes[kind] = moved
-            out["routes"] = routes
-        else:
-            out[key] = now - before[key]
-    out["compile_s"] = round(out["compile_s"], 3)
-    return out
-
-
-def _device_sync() -> None:
-    """Every device has finished what was enqueued on it: a device runs
-    its queue in order, so a trivial program that is ready was preceded
-    by everything dispatched before it."""
-    import jax
-
-    jax.block_until_ready(
-        [jax.device_put(0, device) + 0 for device in jax.devices()]
-    )
+    compiles = {
+        key: after[key] - before[key]
+        for key in ("compile_s", "compiles", "cache_misses", "cache_hits")
+    }
+    obs = meters.observatory_delta(before, after)
+    return {
+        **compiles,
+        "compile_s": round(compiles["compile_s"], 3),
+        "h2d_bytes": obs["transfers"]["h2d_bytes"],
+        "d2h_bytes": obs["transfers"]["d2h_bytes"],
+        "routes": obs["routes"],
+    }
 
 
 @contextmanager
@@ -200,44 +138,17 @@ def phase(name: str):
     before = _readings()
     t0 = time.perf_counter()
     yield record
-    _device_sync()
+    meters.device_sync()
     record["wall_s"] = round(time.perf_counter() - t0, 3)
     record.update(_delta(before, _readings()))
     record["native"] = native_state()
     print(json.dumps(record), flush=True)
 
 
-def counters() -> dict:
-    """The integer counters of the metrics registry, now. The evidence
-    checks read what moved since such a snapshot taken when the run
-    began: the registry is process-wide, and only a fresh process starts
-    it at zero."""
-    from ethereum_consensus_tpu.telemetry import metrics
-
-    return {
-        name: value
-        for name, value in metrics.snapshot().items()
-        if isinstance(value, int)
-    }
-
-
-def _moved(since: dict) -> dict:
-    return {
-        name: value - since.get(name, 0)
-        for name, value in counters().items()
-        if value != since.get(name, 0)
-    }
-
-
-def decline_counters(since: dict) -> dict:
-    """Every counter that says a route declined to a host path and moved."""
-    return {
-        name: moved
-        for name, moved in _moved(since).items()
-        if ".fallback." in name
-        or ".fused_fallback." in name
-        or name.startswith(("mesh.decline.", "bls.device_decline."))
-    }
+# The evidence checks read what moved since a snapshot of the counters taken
+# when the run began: the registry is process-wide, and only a fresh process
+# starts it at zero.
+counters = meters.counters
 
 
 # ---------------------------------------------------------------------------
@@ -369,8 +280,8 @@ def stream_blocks(world: dict, roots: dict):
 
     with phase("stream_blocks") as record:
         executor = Executor(world["pre"].copy(), world["context"])
-        # the shape bench.py's pipeline_blocks uses; the settle bound is
-        # raised because a cold first flush compiles the pairing kernels
+        # one window of 8 blocks, two in flight; the settle bound is raised
+        # because a cold first flush compiles the pairing kernels
         policy = FlushPolicy(
             window_size=8, max_in_flight=2, settle_timeout_s=1000.0,
             verify_lanes=1,
@@ -413,7 +324,8 @@ def check_evidence(since: dict, routed_kinds=ROUTED_KINDS) -> dict:
     from ethereum_consensus_tpu.telemetry import device as tel_device
 
     tallies = tel_device.OBSERVATORY.route_tallies()
-    moved = _moved(since)
+    now = counters()
+    moved = meters.moved(since, now)
     evidence = {
         "phase": "evidence",
         "route_device": {
@@ -422,7 +334,7 @@ def check_evidence(since: dict, routed_kinds=ROUTED_KINDS) -> dict:
         },
         "pallas_entries": moved.get("ops.sha256.pallas", 0),
         "device_hash_levels": moved.get("ssz.hash_level.device", 0),
-        "declines": decline_counters(since),
+        "declines": meters.declines(since, now),
         "bls_device_host_routes": tallies.get("bls_device", {}),
     }
     print(json.dumps(evidence), flush=True)
@@ -637,10 +549,11 @@ def check_mesh_evidence(since: dict, n_devices: int) -> dict:
     from ethereum_consensus_tpu.telemetry import device as tel_device
 
     span = tel_device.OBSERVATORY.device_span()
+    now = counters()
     evidence = {
         "phase": "mesh_evidence",
-        "mesh_engage": _moved(since).get("mesh.engage", 0),
-        "declines": decline_counters(since),
+        "mesh_engage": meters.moved(since, now).get("mesh.engage", 0),
+        "declines": meters.declines(since, now),
         "device_span": {name: span.get(name) for name in MESH_KERNELS},
         "routes": {
             kind: choices

@@ -31,8 +31,6 @@ from contextlib import contextmanager
 # The envflags analyzer checks (a) every EC_/ECT_ environ read in the
 # package resolves to a key listed here, and (b) every key here has a
 # row in the "Environment flags" table in docs/OBSERVABILITY.md.
-# (Harness-level keys like EC_BENCH_XL / EC_SOAK_PROFILE are read by
-# bench.py outside the package and live only in the doc table.)
 KNOWN_KEYS = {
     "ECT_OPS_VECTOR": "=off disables every columnar path (scalar oracle mode)",
     "ECT_EPOCH_VECTOR": "=off disables just the columnar-primary epoch engine",

@@ -1,36 +1,23 @@
-"""Experimental MXU-shaped Montgomery multiplication (8-bit limb columns).
+"""MXU-shaped Montgomery multiplication: the int8 digit product.
 
 The lazy tower (ops/fql.py) made the batched pairing COMPILE and run
 correct, but on chips that emulate wide-integer lane multiplies (v5e)
 its u64 column products lose to the native ADX backend. The TPU's
 arithmetic actually lives in the MXU, whose integer path is
-int8×int8→int32. This module re-shapes the schoolbook column product to
-feed it:
+int8×int8→int32. ``mont7r`` re-shapes the schoolbook column product to
+feed it: each operand is carry-normalized to 25 exact 16-bit columns and
+cut into 58 7-bit digits (which fit SIGNED int8); the digits of ``a``
+form a per-element matrix of shifted copies, and the whole product is
+one batched int8 dot_general with exact int32 accumulation
+(58 terms × 127² < 2^20). The Montgomery reduction that follows is the
+same column-serial sweep as fql.mont at byte granularity (52 rounds).
 
-    a, b in 48 8-bit limbs;   outer[n, i, j] = a8[n, i] · b8[n, j]
-    cols[n, k] = Σ_{i+j=k} outer[n, i, j]
-               = (outer reshaped to (n, 2304)) @ M        # one matmul
-    with M[(i, j), k] = [i + j == k], a constant 0/1 (2304, 95) operand.
-
-Every accumulation is exact in int32 (48 terms × 255² < 2^22). The
-contraction as implemented is int32×int32 (outer-product values exceed
-int8, and jax dot_general needs matching operand dtypes): it reshapes
-the reduction into MXU-tileable matmul form but does NOT yet hit the
-int8×int8→int32 fast path itself. `product_cols7`/`mont7` DO: 7-bit
-digits (55 per value, fitting SIGNED int8) form per-element shifted
-digit matrices, and the whole product is one batched int8 dot_general
-with exact int32 accumulation (55 terms × 127² < 2^20) — the MXU's
-native integer path. Hardware measurement decides routing. The Montgomery reduction that follows is the same
-column-serial sweep as fql.mont at byte granularity (52 rounds).
-
-STATUS: ROUTED (round 4). `mont7r` generalizes `mont7` to the lazy
-tower's redundant operands and is a drop-in for ``fql.mont``, selected
-by ``fql.set_multiplier("mxu")`` / ``EC_PAIRING_MULT=mxu``; correctness
-is pinned by tests/test_ops_pairing.py (column-exact vs fql.mont on
-redundant and canonical inputs, full batch-verdict parity under the mxu
-multiplier). ``bench.py bench_pairing_device`` measures both
-multipliers; the live-chip crossover decides the default
-(`install(pairing_min_sets=...)`). See docs/DEVICE_PAIRING.md.
+``mont7r`` takes the lazy tower's redundant operands and is a drop-in
+for ``fql.mont``, selected by ``fql.set_multiplier("mxu")`` /
+``EC_PAIRING_MULT=mxu``; correctness is pinned by
+tests/test_ops_pairing.py (column-exact vs fql.mont on redundant and
+canonical inputs, full batch-verdict parity under the mxu multiplier).
+Which multiplier wins is a per-chip question; see docs/DEVICE_PAIRING.md.
 """
 
 from __future__ import annotations
@@ -41,58 +28,9 @@ import numpy as np
 
 from . import fql
 
-__all__ = ["product_cols8", "mont8", "lv_mont8", "product_cols7", "mont7"]
+__all__ = ["carry_norm", "product_cols7r", "mont7r"]
 
 L8 = 48          # 8-bit limbs per 384-bit value
-COLS8 = 2 * L8 - 1
-
-# constant anti-diagonal contraction matrix: (i*48+j, k) -> [i+j == k]
-_M = np.zeros((L8 * L8, COLS8), dtype=np.int8)
-for _i in range(L8):
-    for _j in range(L8):
-        _M[_i * L8 + _j, _i + _j] = 1
-
-
-def lv_mont8(a: "fql.LV", b: "fql.LV") -> "fql.LV":
-    """Bound-checked entry point: mont8 REQUIRES canonical 16-bit columns
-    (mont outputs) — unlike fql.mont it does NOT accept lazily-redundant
-    values (_to8 would silently drop bits 16+). The trace-time assert
-    makes that precondition loud, the same discipline as fql.lv_mont."""
-    assert a.cmax <= (1 << 16) and b.cmax <= (1 << 16), (
-        "mont8 needs canonical 16-bit columns; canonicalize redundant "
-        f"values first (got cmax {a.cmax:#x}, {b.cmax:#x})"
-    )
-    return fql.lv_canon(mont8(a.arr, b.arr))
-
-
-def _to8(cols16):
-    """(..., 24) 16-bit columns -> (..., 48) 8-bit columns (int32 lanes).
-    Inputs MUST be mont outputs (exact 16-bit columns) — higher bits are
-    dropped; use lv_mont8 for the checked entry point."""
-    lo = (cols16 & jnp.uint64(0xFF)).astype(jnp.int32)
-    hi = ((cols16 >> jnp.uint64(8)) & jnp.uint64(0xFF)).astype(jnp.int32)
-    return jnp.stack([lo, hi], axis=-1).reshape(cols16.shape[:-1] + (L8,))
-
-
-def product_cols8(a16, b16):
-    """Full 95-column schoolbook product of two 16-bit-column values via
-    the outer-product ⊗ constant-matrix contraction. Returns (..., 95)
-    int64 columns of the exact integer product (8-bit column weights)."""
-    a8 = _to8(a16)
-    b8 = _to8(b16)
-    outer = (a8[..., :, None] * b8[..., None, :]).reshape(
-        a8.shape[:-1] + (L8 * L8,)
-    )
-    # the MXU-shaped contraction: (..., 2304) @ (2304, 95) with exact
-    # int32 accumulation (48 terms x 255^2 < 2^22)
-    cols = jax.lax.dot_general(
-        outer,
-        jnp.asarray(_M, jnp.int32),
-        (((outer.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-    return cols.astype(jnp.int64)
-
 
 _P8 = np.zeros(L8, dtype=np.int64)
 for _i in range(L8):
@@ -102,7 +40,7 @@ for _i in range(L8):
 def _reduce8(t):
     """Byte-granular Montgomery reduction of deferred uint64 byte columns
     (value weight 2^(8i)): 52 rounds for R' = 2^416, carry-normalize,
-    regroup to 16-bit columns. Shared by mont8 and mont7."""
+    regroup to 16-bit columns."""
     n0_8 = (-pow(fql.P_INT, -1, 1 << 8)) % (1 << 8)
     batch = t.shape[:-1]
     p8 = jnp.asarray(_P8.astype(np.uint64))
@@ -133,96 +71,10 @@ def _reduce8(t):
     return lo | (hi << jnp.uint64(8))
 
 
-def mont8(a16, b16):
-    """Montgomery product a·b·(2^416)⁻¹ mod-ish p, MXU-shaped product
-    (int32 contraction) + byte-granular reduction. Output is identical to
-    ``fql.mont(a16, b16)`` — 16-bit columns, value < 1.1p — verified
-    column-exact in tests."""
-    cols = product_cols8(a16, b16)
-    batch = cols.shape[:-1]
-    t = jnp.concatenate(
-        [cols, jnp.zeros(batch + (5,), jnp.int64)], axis=-1
-    ).astype(jnp.uint64)
-    return _reduce8(t)
-
-
-# -- the TRUE int8×int8→int32 form: 7-bit digits ---------------------------
-
-L7 = 55          # 7-bit digits per 384-bit value (55·7 = 385)
-COLS7 = 2 * L7 - 1
-
-
-def _to7(cols16):
-    """(..., 24) exact 16-bit columns → (..., 55) 7-bit digits as SIGNED
-    int8 (digits ≤ 127 fit). Same canonical-input precondition as _to8."""
-    # bits via pairwise extraction: digit d covers bits [7d, 7d+7)
-    out = []
-    for d in range(L7):
-        lo_bit = 7 * d
-        q, r = divmod(lo_bit, 16)
-        v = cols16[..., q] >> jnp.uint64(r)
-        if r > 9 and q + 1 < 24:  # digit straddles the column boundary
-            v = v | (cols16[..., q + 1] << jnp.uint64(16 - r))
-        out.append((v & jnp.uint64(0x7F)).astype(jnp.int8))
-    return jnp.stack(out, axis=-1)
-
-
-def product_cols7(a16, b16):
-    """Exact 109-column 7-bit-weighted product via a BATCHED int8 matmul:
-    cols7[n, k] = Σ_j b7[n, j] · A[n, j, k] with A[n, j, k] = a7[n, k−j]
-    (shifted copies of a's digit vector). Both dot_general operands are
-    int8 with int32 accumulation — the MXU's native integer path — and
-    every sum is exact (55 terms × 127² < 2^20)."""
-    a7 = _to7(a16)
-    b7 = _to7(b16)
-    batch = a7.shape[:-1]
-    shifted = []
-    zero = jnp.zeros(batch + (1,), jnp.int8)
-    for j in range(L7):
-        row = a7
-        if j:
-            pad = jnp.zeros(batch + (j,), jnp.int8)
-            row = jnp.concatenate([pad, a7], axis=-1)
-        tail = COLS7 - row.shape[-1]
-        if tail > 0:
-            row = jnp.concatenate(
-                [row, jnp.zeros(batch + (tail,), jnp.int8)], axis=-1
-            )
-        shifted.append(row)
-    A = jnp.stack(shifted, axis=-2)          # (..., 55, 109) int8
-    del zero
-    # batched (..., 1, 55) @ (..., 55, 109) int8 matmul, int32 accumulate
-    nb = len(batch)
-    cols = jax.lax.dot_general(
-        b7[..., None, :],
-        A,
-        (((nb + 1,), (nb,)), (tuple(range(nb)), tuple(range(nb)))),
-        preferred_element_type=jnp.int32,
-    )[..., 0, :]
-    return cols.astype(jnp.int64)
-
-
-def mont7(a16, b16):
-    """Montgomery product via the TRUE int8 MXU product (product_cols7):
-    the 7-bit-weighted columns regroup into byte-weighted uint64 columns
-    (static shift-adds, exact), then the shared byte-granular reduction.
-    Column-exact vs fql.mont — verified in tests."""
-    cols7 = product_cols7(a16, b16).astype(jnp.uint64)
-    batch = cols7.shape[:-1]
-    t = jnp.zeros(batch + (2 * L8 + 4,), jnp.uint64)
-    for i in range(COLS7):
-        lo_bit = 7 * i
-        q, r = divmod(lo_bit, 8)
-        t = t.at[..., q].add(cols7[..., i] << jnp.uint64(r))
-    # columns now byte-weighted but with values up to ~2^27 each — the
-    # deferred-carry reduction tolerates that (accumulator ≪ 2^64)
-    return _reduce8(t)
-
-
 # -- mont7r: the int8 MXU product for REDUNDANT inputs ----------------------
 #
 # fql.mont's callers (the whole pairing tower) feed lazily-redundant
-# columns (< 2^24, value < ~2^397) that _to7's bit-slicing cannot take
+# columns (< 2^24, value < ~2^397), which digit slicing cannot take
 # directly. mont7r normalizes each operand first with one carry scan
 # (exact, 25 16-bit columns), then runs the digit extraction, the batched
 # int8 matmul, and the byte regroup fully vectorized — a handful of XLA

@@ -110,7 +110,7 @@ def sub(a, b):
 # (fq8.mont7r — the MXU's native int8×int8→int32 path). Same contract
 # either way; the switch exists because which one wins is a per-chip
 # hardware question (v5e emulates u64 lane products; see
-# docs/DEVICE_PAIRING.md and bench.py bench_pairing_device).
+# docs/DEVICE_PAIRING.md).
 _MULTIPLIER = _env.raw("EC_PAIRING_MULT", "u64")
 
 

@@ -4,8 +4,8 @@ Multi-device sharding is testable without several chips by running in a
 subprocess whose JAX sees a virtual ``n``-device CPU platform. The device
 count is an XLA flag read when the backend starts, so this MUST happen
 via the environment of a fresh process — never in-process. This module
-holds the one canonical recipe (used by tests/conftest.py,
-__graft_entry__.dryrun_multichip and bench.py's mesh configs). The child
+holds the one canonical recipe (used by tests/conftest.py and
+__graft_entry__.dryrun_multichip). The child
 is pinned to ``JAX_PLATFORMS=cpu``: it never needs the chip, so a parent
 that holds one can start it.
 

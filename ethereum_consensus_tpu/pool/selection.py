@@ -17,7 +17,7 @@ Ties break deterministically — larger gain first, then the canonical
 group sort order (``store._group_sort_key``), then lowest row index —
 so the scalar twin (`python ints as bitmasks`, same loop) produces the
 IDENTICAL pick sequence: ``tests/test_pool.py`` diffs them under
-randomized traffic, and ``bench.py pool_ingest`` gates on the identity.
+randomized traffic.
 
 Subset rows (admission already rejects them) would never be picked —
 their marginal gain over the superset's union is zero — so selection is

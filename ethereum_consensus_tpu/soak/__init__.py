@@ -7,8 +7,8 @@ SSE subscribers, pool ingestion spam, equivocation traffic — for
 thousands of flush windows, asserting three hard gates: p99 latency
 SLOs off the reservoir histograms (with /healthz pinned to ``ok``),
 flat RSS via the leak sentinel, and end-of-run bit-identity (state
-root, blame, equivocation ledger). ``bench.py soak`` reports the
-sustained blocks/s + queries/s pair the north star asks for.
+root, blame, equivocation ledger). Its report carries the sustained
+blocks/s + queries/s pair.
 
 Host-only by construction: importing this package never imports jax
 (the mesh fault lane engages only when ``ECT_MESH`` is on).

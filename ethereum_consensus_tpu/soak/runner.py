@@ -143,9 +143,8 @@ def load_profile(path: "str | None" = None) -> dict:
 
 
 class SoakConfig:
-    """One soak's shape. Defaults are the ``make soak-smoke`` scale; the
-    bench config (``bench.py soak``) raises cycles/deadline/spam to the
-    sustained shape."""
+    """One soak's shape. Defaults are the ``make soak-smoke`` scale; a
+    sustained run raises cycles/deadline/spam."""
 
     __slots__ = (
         "validator_count", "atts_per_block", "cycles", "deadline_s",
@@ -201,8 +200,8 @@ class SoakConfig:
         # per-deployment memory envelope (ISSUE 15): an ABSOLUTE peak
         # ceiling the flat-RSS gate additionally asserts (None = growth
         # budget only — the shipped catastrophe-catcher default), plus
-        # the bench epoch configs' ceiling table the profile carries
-        # through (bench.py reads it via load_profile)
+        # the profile's per-workload ceiling table (``memory_ceilings``),
+        # carried through for whoever loads it
         self.rss_ceiling_mb = (
             None if rss_ceiling_mb is None else float(rss_ceiling_mb)
         )

@@ -3,9 +3,9 @@ every byte moved on the million-validator hot paths.
 
 PRs 4/7/10 instrumented seconds (spans), lineage (flight), and the
 device side (compile/transfer/routing ledgers) — memory was the last
-black box: the ``EC_BENCH_XL=1`` 2^22 epoch stretch peaks at ~18 GB RSS
-and nothing in the telemetry stack could say which structure owns it or
-how many bytes each epoch phase actually moves. This module closes that
+black box: nothing in the telemetry stack could say which structure owns
+a large registry's resident set or how many bytes each epoch phase
+actually moves. This module closes that
 with one process-wide ``MemoryObservatory`` behind the same one-read
 zero-overhead ``active`` guard as the span recorder and the device
 observatory, recording THREE ledgers:

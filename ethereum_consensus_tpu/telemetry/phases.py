@@ -1,11 +1,10 @@
 """Transition phase attribution from recorded spans.
 
 The per-block cost split the ROADMAP quotes — signature batch / state
-HTR / committees / operations — used to be computed by bench-local
-monkeypatching inside ``bench.py``. It now derives from the named spans
-the transition itself emits (``models/transition.py`` +
+HTR / committees / operations — derives from the named spans the
+transition itself emits (``models/transition.py`` +
 ``models/phase0/helpers.py``), so ANY entry point that records a run —
-bench, the pipeline CLI, the spec harness — attributes the same way.
+the pipeline CLI, the spec harness, a test — attributes the same way.
 
 Span name contract (docs/OBSERVABILITY.md):
 
@@ -20,9 +19,7 @@ Span name contract (docs/OBSERVABILITY.md):
   (``get_beacon_committee`` bodies, proposer-index cache misses).
 
 ``operations`` is everything else inside the transition:
-``slot_advance + block − sig_batch − state_htr − committees`` — the same
-residual definition the old bench plumbing used, so BENCH_*.json
-trajectories stay comparable across the migration.
+``slot_advance + block − sig_batch − state_htr − committees``.
 """
 
 from __future__ import annotations

@@ -2,8 +2,8 @@
 
 The suite runs on XLA's CPU backend: ``JAX_PLATFORMS`` defaults to
 ``cpu`` here, before anything imports jax. The chip is exercised by
-``chip_smoke.py`` (and measured by ``bench.py``), not by the correctness
-suite; what the chip's compiler accepts is tests/test_chip_compile.py.
+``chip_smoke.py`` (and measured by ``benchmark/``), not by the
+correctness suite; what the chip's compiler accepts is tests/test_chip_compile.py.
 
 Tests that need a multi-device mesh spawn a subprocess on a virtual
 8-device CPU platform (``cpu_mesh_env`` below): the device count is an

@@ -4,12 +4,13 @@ The script's phase functions take their sizes as arguments (its command
 line has one deployment, the 2^20 one), so they run here on a small
 registry: roots from the device-routed served path must equal the host
 reference's. Routing thresholds are lowered HERE — the script installs
-``ops.install()`` defaults and nothing else. Without a TPU the script and
-``bench.py`` must refuse: non-zero exit, no result line.
+``ops.install()`` defaults and nothing else. Without a TPU the script
+must refuse: non-zero exit, no result line. What counts as a route
+declining to the host is the benchmark's rule (``benchmark/meters.py``).
 """
 
 import os
-import subprocess
+import re
 import sys
 
 import jax
@@ -100,20 +101,32 @@ def test_main_refuses_without_tpu(argv, capsys):
     assert "no TPU" in out.err
 
 
-def test_bench_refuses_without_tpu():
-    """``python bench.py`` measures the chip: without one it prints no
-    result and exits non-zero (its sizes never depend on the backend)."""
-    proc = subprocess.run(
-        [sys.executable, "bench.py"],
-        cwd=REPO_ROOT,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        capture_output=True,
-        text=True,
-        timeout=300,
-    )
-    assert proc.returncode == 3, proc.stderr[-2000:]
-    assert proc.stdout == ""
-    assert "no TPU" in proc.stderr
+@pytest.mark.parametrize(
+    "name, declined",
+    [
+        ("chip_smoke_test.fallback.case", True),
+        ("chip_smoke_test.fused_fallback.case", True),
+        ("mesh.decline.chip_smoke_test", True),
+        ("bls.device_decline.chip_smoke_test", True),
+        ("ops_vector.attestations.blocks", False),
+        ("bls.aggregate_pubkeys.from_cache", False),
+    ],
+)
+def test_evidence_declines_are_the_benchmarks(name, declined):
+    """A counter that moved is a decline exactly when the benchmark's
+    meters say so: the evidence check refuses it by name, and lets any
+    other counter pass."""
+    from ethereum_consensus_tpu.telemetry import metrics
+
+    since = chip_smoke.counters()
+    metrics.counter("ops.sha256.pallas").inc()
+    metrics.counter(name).inc()
+    if declined:
+        with pytest.raises(chip_smoke.SmokeFailure, match=re.escape(name)):
+            chip_smoke.check_evidence(since, routed_kinds=())
+    else:
+        evidence = chip_smoke.check_evidence(since, routed_kinds=())
+        assert evidence["declines"] == {}
 
 
 @pytest.mark.parametrize("placed_from_outside", [True, False])

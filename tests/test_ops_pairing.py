@@ -230,49 +230,6 @@ def test_batch_verify_device_verdicts():
     bad[1], bad[2] = bad[2], bad[1]
     assert not pairing.batch_verify_device(pk_raws, h_raws, bad, scalars)
 
-def test_fq8_matmul_product_matches_fql():
-    """The experimental MXU-shaped Montgomery multiply (ops/fq8.py:
-    8-bit-limb outer product contracted against the constant
-    anti-diagonal matrix) must be COLUMN-EXACT against fql.mont — same
-    R' = 2^416, same output representation."""
-    import jax.numpy as jnp
-
-    from ethereum_consensus_tpu.ops import fq8
-
-    rng = np.random.default_rng(11)
-    a = jnp.asarray(rng.integers(0, 1 << 16, size=(16, 24), dtype=np.uint64))
-    b = jnp.asarray(rng.integers(0, 1 << 16, size=(16, 24), dtype=np.uint64))
-    want = np.asarray(fql.mont(a, b))
-    got = np.asarray(fq8.mont8(a, b))
-    assert (want == got).all()
-    # and the raw 95-column product is the exact integer product
-    cols = np.asarray(fq8.product_cols8(a, b))
-    for n in range(4):
-        va = sum(int(c) << (16 * i) for i, c in enumerate(np.asarray(a[n])))
-        vb = sum(int(c) << (16 * i) for i, c in enumerate(np.asarray(b[n])))
-        vp = sum(int(c) << (8 * i) for i, c in enumerate(cols[n]))
-        assert vp == va * vb, n
-
-
-def test_fq7_true_int8_product_matches_fql():
-    """mont7 — the batched int8×int8→int32 dot_general form (7-bit
-    digits, per-element shift matrices) — must also be column-exact
-    against fql.mont, and its raw 109-column product integer-exact."""
-    import jax.numpy as jnp
-
-    from ethereum_consensus_tpu.ops import fq8
-
-    rng = np.random.default_rng(13)
-    a = jnp.asarray(rng.integers(0, 1 << 16, size=(16, 24), dtype=np.uint64))
-    b = jnp.asarray(rng.integers(0, 1 << 16, size=(16, 24), dtype=np.uint64))
-    assert (np.asarray(fql.mont(a, b)) == np.asarray(fq8.mont7(a, b))).all()
-    cols = np.asarray(fq8.product_cols7(a, b))
-    for n in range(4):
-        va = sum(int(c) << (16 * i) for i, c in enumerate(np.asarray(a[n])))
-        vb = sum(int(c) << (16 * i) for i, c in enumerate(np.asarray(b[n])))
-        vp = sum(int(c) << (7 * i) for i, c in enumerate(cols[n]))
-        assert vp == va * vb, n
-
 
 def test_mont7r_redundant_inputs_match_fql():
     """mont7r — the routed MXU multiplier — takes the SAME redundant

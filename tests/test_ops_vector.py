@@ -644,15 +644,39 @@ def test_bench_smoke_warm_block_engages_columnar_engine():
     engage (ops_vector.* counters), commit via bulk_store, and keep the
     named hot-scan spans off the per-block path — the cheap standing
     proof that the fast path didn't silently degrade to scalar writes."""
-    import bench
+    from ethereum_consensus_tpu.models.altair.block_processing import (
+        _registry_pubkey_index,
+    )
+    from ethereum_consensus_tpu.models.deneb import helpers
     from ethereum_consensus_tpu.models.deneb.state_transition import (
         state_transition,
+    )
+    from ethereum_consensus_tpu.models.phase0.helpers import (
+        _registry_pubkey_objects,
     )
     from ethereum_consensus_tpu.telemetry import phases as tel_phases
     from ethereum_consensus_tpu.telemetry import spans as tel_spans
 
     state, ctx, signed = chain_utils.mainnet_block_bundle("deneb", 1 << 14, 8)
-    bench._prime_warm_state("deneb", state, ctx)
+    # the state-level epoch memos, the pubkey memos and the registry
+    # columns, filled on the original as a resident client holds them:
+    # copies share all three
+    epoch = helpers.get_current_epoch(state, ctx)
+    for e in {epoch, max(0, epoch - 1)}:
+        helpers.get_active_validator_indices(state, e)
+    helpers.get_total_active_balance(state, ctx)
+    _registry_pubkey_objects(state)
+    _registry_pubkey_index(state)
+    cols = ops_vector.columns_for(state)
+    assert cols is not None
+    cols.validator_columns(state)
+    for field in (
+        "balances",
+        "inactivity_scores",
+        "previous_epoch_participation",
+        "current_epoch_participation",
+    ):
+        cols.list_column(state, field)
     warm = state.copy()
     state_transition(warm, signed, ctx)  # warm caches/compiles
 

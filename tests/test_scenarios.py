@@ -319,9 +319,7 @@ def test_storm_mainnet_scale_2pow17():
     """The acceptance shape: a 10% invalid-block storm over a deneb
     chain at 2^17 validators recovers every failure and lands
     bit-identically to the scalar oracle. Slow-marked (the chain bundle
-    build alone costs minutes cold); same bundle shape as `bench.py
-    adversarial_replay`'s degraded tier, so the two share the disk
-    cache."""
+    build alone costs minutes cold)."""
     state, ctx, blocks = chain_utils.mainnet_chain_bundle(
         "deneb", 1 << 17, 16, 8
     )
